@@ -43,7 +43,7 @@ cd "$(dirname "$0")/.."
 cachedir="$(mktemp -d /tmp/keystone-aot-smoke-XXXXXX)"
 trap 'rm -rf "$cachedir"' EXIT
 # both cache layers root in the throwaway dir so boot 1 is genuinely cold
-run=(env JAX_PLATFORMS=cpu KEYSTONE_COMPILE_CACHE="$cachedir/xla"
+run=(env JAX_PLATFORMS=cpu JAX_COMPILATION_CACHE_DIR="$cachedir/xla"
      python -m keystone_tpu --serve-demo --backend cpu
      --aot-cache "$cachedir")
 echo "== boot 1 (cold: traces + exports every bucket) =="

@@ -154,7 +154,7 @@ trap 'rm -rf "$aot_dir"' EXIT
 for mode in miss hit; do
   aot_out="$(mktemp /tmp/keystone-aot-trace-XXXXXX.json)"
   env JAX_PLATFORMS=cpu KEYSTONE_TRACE="$aot_out" \
-    KEYSTONE_AOT_CACHE="$aot_dir" KEYSTONE_COMPILE_CACHE="$aot_dir/xla" \
+    KEYSTONE_AOT_CACHE="$aot_dir" JAX_COMPILATION_CACHE_DIR="$aot_dir/xla" \
     python - "$aot_out" "$mode" <<'PY'
 import json
 import sys

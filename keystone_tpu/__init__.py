@@ -11,52 +11,42 @@ XLA's lowering is unstable (``ops/`` — e.g. the KRR Gaussian kernel block).
 
 import os as _os
 
-#: the XLA cache dir THIS package defaulted jax to (None when the operator
-#: chose one via env/config) — `compile.configure(--aot-cache)` may relocate
-#: a defaulted cache under the AOT dir, but never an operator's choice
-_default_xla_cache_dir = None
+#: where XLA's persistent compilation cache lives when the environment does
+#: not place it (``JAX_COMPILATION_CACHE_DIR``): one fixed, git-ignored
+#: directory in the checkout. The path is part of the cache key, so it is
+#: never built from ``~``, a temp name, a pid or the time.
+COMPILE_CACHE_DIR = _os.path.join(
+    _os.path.dirname(_os.path.dirname(_os.path.abspath(__file__))),
+    ".jax_cache",
+)
+
+#: programs that compile faster than this are not worth a disk entry;
+#: ``compile.configure`` lowers it to 0 for the lifetime of an AOT cache
+PERSIST_MIN_COMPILE_SECS = 0.5
 
 
-def _enable_persistent_compile_cache() -> None:
-    """Point XLA at an on-disk compilation cache (set
-    ``KEYSTONE_NO_COMPILE_CACHE=1`` to disable, ``KEYSTONE_COMPILE_CACHE=dir``
-    to relocate). Compiles dominate cold-start wall time on TPU; caching them
-    across processes is free speed for every pipeline."""
-    from .utils import env_flag, env_str
-
-    if env_flag("KEYSTONE_NO_COMPILE_CACHE", False):
-        return
-    chosen = env_str("KEYSTONE_COMPILE_CACHE")
-    cache_dir = chosen or _os.path.join(
-        _os.path.expanduser("~"), ".cache", "keystone_tpu", "xla"
-    )
+def _place_compile_cache() -> None:
+    """Point XLA at an on-disk compilation cache: compiles dominate
+    cold-start wall time on the accelerator, and caching them across
+    processes is free speed for every pipeline. ``JAX_COMPILATION_CACHE_DIR``
+    places the cache and ``JAX_ENABLE_COMPILATION_CACHE=0`` turns it off —
+    jax's own variables are the one way; when the first is set no code here
+    (or in ``compile.configure``) sets another directory."""
     # NOTE: importing this package therefore imports jax and touches global
-    # jax.config as an import side effect — env vars like JAX_PLATFORMS set
-    # by user code AFTER `import keystone_tpu` will not take effect (see
-    # README "Backend selection"). Use parallel.virtual or __main__'s
-    # --backend flag to pick a backend programmatically.
+    # jax.config as an import side effect (no backend is initialized).
     import jax
 
-    if env_str("JAX_COMPILATION_CACHE_DIR") or getattr(
-        jax.config, "jax_compilation_cache_dir", None
-    ):
-        return  # the user already configured a cache; don't hijack it
-    try:
-        jax.config.update("jax_compilation_cache_dir", cache_dir)
-        jax.config.update("jax_persistent_cache_min_entry_size_bytes", -1)
-        jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.5)
-        if not chosen:
-            global _default_xla_cache_dir
-            _default_xla_cache_dir = cache_dir
-    except Exception:  # pragma: no cover - jax without these specific knobs
-        import logging
+    from .utils import env_str
 
-        logging.getLogger(__name__).debug(
-            "persistent compile cache not enabled", exc_info=True
-        )
+    if env_str("JAX_COMPILATION_CACHE_DIR") is None:
+        jax.config.update("jax_compilation_cache_dir", COMPILE_CACHE_DIR)
+    jax.config.update("jax_persistent_cache_min_entry_size_bytes", -1)
+    jax.config.update(
+        "jax_persistent_cache_min_compile_time_secs", PERSIST_MIN_COMPILE_SECS
+    )
 
 
-_enable_persistent_compile_cache()
+_place_compile_cache()
 
 from .data.chunked import ChunkedDataset
 from .data.dataset import Dataset

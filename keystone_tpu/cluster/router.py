@@ -73,6 +73,8 @@ from concurrent.futures import Future
 from dataclasses import dataclass, field
 from typing import Any, Dict, List, Optional, Sequence
 
+import jax
+
 from ..autoscale import Autoscaler, ScalePolicy
 from ..autoscale.qos import (
     DEFAULT_TENANT,
@@ -99,6 +101,7 @@ from . import shm as shm_mod
 from . import wire as wire_mod
 from .wire import (
     ConnectionClosed,
+    WorkerError,
     costs_from_wire,
     deadline_to_wire,
     decode_error,
@@ -162,6 +165,9 @@ class _WorkerSlot:
         self.outstanding: set = set()
         self.depth = 0  # worker-reported local queue depth (pongs)
         self.ready_report: Optional[dict] = None
+        #: the typed error a worker sent in place of ``ready`` (its boot
+        #: raised: no chip to be had, a placement or contract refusal)
+        self.boot_error: Optional[BaseException] = None
         self.last_snapshot: Optional[dict] = None
         #: stats request/reply matching: a stats reply only lands if it
         #: echoes the CURRENT sequence — a late reply from a previous
@@ -217,6 +223,7 @@ class ClusterRouter:
         health_interval_s: float = 2.0,
         log_interval_s: float = 10.0,
         virtual_devices: Optional[int] = None,
+        platform: Optional[str] = None,
         log_level: Optional[str] = None,
         slo: Optional[SloPolicy] = None,
         trace_sample: Optional[float] = None,
@@ -245,6 +252,12 @@ class ClusterRouter:
             "aot_cache": aot_cache,
             "warmup": warmup,
             "virtual_devices": virtual_devices,
+            # the workers' platform is explicit: ``platform=``, else
+            # the one this process was asked for (--backend /
+            # JAX_PLATFORMS; None = jax's own choice). It rides the boot
+            # spec, so a worker comes up on it or fails loudly — never
+            # on another. Reading the config initializes no backend.
+            "platform": platform or jax.config.jax_platforms or None,
             "log_level": log_level,
             "tenant_weights": (
                 dict(tenant_weights) if tenant_weights else None
@@ -485,26 +498,34 @@ class ClusterRouter:
             self._spawn_worker(slot)
         deadline = time.monotonic() + self._spawn_timeout_s
         with self._cond:
-            while not all(s.alive for s in self._slots):
+            # wait until EVERY slot has settled (ready, or dead): two
+            # workers racing for one chip fail differently — the loser
+            # cannot open it, the winner refuses the placement — and the
+            # typed refusal below is the one worth raising
+            while not all(
+                s.alive or s.boot_error or s.proc.poll() is not None
+                for s in self._slots
+            ):
                 if self._closed:
                     raise EngineStopped("router shut down during start")
-                dead = [
-                    s.index for s in self._slots
-                    if s.proc is not None and not s.alive
-                    and s.proc.poll() is not None
-                ]
-                if dead:
-                    break
                 if not self._cond.wait(timeout=0.2):
                     if time.monotonic() >= deadline:
                         break
-        missing = [s.index for s in self._slots if not s.alive]
+        missing = [s for s in self._slots if not s.alive]
         if missing:
             self.shutdown(drain=False)
+            errors = [s.boot_error for s in missing if s.boot_error]
+            typed = next(
+                (e for e in errors if not isinstance(e, WorkerError)), None
+            )
+            if typed is not None:
+                raise typed
+            detail = f" — {errors[0]}" if errors else ""
             raise RuntimeError(
-                f"cluster workers {missing} failed to boot within "
-                f"{self._spawn_timeout_s:.0f}s — check worker stderr "
-                "(spawned processes inherit this process's streams)"
+                f"cluster workers {[s.index for s in missing]} failed to "
+                f"boot within {self._spawn_timeout_s:.0f}s{detail} — check "
+                "worker stderr (spawned processes inherit this process's "
+                "streams)"
             )
         self._health_thread = threading.Thread(
             target=self._health_loop, name="ks-router-health", daemon=True
@@ -634,6 +655,14 @@ class ClusterRouter:
                 ):
                     raise ConnectionClosed("bad hello")
                 ready = recv_msg(conn, deadline=handshake_by)
+                if ready.get("type") == "boot_error":
+                    with self._cond:
+                        self._slots[int(hello["worker"])].boot_error = (
+                            decode_error(ready.get("error") or {})
+                        )
+                        self._cond.notify_all()
+                    conn.close()
+                    continue
                 if ready.get("type") != "ready":
                     raise ConnectionClosed(
                         f"expected ready, got {ready.get('type')!r}"
@@ -673,6 +702,7 @@ class ClusterRouter:
             slot.booting = False
             slot.capacity = int(ready.get("capacity", 1))
             slot.ready_report = dict(ready)
+            slot.boot_error = None
             slot.outstanding = set()
             # codec negotiation: binary only when this router wants it
             # AND the hello advertised it — an old worker that never

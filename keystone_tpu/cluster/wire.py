@@ -103,6 +103,7 @@ def _registry():
         Shed,
     )
     from ..check import ContractMismatchError, PipelineCheckError
+    from ..parallel.placement import PlacementError
     from ..workflow.pipeline import NotTraceableError
 
     types = (
@@ -117,6 +118,7 @@ def _registry():
         NotTraceableError,
         ContractMismatchError,
         PipelineCheckError,
+        PlacementError,
         WorkerError,
     )
     return {t.__name__: t for t in types}

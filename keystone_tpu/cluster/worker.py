@@ -95,6 +95,13 @@ def worker_main(host: str, port: int, token: str, worker_id: int,
             f"[worker-{worker_id}] %(levelname)s %(name)s: %(message)s"
         ),
     )
+    if spec.get("platform"):
+        # the platform the router was asked for, explicit in THIS
+        # process too: if it cannot be had, jax raises at backend
+        # start-up instead of quietly serving from another
+        import jax
+
+        jax.config.update("jax_platforms", spec["platform"])
     if spec.get("virtual_devices"):
         from ..parallel.virtual import provision_virtual_devices
 
@@ -212,29 +219,42 @@ def worker_main(host: str, port: int, token: str, worker_id: int,
         "codec": 1,
     })
 
-    fitted = resolve_model(spec["model"])
-    # upfront contract validation: the router's spec'd datum shape/dtype
-    # against the model's STATIC check report — a mis-deployed model
-    # (wrong artifact for this topology) fails the boot with a typed,
-    # node-attributed error instead of serving garbage or tracing a
-    # doomed bucket set (the fleet constructor re-validates coupling)
-    fitted.check(span=False).require_contract(
-        spec.get("datum_shape"), spec.get("dtype"), verb="boot"
-    )
-    devices = _worker_devices(
-        worker_id, int(spec.get("n_workers", 1)), spec.get("replicas")
-    )
-    fleet = ServingFleet(
-        fitted,
-        devices=devices,
-        buckets=tuple(spec.get("buckets") or (1, 8, 32, 64)),
-        datum_shape=spec.get("datum_shape"),
-        dtype=spec.get("dtype"),
-        max_queue=int(spec.get("max_queue", 1024)),
-        max_wait_ms=float(spec.get("max_wait_ms", 2.0)),
-        tenant_weights=spec.get("tenant_weights"),
-    )
-    fleet.start(warmup=spec.get("warmup"))
+    try:
+        # placement first: it is the first touch of the backend (a chip
+        # that cannot be had fails here, in seconds) and its refusal —
+        # more worker processes than accelerator chips — is typed
+        devices = _worker_devices(
+            worker_id, int(spec.get("n_workers", 1)), spec.get("replicas")
+        )
+        fitted = resolve_model(spec["model"])
+        # upfront contract validation: the router's spec'd datum
+        # shape/dtype against the model's STATIC check report — a
+        # mis-deployed model (wrong artifact for this topology) fails the
+        # boot with a typed, node-attributed error instead of serving
+        # garbage or tracing a doomed bucket set (the fleet constructor
+        # re-validates coupling)
+        fitted.check(span=False).require_contract(
+            spec.get("datum_shape"), spec.get("dtype"), verb="boot"
+        )
+        fleet = ServingFleet(
+            fitted,
+            devices=devices,
+            buckets=tuple(spec.get("buckets") or (1, 8, 32, 64)),
+            datum_shape=spec.get("datum_shape"),
+            dtype=spec.get("dtype"),
+            max_queue=int(spec.get("max_queue", 1024)),
+            max_wait_ms=float(spec.get("max_wait_ms", 2.0)),
+            tenant_weights=spec.get("tenant_weights"),
+        )
+        fleet.start(warmup=spec.get("warmup"))
+    except Exception as e:
+        # the router's start() raises this, typed, in place of a bare
+        # "failed to boot — check worker stderr"
+        reply({
+            "type": "boot_error", "worker": worker_id,
+            "error": encode_error(e),
+        })
+        raise
     metrics_ref[0] = fleet.metrics
     snap = fleet.metrics.snapshot()
     reply({
@@ -245,6 +265,7 @@ def worker_main(host: str, port: int, token: str, worker_id: int,
         "capacity": fleet.n_replicas * fleet.policy.max_size,
         "replicas": fleet.n_replicas,
         "devices": [str(d) for d in devices],
+        "platform": devices[0].platform,
         # the shm negotiation's closing answer: true means both rings
         # attached and zero-copy payloads are live on this connection
         "shm": shm_rx is not None,

@@ -10,10 +10,11 @@ loads the executable instead of re-paying the trace. Warm boots are
 milliseconds of deserialization instead of tens of seconds of tracing
 and XLA compilation.
 
-Layout of a cache directory::
+Layout of a cache directory (exported StableHLO and manifests only —
+XLA's own compilation cache stays wherever ``JAX_COMPILATION_CACHE_DIR``
+or the package default placed it, see ``keystone_tpu/__init__.py``)::
 
     <dir>/entries/<pipeline-digest>-<signature-digest>.aot   # exported StableHLO
-    <dir>/xla/                                               # layered jax compilation cache
 
 Knobs: ``KEYSTONE_AOT_CACHE=<dir>`` (or ``--aot-cache`` on the CLI, or
 ``utils.obs.configure(aot_cache=...)``), ``KEYSTONE_AOT_CACHE_BYTES``
@@ -24,10 +25,10 @@ for the invalidation rules.
 from __future__ import annotations
 
 import logging
-import os
 import threading
 from typing import Optional
 
+from ..utils.timing import degraded
 from .aot import AotDispatcher, signature_of
 from .cache import CacheEntry, ExecutableCache
 from .fingerprint import (
@@ -84,12 +85,6 @@ logger = logging.getLogger(__name__)
 _lock = threading.Lock()
 _cache: Optional[ExecutableCache] = None
 _initialized = False  # False => next get_cache() reads KEYSTONE_AOT_CACHE
-#: jax config values overwritten by _layer_jax_compilation_cache, so
-#: reset() can put them back: {config_name: prior_value}
-_prior_jax_config: Optional[dict] = None
-#: the XLA dir the layering itself installed — a later configure(other_dir)
-#: may relocate it again (it is ours, not operator-chosen)
-_layered_xla_dir: Optional[str] = None
 
 
 def configure(
@@ -98,11 +93,11 @@ def configure(
     """Install the process-wide executable cache.
 
     ``path=None`` follows ``KEYSTONE_AOT_CACHE`` (unset or empty ⇒ AOT
-    caching disabled). Installing a cache also layers jax's persistent
-    compilation cache underneath at ``<dir>/xla`` — so even a code path
-    that re-lowers (an export round trip, a fallback live compile) hits
-    a warm XLA cache on the second boot — unless the process already
-    configured ``jax_compilation_cache_dir`` itself, which is respected.
+    caching disabled). Installing a cache also zeroes jax's minimum
+    compile time for persisting an XLA executable: serve programs compile
+    in well under the package default, which would skip exactly the
+    entries a warm boot needs. The XLA cache DIRECTORY is never touched
+    here — it was placed once, at import.
     """
     global _cache, _initialized
     with _lock:
@@ -123,9 +118,12 @@ def configure(
                 "aot: cache dir %r unusable — AOT caching disabled", path,
                 exc_info=True,
             )
+            degraded("aot_cache_dir")
             _cache = None
             return None
-        _layer_jax_compilation_cache(_cache)
+        import jax
+
+        jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
         return _cache
 
 
@@ -139,63 +137,17 @@ def get_cache() -> Optional[ExecutableCache]:
 
 
 def reset() -> None:
-    """Forget the installed cache AND the env memo, and restore any jax
-    config knobs :func:`configure` overwrote (test hygiene)."""
-    global _cache, _initialized, _prior_jax_config, _layered_xla_dir
+    """Forget the installed cache AND the env memo, and put the XLA
+    persistence threshold :func:`configure` zeroed back at the package
+    default (test hygiene)."""
+    global _cache, _initialized
     with _lock:
         _cache = None
         _initialized = False
-        _layered_xla_dir = None
-        prior, _prior_jax_config = _prior_jax_config, None
-    if prior:
-        import jax
+    import jax
 
-        for name, value in prior.items():
-            try:
-                jax.config.update(name, value)
-            except Exception:  # pragma: no cover - knob absent in this jax
-                logger.debug("could not restore jax config %s", name,
-                             exc_info=True)
+    from .. import PERSIST_MIN_COMPILE_SECS
 
-
-def _layer_jax_compilation_cache(cache: ExecutableCache) -> None:
-    """Point jax's own persistent compilation cache under the AOT cache
-    dir, so the XLA compile of a deserialized (or re-lowered) module is a
-    disk lookup on warm boots — and the whole warm-boot state lives in
-    ONE directory an operator can mount into a fresh replica. The package
-    import-time DEFAULT (``~/.cache/keystone_tpu/xla``) is relocated here;
-    a dir the operator chose (``JAX_COMPILATION_CACHE_DIR`` /
-    ``KEYSTONE_COMPILE_CACHE``, or their own ``jax.config``) is kept.
-    The persistence thresholds are zeroed either way: serve programs
-    compile in well under the default minimum compile time, which would
-    skip exactly the entries a warm boot needs."""
-    global _prior_jax_config, _layered_xla_dir
-    try:
-        import jax
-
-        import keystone_tpu as _pkg
-
-        prior = _prior_jax_config if _prior_jax_config is not None else {}
-
-        def _set(name, value):
-            prior.setdefault(name, getattr(jax.config, name))
-            jax.config.update(name, value)
-
-        current_dir = jax.config.jax_compilation_cache_dir
-        relocatable = (
-            not current_dir
-            or current_dir == getattr(_pkg, "_default_xla_cache_dir", None)
-            or current_dir == _layered_xla_dir  # a previous configure()'s
-        )
-        if relocatable:
-            os.makedirs(cache.xla_cache_dir, exist_ok=True)
-            _set("jax_compilation_cache_dir", cache.xla_cache_dir)
-            _layered_xla_dir = cache.xla_cache_dir
-        _set("jax_persistent_cache_min_compile_time_secs", 0.0)
-        _set("jax_persistent_cache_min_entry_size_bytes", -1)
-        _prior_jax_config = prior
-    except Exception:
-        logger.warning(
-            "aot: could not layer the jax persistent compilation cache",
-            exc_info=True,
-        )
+    jax.config.update(
+        "jax_persistent_cache_min_compile_time_secs", PERSIST_MIN_COMPILE_SECS
+    )

@@ -38,6 +38,7 @@ import time
 from typing import Any, Callable, Dict, Optional, Tuple
 
 from ..obs.tracer import current as _trace_current
+from ..utils.timing import degraded
 from .cache import ExecutableCache
 from .fingerprint import entry_key, environment_key
 
@@ -152,6 +153,7 @@ class AotDispatcher:
                 "aot: undeserializable entry for %s %s — falling back to live "
                 "compile", self._label or key, sig, exc_info=True,
             )
+            degraded("aot_load")
             self._cache._discard(entry.path, "undeserializable")
             return None
         self._loaded += 1
@@ -220,6 +222,7 @@ class AotDispatcher:
                 "(no cross-process caching for this signature)",
                 self._label or key, sig, exc_info=True,
             )
+            degraded("aot_export")
             if self._expected_exportable:
                 # static-vs-dynamic disagreement: the checker's lattice
                 # said this chain exports. A verdict bug — make it loud
@@ -269,6 +272,7 @@ class AotDispatcher:
                 "aot: could not persist %s %s — executable still serves "
                 "live", self._label or key, sig, exc_info=True,
             )
+            degraded("aot_persist")
             payload = b""
         if payload:
             self._ledger.record(

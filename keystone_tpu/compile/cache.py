@@ -92,12 +92,6 @@ class ExecutableCache:
     def entries_dir(self) -> str:
         return os.path.join(self.root, "entries")
 
-    @property
-    def xla_cache_dir(self) -> str:
-        """Where the layered jax persistent compilation cache lives (see
-        :func:`keystone_tpu.compile.configure`)."""
-        return os.path.join(self.root, "xla")
-
     def entry_path(self, key: str) -> str:
         if os.sep in key or not key:
             raise ValueError(f"invalid cache key {key!r}")

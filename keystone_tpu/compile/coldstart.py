@@ -20,8 +20,9 @@ Usage::
 
 Output (one line)::
 
-    {"construct_seconds": ..., "warmup_seconds": ..., "compiles": N,
-     "aot_loads": M, "buckets": [...], "outputs_match": true, ...}
+    {"platform": "cpu", "construct_seconds": ..., "warmup_seconds": ...,
+     "compiles": N, "aot_loads": M, "buckets": [...],
+     "outputs_match": true, ...}
 """
 
 from __future__ import annotations
@@ -50,6 +51,7 @@ def main(argv: Optional[List[str]] = None) -> int:
 
     import numpy as np
 
+    from ..parallel.mesh import device_summary
     from ..serving.demo import build_demo_fitted
     from ..serving.engine import ServingEngine
 
@@ -83,6 +85,7 @@ def main(argv: Optional[List[str]] = None) -> int:
     print(
         json.dumps(
             {
+                "platform": device_summary()["platform"],
                 "construct_seconds": round(construct_seconds, 4),
                 "warmup_seconds": round(warmup_seconds, 4),
                 "buckets_warmed": warmed,

@@ -42,6 +42,7 @@ from collections import OrderedDict
 from typing import Any, Callable, Dict, List, Optional, Tuple
 
 from ..obs.tracer import current as _trace_current
+from ..utils.timing import degraded
 from .aot import Signature, signature_of
 from .cache import ExecutableCache
 from .fingerprint import (
@@ -199,6 +200,7 @@ class SegmentDispatcher:
                 "segment: undeserializable entry for %s — falling back to "
                 "live compile", self._label or key, exc_info=True,
             )
+            degraded("aot_load")
             self._cache._discard(entry.path, "undeserializable")
             return None
         self._loaded += 1
@@ -258,6 +260,7 @@ class SegmentDispatcher:
                 "(no cross-process caching for this signature)",
                 self._label or key, exc_info=True,
             )
+            degraded("aot_export")
             self._traced += 1
             seg_cost.record_compile(
                 self._digest, time.perf_counter() - t0,
@@ -297,6 +300,7 @@ class SegmentDispatcher:
                 "segment: could not persist %s — executable still serves "
                 "live", self._label or key, exc_info=True,
             )
+            degraded("aot_persist")
             payload = b""
         if payload and self._ledger is not None:
             self._ledger.record(
@@ -484,6 +488,7 @@ class SegmentBinding:
         if self._demoted:
             return
         self._demoted = True
+        degraded("segment_demoted")
         logger.warning(
             "segment %s (%s): %s — demoted to node dispatch",
             self.index, self.label, why, exc_info=True,
@@ -651,6 +656,7 @@ def prewarm_segment_artifacts(
                     "segment prewarm: could not warm %s — skipped",
                     digest[:16], exc_info=True,
                 )
+                degraded("warmup")
     if warmed:
         logger.info("segment prewarm: %d executable(s) warmed", warmed)
     return warmed

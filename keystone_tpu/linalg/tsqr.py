@@ -11,31 +11,13 @@ is cheap and one level suffices).
 
 from __future__ import annotations
 
-from functools import partial
+from functools import lru_cache, partial
 from typing import Optional
 
 import jax
 import jax.numpy as jnp
+from jax import shard_map
 from jax.sharding import Mesh, PartitionSpec as P
-
-try:  # jax ≥ 0.6 exports shard_map at top level
-    from jax import shard_map
-except ImportError:  # older jax: experimental location
-    from jax.experimental.shard_map import shard_map
-
-# The replication-check knob was renamed check_rep → check_vma in a
-# different release than the top-level export, so pick it off the actual
-# signature rather than the import location.
-import inspect as _inspect
-
-_shmap_params = set(_inspect.signature(shard_map).parameters)
-_SHMAP_CHECK = (
-    {"check_vma": False}
-    if "check_vma" in _shmap_params
-    else {"check_rep": False} if "check_rep" in _shmap_params else {}
-)
-
-from functools import lru_cache
 
 from ..parallel.mesh import DATA_AXIS, default_mesh, pad_to_multiple, shard_batch
 
@@ -59,7 +41,7 @@ def _tsqr_fn(mesh: Mesh):
         mesh=mesh,
         in_specs=P(DATA_AXIS, None),
         out_specs=P(None, None),
-        **_SHMAP_CHECK,
+        check_vma=False,
     )
     def _tsqr(A_local):
         R_local = jnp.linalg.qr(A_local, mode="r")

@@ -20,7 +20,6 @@ import jax.numpy as jnp
 
 from ...data.dataset import Dataset
 from ...workflow.transformer import Estimator, Transformer
-from ...utils.jit import nestable_jit
 from ..learning.gmm import (
     GaussianMixtureModel,
     GaussianMixtureModelEstimator,
@@ -28,7 +27,7 @@ from ..learning.gmm import (
 )
 
 
-@nestable_jit
+@jax.jit
 def _fisher_vector(X, means, variances, weights, weight_threshold):
     """X: (n, d, m) batch of descriptor matrices; means/variances (d, k);
     weights (k,). Returns (n, d, 2k)."""

@@ -25,13 +25,12 @@ import numpy as np
 from ...data.dataset import Dataset
 from ...linalg.row_matrix import solve_spd
 from ...utils.timing import phase
-from ...utils.jit import nestable_jit
 from ...workflow.transformer import LabelEstimator, Transformer
 from ...workflow.node_optimization import Optimizable
 from .cost import AutoSolverFrontDoor, CostModel, combine_cost
 
 
-@nestable_jit
+@jax.jit
 def _gaussian_block_xla(X, Xb, gamma):
     """exp(−γ‖x−y‖²) for all (row of X, row of Xb): (n, b)
     (parity: computeKernel, KernelGenerator.scala:138-206)."""

@@ -25,7 +25,6 @@ import numpy as np
 from ...data.dataset import Dataset
 from ...linalg.row_matrix import solve_spd
 from ...parallel.mesh import shard_classes
-from ...utils.jit import nestable_jit
 from ...workflow.node_optimization import Optimizable
 from ...workflow.transformer import LabelEstimator
 from .cost import AutoSolverFrontDoor, CostModel, combine_cost
@@ -74,7 +73,7 @@ def _chunk_grams(A, mask_chunk):
 from ...linalg.weighted import _batched_solve, solve_weighted_streaming
 
 
-@nestable_jit
+@jax.jit
 def _dual_solve_chunk(Q, R, dvec, pm_proj, mu_proj, s3, rhs, lam):
     """Per-class solves in the SAMPLE-SPAN basis, vmapped over a class
     chunk — the few-shot/many-class regime (n ≪ d, e.g. the reference's
@@ -583,7 +582,7 @@ def solve_reweighted_l2(
     return Ws
 
 
-@nestable_jit
+@jax.jit
 def _weighted_gram(Aj, mj, b):
     # no explicit precision= — the _f32_true context governs (an explicit
     # "high" would override it and keep this at bf16_3x)
@@ -591,7 +590,7 @@ def _weighted_gram(Aj, mj, b):
     return jnp.matmul(Ajc.T, Ajc * b[:, None])
 
 
-@nestable_jit
+@jax.jit
 def _reweighted_block_update(Aj, mj, G, Wj_old, R, y_zm, b, reg):
     Ajc = Aj - mj
     # remove this block's contribution from the weighted residual

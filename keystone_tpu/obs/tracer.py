@@ -34,7 +34,8 @@ logger = logging.getLogger(__name__)
 
 # -- XLA compile counting ---------------------------------------------------
 
-#: process-wide count of XLA backend compiles, fed by jax.monitoring.
+#: process-wide count of XLA backend compile requests (persistent-cache
+#: hits included), fed by jax.monitoring.
 #: Listeners cannot be unregistered individually, so this installs once
 #: (lazily, on first Tracer construction) and stays for the process life;
 #: the increment is negligible and only spans read the counter.
@@ -58,8 +59,10 @@ def _install_compile_listener() -> None:
         from jax import monitoring
 
         def _on_duration(event: str, duration: float, **kw) -> None:
-            # one /jax/core/compile/backend_compile_duration per real
-            # XLA compile (cache hits emit cache events instead)
+            # one /jax/core/compile/backend_compile_duration per compile
+            # REQUEST: jax times compile_or_get_cached, so a request the
+            # persistent cache answers fires it too (and additionally a
+            # /jax/compilation_cache/cache_hits event)
             if event.endswith("backend_compile_duration"):
                 global _compiles_seen
                 _compiles_seen = next(_compiles) + 1

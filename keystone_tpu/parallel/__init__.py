@@ -16,6 +16,7 @@ from .mesh import (
     batch_sharding,
     column_sharding,
     default_mesh,
+    device_summary,
     make_mesh,
     mesh_n_data,
     pad_to_multiple,
@@ -33,6 +34,7 @@ __all__ = [
     "column_sharding",
     "data_axis_devices",
     "default_mesh",
+    "device_summary",
     "gather_lane_partials",
     "lane_devices",
     "make_mesh",

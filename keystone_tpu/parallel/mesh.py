@@ -23,6 +23,7 @@ Axis conventions (used consistently across the framework):
 from __future__ import annotations
 
 import contextlib
+import sys
 from typing import Any, Optional, Sequence, Tuple
 
 import jax
@@ -57,6 +58,29 @@ def make_mesh(
         )
     dev_array = np.asarray(devices[:use]).reshape(n_data, n_model)
     return Mesh(dev_array, (DATA_AXIS, MODEL_AXIS))
+
+
+def device_summary() -> dict:
+    """The platform this process got, as jax reports it: ``platform``,
+    ``kind`` and ``count``. Initializes the backend — so on an
+    accelerator only the ONE process that owns the chip calls it."""
+    devices = jax.devices()
+    return {
+        "platform": devices[0].platform,
+        "kind": devices[0].device_kind,
+        "count": len(devices),
+    }
+
+
+def report_platform() -> None:
+    """One stderr line naming the platform this process runs on, printed
+    by the CLI entry points at start — never by a router parent in
+    ``--workers`` mode, which must stay off the device."""
+    d = device_summary()
+    print(
+        f"keystone_tpu: platform {d['platform']} ({d['kind']} x{d['count']})",
+        file=sys.stderr,
+    )
 
 
 def set_default_mesh(mesh: Optional[Mesh]) -> None:

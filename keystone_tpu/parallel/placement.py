@@ -9,6 +9,11 @@ micro-batches while the model axis stays available to each replica's
 executable. A 1-device environment yields co-resident replicas — still
 useful on CPU, where the worker threads overlap host-side work (request
 validation, stacking, D2H) with each other's device compute.
+
+Replicas are THREADS of one process and may share a device on any
+platform. Cluster workers are PROCESSES: they may share a CPU, but an
+accelerator chip belongs to one process at a time, so more worker
+processes than chips is refused (:class:`PlacementError`).
 """
 
 from __future__ import annotations
@@ -16,6 +21,10 @@ from __future__ import annotations
 from typing import Any, List, Optional
 
 from .mesh import default_mesh
+
+
+class PlacementError(ValueError):
+    """The placement asked for cannot exist on this platform."""
 
 
 def data_axis_devices(mesh=None) -> List[Any]:
@@ -36,14 +45,24 @@ def worker_device_indices(
     (worker ``w`` of ``W`` over ``D`` devices owns ``[wD/W, (w+1)D/W)``),
     so the process tier carves the mesh the same way the thread tier
     carves it into replicas. More workers than devices yields
-    co-resident workers (``[w % D]``) — the CPU/1-device case, where
-    separate processes still overlap host-side work across GILs."""
+    co-resident workers (``[w % D]``) on the CPU platform only, where
+    separate processes still overlap host-side work across GILs; on an
+    accelerator it raises :class:`PlacementError`."""
     if not 0 <= worker_id < n_workers:
         raise ValueError(
             f"worker_id {worker_id} outside [0, {n_workers})"
         )
-    n_dev = len(data_axis_devices(mesh))
+    devs = data_axis_devices(mesh)
+    n_dev = len(devs)
     if n_dev < n_workers:
+        platform = devs[0].platform
+        if platform != "cpu":
+            raise PlacementError(
+                f"{n_workers} worker processes over {n_dev} {platform} "
+                "device(s): a chip belongs to one process at a time — "
+                f"use at most {n_dev} worker(s), and replicas (threads) "
+                "to share a chip"
+            )
         return [worker_id % n_dev]
     lo = worker_id * n_dev // n_workers
     hi = (worker_id + 1) * n_dev // n_workers

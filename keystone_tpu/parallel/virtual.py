@@ -8,7 +8,6 @@ Used by tests/conftest.py (fixed 8-device mesh for the suite) and by
 
 from __future__ import annotations
 
-import logging
 import os
 from typing import Optional
 
@@ -20,8 +19,7 @@ def provision_virtual_devices(n_devices: int) -> None:
 
     Importing this module already pulls in jax (via the package __init__),
     so this always works through the live config: tear down any initialized
-    backend (e.g. the driver's single real TPU chip), then point the config
-    at an N-device CPU platform. The env vars are also set so child
+    backend, then point the config at an N-device CPU platform. The env vars are also set so child
     processes inherit the same view. The switch is one-way: after this
     call, everything in the process runs on virtual CPU devices — callers
     that still need the real accelerator must use a separate process.
@@ -68,33 +66,17 @@ def provision_virtual_devices(n_devices: int) -> None:
         )
 
     import jax
+    import jax.extend.backend
+    from jax._src import xla_bridge
 
-    try:
-        from jax._src import xla_bridge
-
-        initialized = xla_bridge.backends_are_initialized()
-    except Exception:
-        logging.getLogger(__name__).debug(
-            "jax backend-initialization probe failed; assuming initialized",
-            exc_info=True,
-        )
-        initialized = True
-    if initialized:
+    if xla_bridge.backends_are_initialized():
         # Drop the live backend so the next jax.devices() re-reads the
         # config. Must happen before the config updates below
         # (num_cpu_devices rejects changes post-init). The public API also
         # flushes the get_backend memo and jit caches.
-        import jax.extend.backend
-
         jax.extend.backend.clear_backends()
     jax.config.update("jax_platforms", "cpu")
-    try:
-        jax.config.update("jax_num_cpu_devices", n_devices)
-    except Exception:
-        # older jax: the XLA_FLAGS path above still applies
-        logging.getLogger(__name__).debug(
-            "jax_num_cpu_devices knob absent", exc_info=True
-        )
+    jax.config.update("jax_num_cpu_devices", n_devices)
     if len(jax.devices()) < n_devices:
         raise RuntimeError(
             f"could not provision {n_devices} virtual CPU devices "

@@ -59,14 +59,28 @@ def build_demo_fitted(
     return fitted, np.asarray(test.data.to_array())
 
 
-def _serve_through_cluster(args, fitted, data, buckets) -> int:
+def _serve_through_cluster(args, buckets) -> int:
     """The ``--workers N`` path: a ClusterRouter over N worker processes,
     each rebuilding the SAME deterministic pipeline (same fingerprint ⇒
     warm boot from the shared AOT cache when one is configured) and
-    serving it from a local fleet of ``--replicas`` replicas."""
+    serving it from a local fleet of ``--replicas`` replicas.
+
+    This parent never initializes a jax backend: an accelerator chip
+    belongs to one process, and the workers need it. So its request rows
+    are seeded numpy, and its check is consistency instead of a local
+    ``fitted.apply``: every row is served twice, under different arrival
+    orders and batch-mates (with several workers, by whichever process
+    the router picks), and the two replies must agree."""
+    from jax._src import xla_bridge
+
     from .. import compile as compile_mod
     from ..cluster import ClusterRouter
+    from ..pipelines.mnist_random_fft import MNIST_IMAGE_SIZE
 
+    held_backend = xla_bridge.backends_are_initialized()
+    data = np.random.default_rng(0).standard_normal(
+        (args.requests, MNIST_IMAGE_SIZE)
+    ).astype(np.float32)
     # --tenants "gold:3,bronze:1": weighted-fair shares in the worker
     # fleets, traffic round-robined across the named tenants so the
     # --status QoS section has shares to render
@@ -104,6 +118,7 @@ def _serve_through_cluster(args, fitted, data, buckets) -> int:
     with router:
         with ThreadPoolExecutor(max_workers=args.clients) as pool:
             preds = list(pool.map(_one, enumerate(data)))
+            again = list(pool.map(_one, reversed(list(enumerate(data)))))
         snap = router.snapshot()
         reports = [r for r in router.worker_reports if r]
         if args.status:
@@ -113,11 +128,9 @@ def _serve_through_cluster(args, fitted, data, buckets) -> int:
             # timelines, worker liveness/restart budgets, SLO verdicts
             # (reuses the snapshot above — one stats round-trip, not two)
             print(format_status(router.status(snap=snap)))
-    expected = (
-        np.asarray(fitted.apply(data).to_array())
-        if len(data) else np.array([])
-    )
-    agree = int(np.sum(np.asarray(preds).ravel() == expected.ravel()))
+    agree = int(np.sum(
+        np.asarray(preds).ravel() == np.asarray(again[::-1]).ravel()
+    ))
     c = snap["counters"]
     lat = snap["latency"]
     compiles = sum(r.get("compiles", 0) for r in reports)
@@ -133,9 +146,13 @@ def _serve_through_cluster(args, fitted, data, buckets) -> int:
         f"p50={lat.get('p50', 0):.4f}s p99={lat.get('p99', 0):.4f}s "
         f"workers={args.workers} shed={c.get('shed', 0)} "
         f"restarts={c.get('restarts', 0)} "
-        f"per_worker_batches={worker_batches}"
+        f"per_worker_batches={worker_batches} "
+        f"platform={','.join(sorted({str(r.get('platform')) for r in reports}))}"
     )
-    ok = agree == len(data) and c.get("completed", 0) == len(data)
+    ok = agree == len(data) and c.get("completed", 0) == 2 * len(data)
+    if xla_bridge.backends_are_initialized() and not held_backend:
+        print("SERVE FAIL: the router parent initialized a jax backend")
+        ok = False
     if len(reports) < args.workers:
         print(f"SERVE FAIL: only {len(reports)}/{args.workers} workers ready")
         ok = False
@@ -206,16 +223,19 @@ def main(argv: Optional[List[str]] = None) -> int:
     args = p.parse_args(argv)
     buckets = tuple(int(b) for b in args.buckets.split(","))
 
+    if args.workers > 0:
+        return _serve_through_cluster(args, buckets)
+
+    from ..parallel.mesh import report_platform
     from .engine import ServingEngine
     from .fleet import ServingFleet
 
+    report_platform()
     fitted, test_data = build_demo_fitted(
         num_ffts=args.numFFTs, block_size=args.blockSize, lam=args.lam,
         n_train=args.nTrain, n_test=args.requests,
     )
     data = test_data[: args.requests]
-    if args.workers > 0:
-        return _serve_through_cluster(args, fitted, data, buckets)
     if args.replicas > 1:
         engine = ServingFleet(
             fitted,

@@ -59,6 +59,7 @@ from ..faults import ReplicaKilled
 from ..obs import flight as _flight
 from ..obs import resource as _resource
 from ..obs.tracer import current as _trace_current
+from ..utils.timing import degraded
 from ..workflow.pipeline import FittedPipeline
 from .batching import BucketPolicy
 from .errors import CanaryMismatch, EngineStopped
@@ -370,6 +371,7 @@ class ServingFleet:
                 "fleet warm-up: segment pre-warm failed — warm fits will "
                 "load lazily", exc_info=True,
             )
+            degraded("warmup")
 
     def _distinct_devices(self) -> list:
         seen, out = set(), []

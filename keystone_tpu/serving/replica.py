@@ -182,6 +182,7 @@ def _build_aot_dispatcher(fitted, fn, note_trace, metrics, label):
         logger.info(
             "serving: AOT cache skipped (pipeline not fingerprintable): %s", e
         )
+        timing.degraded("aot_fingerprint")
         return None
     except Exception:
         # e.g. RecursionError on self-referential operator state: a
@@ -191,6 +192,7 @@ def _build_aot_dispatcher(fitted, fn, note_trace, metrics, label):
             "serving: AOT cache skipped (fingerprinting failed)",
             exc_info=True,
         )
+        timing.degraded("aot_fingerprint")
         return None
 
     def _note_load(sig):

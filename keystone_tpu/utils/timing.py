@@ -81,6 +81,14 @@ def record(name: str, seconds: float) -> None:
         _counts[name] += 1
 
 
+def degraded(site: str) -> None:
+    """A catch-and-degrade site fired: the program kept running, on a
+    slower or older path (a demoted segment, a refused export, a skipped
+    warm-up). Counted as ``degrade.<site>`` so a smoke or benchmark can
+    refuse a run that quietly fell back — ``snapshot("degrade.")``."""
+    record("degrade." + site, 0.0)
+
+
 def reset() -> None:
     """Clear phase totals AND the obs rate-limiter state: a fresh
     measurement epoch (back-to-back bench runs in one process) must get

@@ -36,6 +36,7 @@ from concurrent.futures import ThreadPoolExecutor
 from typing import Any, Dict, List, Optional, Tuple
 
 from ..obs.tracer import current as _trace_current
+from ..utils.timing import degraded
 from .env import PipelineEnv
 from .expressions import DatasetExpression, Expression
 from .graph import Graph, GraphId, NodeId, SinkId, SourceId
@@ -303,6 +304,7 @@ class GraphExecutor:
                 "segment planning failed — node dispatch for this executor",
                 exc_info=True,
             )
+            degraded("segment_planning")
             return {}
 
     def _execute_segment(

@@ -86,6 +86,30 @@ def test_a_predict_parity_and_load_spread(router, data, expected):
     assert snap["latency"]["count"] >= n
 
 
+def test_a2_boot_spec_carries_the_platform(router):
+    # the platform this process was asked for (conftest: cpu) rides the
+    # boot spec, and every worker reports the platform it came up on
+    assert router._spec["platform"] == "cpu"
+    reports = router.worker_reports
+    assert [r["platform"] for r in reports] == ["cpu", "cpu"]
+
+
+def test_a3_a_worker_boot_failure_is_raised_typed_by_start():
+    # the worker refuses the contract at boot; start() must raise THAT
+    # error, inside the spawn timeout — not "check worker stderr"
+    from keystone_tpu.check import ContractMismatchError
+
+    bad = ClusterRouter(
+        ("factory", "keystone_tpu.cluster.demo:build_demo_model",
+         {"num_ffts": 2, "block_size": 256, "n_train": 256}),
+        workers=1, buckets=(8,), datum_shape=(783,), spawn_timeout_s=120,
+    )
+    t0 = time.monotonic()
+    with pytest.raises(ContractMismatchError):
+        bad.start()
+    assert time.monotonic() - t0 < 120
+
+
 def test_b_deadline_crosses_the_process_boundary(router, data):
     # the router's estimate is COLD (no observe_service, health pongs
     # disabled), so the front door cannot shed — an already-expired

@@ -132,28 +132,69 @@ def test_engine_cold_then_warm_boot_zero_traces(tmp_path):
     assert np.array_equal(np.asarray(preds_cold), np.asarray(preds_warm))
 
 
-def test_configure_relocates_default_xla_cache_but_not_a_chosen_one(tmp_path):
-    """The whole warm-boot state must live in ONE mountable dir: the
-    package-default XLA cache relocates under the AOT dir; an
-    operator-chosen dir is respected. reset() restores either way."""
+def test_configure_never_moves_the_xla_cache(tmp_path):
+    """The XLA compilation cache is placed once, at import — by
+    JAX_COMPILATION_CACHE_DIR, else at the fixed in-checkout path — and
+    compile.configure(dir) leaves it there (the path is part of the key:
+    a cache that moves never hits). configure only zeroes the minimum
+    compile time for persisting; reset() puts the package default back."""
+    import os
+
     import jax
 
     import keystone_tpu as pkg
 
-    before = jax.config.jax_compilation_cache_dir
+    placed = os.environ.get("JAX_COMPILATION_CACHE_DIR") or pkg.COMPILE_CACHE_DIR
+    assert jax.config.jax_compilation_cache_dir == placed
     cmod.configure(str(tmp_path))
     try:
-        if before and before != getattr(pkg, "_default_xla_cache_dir", None):
-            # operator-chosen (env/config): must be untouched
-            assert jax.config.jax_compilation_cache_dir == before
-        else:
-            assert jax.config.jax_compilation_cache_dir == str(
-                tmp_path / "xla"
-            )
+        assert jax.config.jax_compilation_cache_dir == placed
         assert jax.config.jax_persistent_cache_min_compile_time_secs == 0.0
+        assert not (tmp_path / "xla").exists()
     finally:
         cmod.reset()
-    assert jax.config.jax_compilation_cache_dir == before
+    assert jax.config.jax_compilation_cache_dir == placed
+    assert (
+        jax.config.jax_persistent_cache_min_compile_time_secs
+        == pkg.PERSIST_MIN_COMPILE_SECS
+    )
+
+
+def test_cache_placement_from_the_environment(tmp_path):
+    """Set → that directory, before and after configure(); unset → the
+    fixed in-checkout path, also after configure(). Checked in fresh
+    interpreters: placement happens at package import."""
+    import os
+    import subprocess
+    import sys
+
+    probe = (
+        "import jax, keystone_tpu\n"
+        "from keystone_tpu import compile as c\n"
+        "print(jax.config.jax_compilation_cache_dir)\n"
+        f"c.configure({str(tmp_path / 'aot')!r})\n"
+        "print(jax.config.jax_compilation_cache_dir)\n"
+        "print(keystone_tpu.COMPILE_CACHE_DIR)\n"
+    )
+
+    def run(env_dir):
+        env = {k: v for k, v in os.environ.items()
+               if k != "JAX_COMPILATION_CACHE_DIR"}
+        if env_dir:
+            env["JAX_COMPILATION_CACHE_DIR"] = env_dir
+        root = os.path.dirname(os.path.dirname(os.path.dirname(
+            os.path.abspath(__file__)
+        )))
+        out = subprocess.run(
+            [sys.executable, "-c", probe], env=env, cwd=root,
+            capture_output=True, text=True, timeout=120, check=True,
+        ).stdout.split()
+        return out, root
+
+    (before, after, _), _ = run(str(tmp_path / "placed"))
+    assert before == after == str(tmp_path / "placed")
+    (before, after, fixed), root = run(None)
+    assert before == after == fixed == os.path.join(root, ".jax_cache")
 
 
 def test_engine_without_cache_behaves_exactly_as_before(tmp_path):

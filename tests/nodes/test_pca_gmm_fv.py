@@ -176,3 +176,47 @@ def test_gmm_csv_load_roundtrip(tmp_path):
     )
     np.testing.assert_allclose(np.asarray(gmm.means), means)
     assert gmm.k == 2 and gmm.dim == 4
+
+
+def test_posteriors_eager_and_nested_in_jit_match_float64_oracle():
+    """`_posteriors` is a jitted helper that the FisherVector program calls
+    from inside its own jit: called at top level, or nested in an outer
+    trace, it must agree with itself and with a float64 oracle (a nested
+    call once miscompiled on an experimental backend; PR 21 re-ran this on
+    a TPU v5e with plain jax.jit and it held)."""
+    import jax
+
+    from keystone_tpu.nodes.learning.gmm import _posteriors
+
+    rng = np.random.default_rng(0)
+    X = (rng.standard_normal((512, 8)) * 5).astype(np.float32)
+    # one descriptor with a large-magnitude coordinate, like real PCA'd SIFT
+    X[0, 0] = -36.6
+    means = rng.standard_normal((2, 8)).astype(np.float32)
+    var = (2.0 * (1 + rng.random((2, 8)))).astype(np.float32)
+    w = np.array([0.7, 0.3], dtype=np.float32)
+    thr = 1e-4
+
+    x64, m64, v64, w64 = (a.astype(np.float64) for a in (X, means, var, w))
+    ll = np.stack(
+        [
+            -0.5 * np.sum((x64 - m64[j]) ** 2 / v64[j], axis=1)
+            - 0.5 * np.sum(np.log(2 * np.pi * v64[j]))
+            + np.log(w64[j])
+            for j in range(len(w64))
+        ],
+        axis=1,
+    )
+    ll -= ll.max(axis=1, keepdims=True)
+    q = np.exp(ll)
+    q /= q.sum(axis=1, keepdims=True)
+    q = np.where(q > thr, q, 0.0)
+    q /= q.sum(axis=1, keepdims=True)
+
+    q_eager = np.asarray(_posteriors(X, means, var, w, thr))
+    q_nested = np.asarray(
+        jax.jit(lambda x: _posteriors(x, means, var, w, thr))(X)
+    )
+    np.testing.assert_allclose(q_nested, q_eager, atol=1e-4)
+    np.testing.assert_allclose(q_eager, q, atol=1e-3)
+    np.testing.assert_allclose(q_nested, q, atol=1e-3)
