@@ -16,11 +16,16 @@ test rows of the seeded synthetic task, generated in HBM). Three legs:
   of the same algebra.
 
 It refuses anything but a TPU, fails if any catch-and-degrade site fired
-on its path, prints one JSON object as the last line of stdout, and exits
-0 only if every leg passed. Times in the JSON are bring-up facts, not
-benchmark numbers. The legs are plain functions with size arguments:
-tier-1 and the CPU rehearsal call them at tiny sizes; ``__main__`` has no
-CPU mode.
+on its path, and exits 0 only if every leg passed. Stdout is two lines of
+JSON: the report (per leg what ran, compile and cache counts, peak HBM;
+its times are bring-up facts, not benchmark numbers), then LAST the
+verdict the driver parses, exactly
+``{"ok": true, "device": {"platform": "tpu", "kind": "...", "count": 1}}``
+with the device as jax reports it — nothing else goes on that line. Run
+without an accelerator, or in a directory that holds none of the repo, it
+prints neither and exits non-zero. The legs are plain functions with size
+arguments: tier-1 and the CPU rehearsal call them at tiny sizes;
+``__main__`` has no CPU mode.
 """
 
 from __future__ import annotations
@@ -52,7 +57,11 @@ KERNEL_SHAPE = dict(n=8192, d=512, b=2048)
 
 def require_tpu() -> dict:
     """The device as jax reports it; exits 2 unless it is a TPU."""
-    from keystone_tpu.parallel.mesh import device_summary
+    try:
+        from keystone_tpu.parallel.mesh import device_summary
+    except ImportError as e:
+        print(f"chip_smoke: the program is not here: {e}", file=sys.stderr)
+        raise SystemExit(2)
 
     try:
         found = device_summary()
@@ -395,6 +404,19 @@ def sync_check(*, size, steps):
     }
 
 
+def verdict_line(ok: bool, device: dict) -> str:
+    """The last line of stdout: the two keys the driver's check reads and
+    no others (the report line above it carries everything else)."""
+    return json.dumps({
+        "ok": bool(ok),
+        "device": {
+            "platform": str(device["platform"]),
+            "kind": str(device["kind"]),
+            "count": int(device["count"]),
+        },
+    })
+
+
 def main() -> int:
     os.environ.setdefault("JAX_PLATFORMS", "tpu")
     device = require_tpu()
@@ -444,7 +466,7 @@ def main() -> int:
 
     fallbacks = fallbacks_fired()
     ok = all(report["ok"] for report in legs.values()) and not fallbacks
-    result = {
+    report = {
         "ok": bool(ok),
         "device": device,
         "jax": jax.__version__,
@@ -460,7 +482,8 @@ def main() -> int:
         "claim": None,
     }
     sys.stderr.flush()
-    print(json.dumps(result), flush=True)
+    print(json.dumps(report))
+    print(verdict_line(ok, device), flush=True)
     return 0 if ok else 1
 
 

@@ -37,6 +37,36 @@ def test_script_refuses_the_cpu_and_names_it():
     assert proc.stdout.strip() == ""  # no result line
 
 
+def test_script_alone_fails_without_a_result(tmp_path):
+    """In a directory that holds the script and nothing else of the repo
+    there is no program to drive: non-zero, no result line."""
+    import shutil
+
+    shutil.copy(os.path.join(ROOT, "chip_smoke.py"), tmp_path)
+    env = {k: v for k, v in os.environ.items() if k != "PYTHONPATH"}
+    proc = subprocess.run(
+        [sys.executable, "chip_smoke.py"], cwd=tmp_path, env=env,
+        capture_output=True, text=True, timeout=120,
+    )
+    assert proc.returncode != 0
+    assert "not here" in proc.stderr
+    assert proc.stdout.strip() == ""
+
+
+def test_verdict_line_has_the_drivers_keys_and_no_others():
+    import json
+
+    line = chip_smoke.verdict_line(
+        np.bool_(True),
+        {"platform": "tpu", "kind": "TPU v5 lite", "count": np.int64(1)},
+    )
+    assert "\n" not in line
+    assert json.loads(line) == {
+        "ok": True,
+        "device": {"platform": "tpu", "kind": "TPU v5 lite", "count": 1},
+    }
+
+
 def test_fit_then_serve_legs_at_tiny_size(tmp_path):
     cmod.configure(str(tmp_path))
     report, fitted, rows = chip_smoke.fit_leg(
