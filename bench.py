@@ -517,7 +517,6 @@ def bench_krr() -> dict:
     model = None
     for trial in range(4):
         profiled = trial < 2
-        timing.enable(profiled)
         if profiled:
             timing.reset()
         est = KernelRidgeRegression(
@@ -532,7 +531,6 @@ def bench_krr() -> dict:
             phase_tables.append(timing.snapshot())
         if model is None:
             model = m_i
-    timing.enable(False)
     t_fit = min(fit_attempts)
     n_blocks = -(-n // bs)
     # flop model: per block kernel-gen 2·n·b·d + residual 2·n·b·k +
@@ -796,7 +794,6 @@ def bench_mnist() -> dict:
     # wall-clock — the headline is still the honest end-to-end cost of a
     # profiled run, and the tables attribute it. Disabled again before
     # return so later benches choose their own scope (ADVICE r3).
-    timing.enable()
 
     data_source = "synthetic"
     train = test = None
@@ -1078,7 +1075,6 @@ def bench_mnist() -> dict:
         1e-9,
     )
     peak = _device_peak_flops()
-    timing.enable(False)
     return {
         "seconds": round(total, 3),
         "phases": {
@@ -1233,7 +1229,6 @@ def bench_imagenet_fv() -> dict:
         # steady fit time. Min reported as the headline, both recorded.
         from keystone_tpu.workflow.env import PipelineEnv
 
-        timing.enable()  # own scope (no dependence on bench order)
         fit_attempts = []
         fit_phase_attempts = []
         fitted = None
@@ -1248,7 +1243,6 @@ def bench_imagenet_fv() -> dict:
                 fitted = fitted_i
         t_fit = min(fit_attempts)
         fit_phases = fit_phase_attempts[fit_attempts.index(t_fit)]
-        timing.enable(False)
 
         # held-out top-5 error (the reference's quality metric, :139-141),
         # via the eager executor
@@ -1649,7 +1643,6 @@ def _bench_imagenet_streaming_fit() -> dict:
 
     from keystone_tpu.workflow.env import PipelineEnv
 
-    timing.enable()
     fit_attempts = []
     phase_tables = []
     fitted = None
@@ -1664,7 +1657,6 @@ def _bench_imagenet_streaming_fit() -> dict:
         phase_tables.append(timing.snapshot())
         if fitted is None:
             fitted = fitted_i
-    timing.enable(False)
     t_fit = min(fit_attempts)
 
     te_pred = np.asarray(fitted.apply(te_ds).to_array())
@@ -4795,10 +4787,8 @@ def bench_resource_accounting() -> dict:
     # they'd be one per batch)
     import logging as _logging
 
-    prior_profiling = timing._profiling
     timing_logger = _logging.getLogger("keystone_tpu.utils.timing")
     prior_level = timing_logger.level
-    timing.enable(True)
     timing_logger.setLevel(_logging.WARNING)
     try:
         busy_before = (
@@ -4820,7 +4810,6 @@ def bench_resource_accounting() -> dict:
     finally:
         # drop the rest of the backlog — EngineStopped on unread futures
         fleet.shutdown(drain=False)
-        timing.enable(prior_profiling)
         timing_logger.setLevel(prior_level)
     costs = snap.get("costs") or {}
 

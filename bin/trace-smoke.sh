@@ -38,6 +38,12 @@
 # stitched trace carries wire.encode/wire.decode spans, and a second run
 # under the KEYSTONE_WIRE_CODEC=pickle kill switch returns bit-equal
 # outputs.
+# A twelfth stage (the span primitive) runs a tiny TIMIT job with NO tracer
+# installed under a plain `jax.profiler` session and asserts that any
+# profiler session records the spans: `obs.tracer.session_spans()` holds
+# `job`, `plan.*`, `exec.segment`, `block_ls.solve`, `xfer.d2h`,
+# `eval.metrics`, unsynced, and the xplane's host plane holds the same
+# regions as `ks:` annotations.
 set -euo pipefail
 cd "$(dirname "$0")/.."
 out="${1:-$(mktemp /tmp/keystone-trace-XXXXXX.json)}"
@@ -58,6 +64,46 @@ assert any(
     e.get("args", {}).get("cache") for e in events
 ), "no cache-annotated DAG-node spans"
 print(f"TRACE OK: {len(events)} events -> {sys.argv[1]}")
+PY
+
+# -- the span primitive under a plain profiler session ------------------------
+env JAX_PLATFORMS=cpu python - <<'PY'
+import glob
+import os
+import tempfile
+
+import jax
+
+from keystone_tpu.obs import tracer
+from keystone_tpu.pipelines.timit import TimitConfig, run, synthetic_timit
+
+conf = TimitConfig(num_cosines=2, cosine_features=64, num_classes=5,
+                   num_epochs=2)
+train, test = synthetic_timit(256, 5, seed=1), synthetic_timit(64, 5, seed=2)
+assert tracer.current() is None, "this stage runs with no tracer installed"
+trace_dir = tempfile.mkdtemp(prefix="keystone-session-")
+with jax.profiler.trace(trace_dir):
+    run(train, test, conf)
+spans = tracer.session_spans()
+names = {sp.name for sp in spans}
+want = {"job", "plan.build", "plan.optimize", "plan.rule", "plan.segments",
+        "pipeline.pull", "exec.segment", "block_ls.solve", "xfer.h2d",
+        "xfer.d2h", "eval.metrics"}
+assert want <= names, sorted(want - names)
+assert all(sp.sync_seconds == 0.0 for sp in spans), "a session never syncs"
+(path,) = glob.glob(
+    os.path.join(trace_dir, "plugins", "profile", "*", "*.xplane.pb")
+)
+annotated = {
+    ev.name
+    for plane in jax.profiler.ProfileData.from_file(path).planes
+    if plane.name.startswith("/host:")
+    for line in plane.lines
+    for ev in line.events
+    if ev.name.startswith("ks:")
+}
+assert {"ks:" + n for n in names} == annotated, (names, annotated)
+print(f"SESSION SPANS OK: {len(spans)} spans, {len(annotated)} ks: names")
 PY
 
 # -- pipelined-scan spans ----------------------------------------------------

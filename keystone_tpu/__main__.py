@@ -154,7 +154,7 @@ def main(argv: Optional[List[str]] = None) -> int:
     #: must be unambiguous against these too, exactly as argparse would
     #: treat it (--tra must stay an error between --trace/--trainer-demo)
     _OTHER_FLAGS = (
-        "--backend", "--cpuDevices", "--log", "--logLevel", "--profile",
+        "--backend", "--cpuDevices", "--log", "--logLevel",
         "--check", "--trace", "--aot-cache", "--profiles",
     )
 
@@ -233,11 +233,6 @@ def main(argv: Optional[List[str]] = None) -> int:
         help="log verbosity (default: $KEYSTONE_LOG or warning)",
     )
     p.add_argument(
-        "--profile", action="store_true",
-        help="per-phase device-time logs in the hot solvers "
-             "(also: KEYSTONE_PROFILE=1)",
-    )
-    p.add_argument(
         "--check", action="store_true", dest="check_only",
         help="static-check mode: build the pipeline, run the whole-DAG "
              "shape/dtype/traceability checker and segment planner "
@@ -272,7 +267,7 @@ def main(argv: Optional[List[str]] = None) -> int:
     from .utils.obs import configure, export_trace
 
     configure(
-        args.log_level, profile=args.profile or None, trace=args.trace,
+        args.log_level, trace=args.trace,
         aot_cache=args.aot_cache, profiles=args.profiles,
     )
     _select_backend(args.backend, args.cpuDevices)

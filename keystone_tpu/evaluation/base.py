@@ -7,6 +7,8 @@ from typing import Any
 
 import numpy as np
 
+from ..utils.params import to_host
+
 
 def resolve(x: Any) -> np.ndarray:
     """Materialize predictions/labels: PipelineDataset → Dataset → array."""
@@ -17,7 +19,7 @@ def resolve(x: Any) -> np.ndarray:
         x = x.get()
     if isinstance(x, Dataset):
         x = x.to_array()
-    return np.asarray(x)
+    return to_host(x)  # a device array is read back under an xfer.d2h span
 
 
 class Evaluator:

@@ -15,6 +15,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from ..obs.tracer import span
+from ..utils.params import to_device, to_host
 from .base import Evaluator, resolve
 
 
@@ -163,9 +165,17 @@ class MulticlassClassifierEvaluator(Evaluator):
         self.num_classes = num_classes
 
     def evaluate(self, predictions: Any, actuals: Any) -> MulticlassMetrics:
-        preds = jnp.asarray(resolve(predictions), dtype=jnp.int32).ravel()
-        acts = jnp.asarray(resolve(actuals), dtype=jnp.int32).ravel()
-        if preds.shape[0] != acts.shape[0]:
-            raise ValueError("predictions and actuals differ in length")
-        cm0 = jnp.zeros((self.num_classes, self.num_classes))
-        return MulticlassMetrics(_confusion(preds, acts, cm0))
+        with span("eval.metrics", classes=self.num_classes):
+            preds = _device_labels(predictions)
+            acts = _device_labels(actuals)
+            if preds.shape[0] != acts.shape[0]:
+                raise ValueError("predictions and actuals differ in length")
+            cm0 = jnp.zeros((self.num_classes, self.num_classes))
+            return MulticlassMetrics(to_host(_confusion(preds, acts, cm0)))
+
+
+def _device_labels(x: Any) -> jax.Array:
+    """``x`` as flat int32 labels on the device. ``resolve`` materializes
+    on the host, so predictions that sit on the device make the round
+    trip — the read-back is where a job waits for its apply to finish."""
+    return jnp.asarray(to_device(resolve(x)), dtype=jnp.int32).ravel()

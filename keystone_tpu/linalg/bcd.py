@@ -28,6 +28,7 @@ import jax.numpy as jnp
 # stack); re-exported here because bcd is where the precision decision is
 # most visible to solver readers.
 from ..data.pipeline_scan import scan_pipeline
+from ..obs.tracer import span
 from .row_matrix import SOLVER_PRECISION, _mm, solve_spd  # noqa: F401
 
 
@@ -120,8 +121,6 @@ def solve_blockwise_l2(
     fewer sweeps than from zero; the prediction buffer is initialized
     consistently (pred = Σ Ãⱼ Wⱼ⁰). Returns per-block (b_j, k) weights.
     """
-    from ..utils.timing import phase
-
     y = jnp.asarray(y, dtype=dtype)
     n, k = y.shape
     blocks = [jnp.asarray(b, dtype=dtype) for b in blocks]
@@ -139,14 +138,14 @@ def solve_blockwise_l2(
         pred = jnp.zeros_like(y)
         for Aj, mj, Wj in zip(blocks, means, Ws):
             pred = pred + _mm(Aj - mj, Wj)
-    # Per-block phase logging (parity: KernelRidgeRegression.scala:216-224's
+    # Per-block spans (parity: KernelRidgeRegression.scala:216-224's
     # per-block phase table). Gram/solve/update run as ONE compiled program
-    # per block shape, so one phase covers the device step.
+    # per block shape, so one span covers the device step.
     for _ in range(num_iter):
         for j, Aj in enumerate(blocks):
-            with phase("bcd.block_update") as out:
+            with span("bcd.block_update") as sp:
                 Ws[j], pred = _block_update(Aj, means[j], Ws[j], pred, y, reg)
-                out.append(pred)
+                sp.sync_on(pred)
     return Ws
 
 
@@ -393,8 +392,6 @@ def solve_blockwise_l2_streaming(
     jprev = 0
     prev_size = sizes[0]
 
-    from ..utils.timing import phase
-
     reg = jnp.asarray(reg, dtype)
     for epoch in range(num_iter):
         for b in range(nblocks):
@@ -407,7 +404,7 @@ def solve_blockwise_l2_streaming(
             )
             c = jnp.zeros((sizes[b], k), dtype=dtype)
             row0 = 0
-            with phase("bcd.stream_block") as out:
+            with span("bcd.stream_block") as sp:
                 for chunk in scan_pipeline(chunk_scan(), label="bcd.stream"):
                     chunk = jnp.asarray(chunk, dtype=dtype)
                     pred, G, c = _stream_chunk_update(
@@ -430,7 +427,7 @@ def solve_blockwise_l2_streaming(
                 Ws[b] = W_new
                 jprev = starts[b]
                 prev_size = sizes[b]
-                out.append(W_new)
+                sp.sync_on(W_new)
     return Ws
 
 
@@ -501,8 +498,6 @@ def _solve_blockwise_l2_streaming_lanes(
         record_scan_collectives,
         reduce_lane_partials,
     )
-    from ..utils.timing import phase
-
     n, k = y_zm.shape
     nblocks = len(starts)
     devs = lane_devices(lanes)
@@ -538,7 +533,7 @@ def _solve_blockwise_l2_streaming_lanes(
             )
             record_scan_collectives(pipe, (2 if do_prev else 1) * lanes)
             row0 = 0
-            with phase("bcd.stream_block") as out:
+            with span("bcd.stream_block") as sp:
                 for i, chunk in enumerate(pipe):
                     chunk = jnp.asarray(chunk, dtype=dtype)
                     rows = int(chunk.shape[0])
@@ -604,7 +599,7 @@ def _solve_blockwise_l2_streaming_lanes(
                 Ws[b] = W_new
                 jprev = starts[b]
                 prev_size = sizes[b]
-                out.append(W_new)
+                sp.sync_on(W_new)
     return Ws
 
 
@@ -657,11 +652,19 @@ def _bcd_scan_impl(A, y, reg, means, init=None, *, block_size, num_iter):
                 mj = jax.lax.dynamic_slice_in_dim(means, j * block_size, block_size)
                 Aj = Aj - mj
             Wj = W[j]
-            r = y - pred + _mm(Aj, Wj)
-            G = _mm(Aj.T, Aj)
-            c = _mm(Aj.T, r)
-            Wj_new = solve_spd(G, c, reg)
-            pred = pred + _mm(Aj, Wj_new - Wj)
+            # named scopes are metadata (a profile groups device time by
+            # them, the benchmark's readers match on them): they change
+            # neither the lowering nor the persistent cache's key
+            with jax.named_scope("ks.solver.residual"):
+                r = y - pred + _mm(Aj, Wj)
+            with jax.named_scope("ks.solver.gram"):
+                G = _mm(Aj.T, Aj)
+            with jax.named_scope("ks.solver.cross"):
+                c = _mm(Aj.T, r)
+            with jax.named_scope("ks.solver.factor_solve"):
+                Wj_new = solve_spd(G, c, reg)
+            with jax.named_scope("ks.solver.residual"):
+                pred = pred + _mm(Aj, Wj_new - Wj)
             W = W.at[j].set(Wj_new)
             return (W, pred), None
 

@@ -28,6 +28,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from ..data.pipeline_scan import scan_pipeline
+from ..obs.tracer import span
 from ..parallel.mesh import shard_classes
 from .accumulators import MomentsState, _np
 
@@ -301,8 +302,6 @@ def _solve_weighted_streaming_serial(
     """Single-lane body: resident (n, k) residual updated in row slices,
     one accumulator set, model-axis (``shard_classes``) parallelism over
     the per-class Grams and solves."""
-    from ..utils.timing import phase
-
     Y = jnp.asarray(Y, dtype=jnp.float32)
     n, k = Y.shape
     y_idx = jnp.argmax(Y, axis=1)
@@ -329,7 +328,7 @@ def _solve_weighted_streaming_serial(
             class_sums = jnp.zeros((k, bs), jnp.float32)
             pop_sum = jnp.zeros((bs,), jnp.float32)
             row0 = 0
-            with phase("wls.stream_cross") as out:
+            with span("wls.stream_cross") as sp:
                 for chunk in scan_pipeline(chunk_scan(), label="wls.stream"):
                     chunk = jnp.asarray(chunk, dtype=jnp.float32)
                     R, xtR, xtRc, G, class_sums, pop_sum = _wls_scan1(
@@ -348,7 +347,7 @@ def _solve_weighted_streaming_serial(
                     raise ValueError(
                         f"chunk source produced {row0} rows, labels {n}"
                     )
-                out.append(xtR)
+                sp.sync_on(xtR)
             if do_stats:
                 pop_mean = pop_sum / n
                 class_means = class_sums / safe_counts[:, None]
@@ -381,7 +380,7 @@ def _solve_weighted_streaming_serial(
                     jnp.zeros((Ccur, bs, bs), jnp.float32)
                 )
                 row0 = 0
-                with phase("wls.stream_grams") as out:
+                with span("wls.stream_grams") as sp:
                     for chunk in scan_pipeline(
                         chunk_scan(), label="wls.stream"
                     ):
@@ -391,7 +390,7 @@ def _solve_weighted_streaming_serial(
                             bs=bs, C=Ccur,
                         )
                         row0 += int(chunk.shape[0])
-                    out.append(grams)
+                    sp.sync_on(grams)
                 delta_cols.append(
                     _wls_class_delta(
                         grams, counts, class_means, pop_mean, joint_means,
@@ -463,8 +462,6 @@ def _solve_weighted_streaming_lanes(
         record_scan_collectives,
         reduce_lane_partials,
     )
-    from ..utils.timing import phase
-
     Y = jnp.asarray(Y, dtype=jnp.float32)
     n, k = Y.shape
     y_idx = jnp.argmax(Y, axis=1)
@@ -504,7 +501,7 @@ def _solve_weighted_streaming_lanes(
             )
             record_scan_collectives(pipe, lanes if do_prev else 0)
             row0 = 0
-            with phase("wls.stream_cross") as out:
+            with span("wls.stream_cross") as sp:
                 for i, chunk in enumerate(pipe):
                     chunk = jnp.asarray(chunk, dtype=jnp.float32)
                     rows = int(chunk.shape[0])
@@ -566,7 +563,7 @@ def _solve_weighted_streaming_lanes(
                 if red is None:
                     raise ValueError("empty chunk source")
                 xtR, xtRc, r_sum, cr_sum, G, class_sums, pop_sum = red
-                out.append(xtR)
+                sp.sync_on(xtR)
             if do_stats:
                 pop_mean = pop_sum / n
                 class_means = class_sums / safe_counts[:, None]
@@ -592,7 +589,7 @@ def _solve_weighted_streaming_lanes(
                     devices=devs,
                 )
                 row0 = 0
-                with phase("wls.stream_grams") as out:
+                with span("wls.stream_grams") as sp:
                     for i, chunk in enumerate(pipe2):
                         chunk = jnp.asarray(chunk, dtype=jnp.float32)
                         rows = int(chunk.shape[0])
@@ -619,7 +616,7 @@ def _solve_weighted_streaming_lanes(
                             f"chunk source produced {row0} rows, labels {n}"
                         )
                     grams = reduce_lane_partials(grams_l, scan=pipe2)
-                    out.append(grams)
+                    sp.sync_on(grams)
                 delta_cols.append(
                     _wls_class_delta(
                         grams, counts, class_means, pop_mean, joint_means,

@@ -19,6 +19,7 @@ import jax
 import jax.numpy as jnp
 
 from ...data.dataset import Dataset
+from ...obs.tracer import span
 from ...workflow.transformer import Estimator, Transformer
 from ..learning.gmm import (
     GaussianMixtureModel,
@@ -87,8 +88,6 @@ class GMMFisherVectorEstimator(Estimator):
         self.gmm_kwargs = gmm_kwargs
 
     def fit(self, data: Dataset) -> FisherVector:
-        from ...utils.timing import phase
-
         data = Dataset.of(data)
         if data.is_batched:
             X = jnp.asarray(data.to_array())
@@ -99,9 +98,9 @@ class GMMFisherVectorEstimator(Estimator):
             cols = jnp.asarray(
                 np.concatenate([np.asarray(i).T for i in data], axis=0)
             )
-        with phase("gmm_fv.em_fit") as out:
+        with span("gmm_fv.em_fit") as sp:
             gmm = GaussianMixtureModelEstimator(
                 self.k, **self.gmm_kwargs
             ).fit_matrix(cols)
-            out.append(gmm.means)
+            sp.sync_on(gmm.means)
         return FisherVector(gmm)

@@ -24,7 +24,7 @@ import numpy as np
 
 from ...data.dataset import Dataset
 from ...linalg.row_matrix import solve_spd
-from ...utils.timing import phase
+from ...obs.tracer import span
 from ...workflow.transformer import LabelEstimator, Transformer
 from ...workflow.node_optimization import Optimizable
 from .cost import AutoSolverFrontDoor, CostModel, combine_cost
@@ -274,7 +274,7 @@ class KernelRidgeRegression(LabelEstimator, CostModel):
                 # keeps the per-block wall (parity: the reference's
                 # per-block timing logs, KernelRidgeRegression.scala:
                 # 216-224 — its four sub-phases are one XLA program here)
-                with phase("krr.block_step") as out:
+                with span("krr.block_step") as sp:
                     if self.cache_kernel:
                         Kb = kernel_cache.get(start)
                         if Kb is None:
@@ -291,7 +291,7 @@ class KernelRidgeRegression(LabelEstimator, CostModel):
                             X, Y, W, start, jnp.float32(self.gamma),
                             jnp.float32(self.lam), bs=size,
                         )
-                    out.append(W)
+                    sp.sync_on(W)
                 steps_done += 1
                 if ckpt and steps_done % self.checkpoint_interval == 0:
                     np.savez(
@@ -341,7 +341,7 @@ class ExactKernelRidge(LabelEstimator, CostModel):
         Y = jnp.asarray(Dataset.of(labels).to_array(), dtype=jnp.float32)
         n = X.shape[0]
         bs = self.block_size
-        with phase("krr.exact_solve") as out:
+        with span("krr.exact_solve") as sp:
             cols = [
                 _kernel_block_slice(
                     X, start, jnp.float32(self.gamma), min(bs, n - start)
@@ -350,7 +350,7 @@ class ExactKernelRidge(LabelEstimator, CostModel):
             ]
             K = jnp.concatenate(cols, axis=1)  # (n, n)
             W = solve_spd(K, Y, jnp.float32(self.lam))
-            out.append(W)
+            sp.sync_on(W)
         return KernelBlockLinearMapper(X, W, self.gamma, bs)
 
 

@@ -27,6 +27,7 @@ logger = logging.getLogger(__name__)
 
 from ...data.dataset import Dataset
 from ...linalg import solve_blockwise_l2, solve_least_squares
+from ...obs.tracer import span
 from ...parallel.mesh import shard_batch
 from ...utils.params import as_param
 from ...workflow.transformer import LabelEstimator, Transformer
@@ -129,10 +130,8 @@ class LinearMapEstimator(LabelEstimator, CostModel):
         bit-identical to an uninterrupted pass."""
         from ...data.chunked import ChunkedDataset
         from ...linalg.accumulators import GramSolverState
-        from ...utils.timing import phase
-
         state = GramSolverState()
-        with phase("linear_map.grid_accumulate") as out:
+        with span("linear_map.grid_accumulate") as sp:
             if isinstance(data, ChunkedDataset):
                 y = jnp.asarray(
                     Dataset.of(labels).to_array(), dtype=jnp.float32
@@ -178,7 +177,7 @@ class LinearMapEstimator(LabelEstimator, CostModel):
                     Dataset.of(data).to_array(),
                     Dataset.of(labels).to_array(),
                 )
-            out.append(state.gram)
+            sp.sync_on(state.gram)
         models = []
         for est in estimators:
             W, b, mean = state.solve(est.lam or 0.0)
@@ -215,17 +214,15 @@ class LinearMapEstimator(LabelEstimator, CostModel):
         streaming BCD path; collectives O(1) per scan)."""
         from ...linalg import solve_least_squares_streaming
         from ...linalg.bcd import stream_column_means
-        from ...utils.timing import phase
-
         y = jnp.asarray(Dataset.of(labels).to_array(), dtype=jnp.float32)
-        with phase("linear_map.stream_center") as out:
+        with span("linear_map.stream_center") as sp:
             a_mean, n = stream_column_means(data.raw_chunks)
             if n != y.shape[0]:
                 raise ValueError(
                     f"chunked features have {n} rows, labels {y.shape[0]}"
                 )
             y_mean = jnp.mean(y, axis=0)
-            out.append(y_mean)
+            sp.sync_on(y_mean)
 
         def centered():
             offset = 0
@@ -238,9 +235,9 @@ class LinearMapEstimator(LabelEstimator, CostModel):
                 )
                 offset += rows
 
-        with phase("linear_map.stream_solve") as out:
+        with span("linear_map.stream_solve") as sp:
             W = solve_least_squares_streaming(centered(), reg=self.lam or 0.0)
-            out.append(W)
+            sp.sync_on(W)
         return LinearMapper(W, b=y_mean, feature_mean=a_mean)
 
     def cost(self, n, d, k, sparsity, num_machines,
@@ -273,8 +270,12 @@ class BlockLinearMapper(Transformer):
         #: WeightedSolverState` captured at fit time — what
         #: ``FittedPipeline.absorb`` folds appended chunks into
         self.solver_state = solver_state
-        # One batched device fetch; parameters live on host (utils/params.py)
-        xs, b, feature_means = jax.device_get((list(xs), b, feature_means))
+        # One batched device fetch; parameters live on host (utils/params.py).
+        # A fit's solve is awaited here: the span covers that wait too
+        with span("xfer.d2h", what="block_model"):
+            xs, b, feature_means = jax.device_get(
+                (list(xs), b, feature_means)
+            )
         self.xs = [as_param(x) for x in xs]
         self.block_size = block_size
         self.b = as_param(b)
@@ -291,12 +292,13 @@ class BlockLinearMapper(Transformer):
         )
 
     def trace_batch(self, X):
-        if self._mean is not None:
-            X = X - self._mean
-        out = X @ self._W
-        if self.b is not None:
-            out = out + self.b
-        return out
+        with jax.named_scope("ks.apply.scores"):
+            if self._mean is not None:
+                X = X - self._mean
+            out = X @ self._W
+            if self.b is not None:
+                out = out + self.b
+            return out
 
     def apply_blocks(self, blocks: Sequence) -> jnp.ndarray:
         """Apply to pre-split feature blocks (parity:
@@ -398,8 +400,6 @@ class BlockLeastSquaresEstimator(LabelEstimator, CostModel):
         """
         from ...data.chunked import ChunkedDataset
         from ...linalg.bcd import _block_means, solve_blockwise_l2_scan
-        from ...utils.timing import phase
-
         warm = getattr(self, "warm_start_ws", None)  # pre-sweep pickles
         self.warm_start_ws = None
         if isinstance(data, ChunkedDataset):
@@ -430,14 +430,14 @@ class BlockLeastSquaresEstimator(LabelEstimator, CostModel):
 
         if X is not None and X.shape[-1] % self.block_size == 0:
             d = X.shape[-1]
-            with phase("block_ls.center") as out:
+            with span("block_ls.center") as sp:
                 X = shard_batch(
                     X if X.dtype == jnp.float32 else X.astype(jnp.float32)
                 )
                 mean_vec = jnp.mean(X, axis=0)
                 y_mean = jnp.mean(y, axis=0)
-                out.append((mean_vec, y_mean))
-            with phase("block_ls.solve") as out:
+                sp.sync_on((mean_vec, y_mean))
+            with span("block_ls.solve") as sp:
                 init = None
                 if warm is not None:
                     cat = jnp.concatenate(
@@ -450,7 +450,7 @@ class BlockLeastSquaresEstimator(LabelEstimator, CostModel):
                     block_size=self.block_size, num_iter=self.num_iter,
                     means=mean_vec, init=init,
                 )
-                out.append(W)
+                sp.sync_on(W)
             ws = [
                 W[i : i + self.block_size]
                 for i in range(0, d, self.block_size)
@@ -469,7 +469,7 @@ class BlockLeastSquaresEstimator(LabelEstimator, CostModel):
                 X[..., i : min(i + self.block_size, d)]
                 for i in range(0, d, self.block_size)
             ]
-        with phase("block_ls.center") as out:
+        with span("block_ls.center") as sp:
             blocks = [
                 shard_batch(b if b.dtype == jnp.float32 else b.astype(jnp.float32))
                 for b in blocks
@@ -477,8 +477,8 @@ class BlockLeastSquaresEstimator(LabelEstimator, CostModel):
             # one program for every mean; centering itself is fused into the
             # per-block solve so centered copies never hit HBM
             means, y_mean = _block_means(blocks, y)
-            out.append(y_mean)
-        with phase("block_ls.solve"):
+            sp.sync_on(y_mean)
+        with span("block_ls.solve"):
             init = None
             if warm is not None and len(warm) == len(blocks) and all(
                 tuple(w.shape) == (int(b.shape[1]), int(y.shape[1]))
@@ -506,8 +506,6 @@ class BlockLeastSquaresEstimator(LabelEstimator, CostModel):
             solve_blockwise_l2_streaming,
             stream_column_means,
         )
-        from ...utils.timing import phase
-
         y = jnp.asarray(Dataset.of(labels).to_array(), dtype=jnp.float32)
 
         # raw (unpipelined) scans compose here: the streaming solvers wrap
@@ -524,21 +522,21 @@ class BlockLeastSquaresEstimator(LabelEstimator, CostModel):
         else:
             chunk_scan = data.raw_chunks
 
-        with phase("block_ls.stream_center") as out:
+        with span("block_ls.stream_center") as sp:
             mean_vec, n = stream_column_means(chunk_scan)
             if n != y.shape[0]:
                 raise ValueError(
                     f"chunked features have {n} rows, labels {y.shape[0]}"
                 )
             y_mean = jnp.mean(y, axis=0)
-            out.append(y_mean)
-        with phase("block_ls.stream_solve") as out:
+            sp.sync_on(y_mean)
+        with span("block_ls.stream_solve") as sp:
             ws = solve_blockwise_l2_streaming(
                 chunk_scan, y - y_mean, reg=self.lam,
                 block_size=self.block_size, num_iter=self.num_iter,
                 means=mean_vec,
             )
-            out.append(ws[-1])
+            sp.sync_on(ws[-1])
         d = int(mean_vec.shape[0])
         means = [
             mean_vec[i : min(i + self.block_size, d)]
@@ -622,11 +620,9 @@ class TSQRLeastSquaresEstimator(LabelEstimator, CostModel):
         from ...data.chunked import ChunkedDataset
         from ...linalg.bcd import stream_column_means
         from ...linalg.tsqr import _qr_fold, tsqr_r, tsqr_r_streaming
-        from ...utils.timing import phase
-
         y = jnp.asarray(Dataset.of(labels).to_array(), dtype=jnp.float32)
         chunked = isinstance(data, ChunkedDataset)
-        with phase("tsqr_ls.grid_factorize") as out:
+        with span("tsqr_ls.grid_factorize") as sp:
             if chunked:
                 a_mean, n = stream_column_means(data.raw_chunks)
                 if n != y.shape[0]:
@@ -659,7 +655,7 @@ class TSQRLeastSquaresEstimator(LabelEstimator, CostModel):
                 R_base = tsqr_r(
                     jnp.concatenate([A - a_mean, y - y_mean], axis=1)
                 )
-            out.append(R_base)
+            sp.sync_on(R_base)
         k = int(y.shape[1])
         models = []
         for est in estimators:
@@ -715,8 +711,6 @@ class TSQRLeastSquaresEstimator(LabelEstimator, CostModel):
         from ...linalg.accumulators import TsqrRState
         from ...linalg.bcd import stream_column_means
         from ...linalg.tsqr import _qr_fold
-        from ...utils.timing import phase
-
         y = jnp.asarray(Dataset.of(labels).to_array(), dtype=jnp.float32)
         key = (
             f"tsqr|n={len(data)}|y={tuple(int(s) for s in y.shape)}"
@@ -734,7 +728,7 @@ class TSQRLeastSquaresEstimator(LabelEstimator, CostModel):
                 "from %s", start_chunk, offset, ckpt.path,
             )
         else:
-            with phase("tsqr_ls.stream_center") as out:
+            with span("tsqr_ls.stream_center") as sp:
                 a_mean, n = stream_column_means(data.raw_chunks)
                 if n != y.shape[0]:
                     raise ValueError(
@@ -742,7 +736,7 @@ class TSQRLeastSquaresEstimator(LabelEstimator, CostModel):
                         f"{y.shape[0]}"
                     )
                 y_mean = jnp.mean(y, axis=0)
-                out.append(y_mean)
+                sp.sync_on(y_mean)
             state = TsqrRState()
             start_chunk, offset = 0, 0
             # block 0's checkpoint carries the means: a fit killed during
@@ -751,7 +745,7 @@ class TSQRLeastSquaresEstimator(LabelEstimator, CostModel):
         d = int(a_mean.shape[0])
         k = int(y.shape[1])
         every = max(1, int(self.checkpoint_every))
-        with phase("tsqr_ls.stream_solve") as out:
+        with span("tsqr_ls.stream_solve") as sp:
             i = start_chunk
             for chunk in data.raw_chunks(skip=start_chunk):
                 chunk = jnp.asarray(chunk, dtype=jnp.float32)
@@ -776,7 +770,7 @@ class TSQRLeastSquaresEstimator(LabelEstimator, CostModel):
             if reg is not None:
                 R = _qr_fold(R, reg)
             W = self._solve_from_r(R, d)
-            out.append(W)
+            sp.sync_on(W)
         ckpt.complete()
         return LinearMapper(W, b=y_mean, feature_mean=a_mean)
 
@@ -796,20 +790,18 @@ class TSQRLeastSquaresEstimator(LabelEstimator, CostModel):
         (``qr([A; √λI])`` has the regularized Gram as RᵀR)."""
         from ...linalg.bcd import stream_column_means
         from ...linalg.tsqr import tsqr_r_streaming
-        from ...utils.timing import phase
-
         if self.checkpoint:
             return self._fit_streaming_checkpointed(data, labels)
 
         y = jnp.asarray(Dataset.of(labels).to_array(), dtype=jnp.float32)
-        with phase("tsqr_ls.stream_center") as out:
+        with span("tsqr_ls.stream_center") as sp:
             a_mean, n = stream_column_means(data.raw_chunks)
             if n != y.shape[0]:
                 raise ValueError(
                     f"chunked features have {n} rows, labels {y.shape[0]}"
                 )
             y_mean = jnp.mean(y, axis=0)
-            out.append(y_mean)
+            sp.sync_on(y_mean)
         d = int(a_mean.shape[0])
         k = int(y.shape[1])
         reg = self._reg_rows(d, k)
@@ -827,9 +819,9 @@ class TSQRLeastSquaresEstimator(LabelEstimator, CostModel):
             if reg is not None:
                 yield reg
 
-        with phase("tsqr_ls.stream_solve") as out:
+        with span("tsqr_ls.stream_solve") as sp:
             W = self._solve_from_r(tsqr_r_streaming(augmented), d)
-            out.append(W)
+            sp.sync_on(W)
         return LinearMapper(W, b=y_mean, feature_mean=a_mean)
 
     def cost(self, n, d, k, sparsity, num_machines,

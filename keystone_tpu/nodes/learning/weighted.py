@@ -24,6 +24,7 @@ import numpy as np
 
 from ...data.dataset import Dataset
 from ...linalg.row_matrix import solve_spd
+from ...obs.tracer import span
 from ...parallel.mesh import shard_classes
 from ...workflow.node_optimization import Optimizable
 from ...workflow.transformer import LabelEstimator
@@ -229,8 +230,6 @@ class BlockWeightedLeastSquaresEstimator(LabelEstimator, CostModel):
         ]
         stats = [None] * len(blocks)  # (pop_cov, pop_mean, joint_means)
 
-        from ...utils.timing import phase
-
         for _ in range(self.num_iter):
             for j, A in enumerate(blocks):
                 d = A.shape[1]
@@ -330,12 +329,12 @@ class BlockWeightedLeastSquaresEstimator(LabelEstimator, CostModel):
                     )
                 delta = jnp.concatenate(delta_cols, axis=0).T  # (d, k)
                 Ws[j] = Ws[j] + delta
-                # per-block phase (parity: the reference's per-block solve
+                # per-block span (parity: the reference's per-block solve
                 # timing logs, BlockWeightedLeastSquares.scala:177-313);
-                # syncs only under KEYSTONE_PROFILE
-                with phase("wls.block") as out:
+                # syncs only under an installed tracer
+                with span("wls.block") as sp:
                     R = R - A @ delta
-                    out.append(R)
+                    sp.sync_on(R)
 
         # final intercept (ref :310-315)
         b = joint_label_mean - sum(

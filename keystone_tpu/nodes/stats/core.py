@@ -134,7 +134,8 @@ class CosineRandomFeatures(Transformer):
         return CosineRandomFeatures(W, b)
 
     def trace_batch(self, X):
-        return jnp.cos(X @ self.W.T + self.b)
+        with jax.named_scope("ks.featurize.cosine"):
+            return jnp.cos(X @ self.W.T + self.b)
 
 
 @jax.jit

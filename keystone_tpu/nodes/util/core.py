@@ -55,7 +55,8 @@ class MaxClassifier(Transformer):
     """argmax over the score vector (parity: MaxClassifier.scala)."""
 
     def trace_batch(self, X):
-        return jnp.argmax(X, axis=-1)
+        with jax.named_scope("ks.apply.argmax"):
+            return jnp.argmax(X, axis=-1)
 
 
 class TopKClassifier(Transformer):

@@ -6,9 +6,12 @@ tree across the three execution layers (graph executor pulls, autocache
 planning, serving micro-batches), Chrome-trace/Perfetto export, a
 plain-text top-N summary, and the estimate-vs-observed autocache audit.
 
-Enable with ``KEYSTONE_TRACE=/path/trace.json`` (or the CLI's
-``--trace PATH``); disabled, every instrumentation point is a single
-``current() is None`` check.
+Every instrumentation site is ``with obs.tracer.span(name) as sp:`` (not
+re-exported here: ``obs.span`` is the record's module): a
+``ks:<name>`` annotation in any profile being taken, and a span in memory
+with an installed tracer (``KEYSTONE_TRACE=/path/trace.json`` or the CLI's
+``--trace PATH``) or, unsynced, for the length of a profiler session
+(``session_spans()``). With neither, the annotation is the whole cost.
 """
 
 from .audit import cache_audit, log_cache_audit
@@ -25,7 +28,17 @@ from .flight import FlightRecorder, SITE_INSTANTS
 from .flight import recorder as flight_recorder
 from .scan import SCAN_LANE_SPAN, SCAN_SPAN, record_scan_span
 from .span import Span, cheap_nbytes
-from .tracer import Tracer, current, export, install, reset, start, stop, suspended
+from .tracer import (
+    Tracer,
+    current,
+    export,
+    install,
+    reset,
+    session_spans,
+    start,
+    stop,
+    suspended,
+)
 
 __all__ = [
     "SCAN_LANE_SPAN",
@@ -48,6 +61,7 @@ __all__ = [
     "log_cache_audit",
     "reset",
     "sample_rate",
+    "session_spans",
     "start",
     "stitch_chrome_trace",
     "stop",

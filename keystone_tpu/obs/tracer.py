@@ -7,15 +7,29 @@ node pull, autocache decision, and serving micro-batch lands in one span
 registry, exportable as Chrome-trace JSON (``obs/export.py``) and audited
 against the cache planner's estimates (``obs/audit.py``).
 
-Overhead contract: tracing is OFF unless a tracer is installed —
-:func:`current` returns None and every instrumentation site is a single
-``is None`` check with NO span allocation. Installed, each span costs one
-dataclass + two clock reads (+ an optional device sync at exit, which is
-the point: accurate attribution).
+The one primitive is the module-level :func:`span`. It always enters a
+``jax.profiler.TraceAnnotation("ks:" + name)``, so whoever takes a profile
+(``jax.profiler.trace``, a capture through ``start_server``, the
+benchmark's ``--trace 1``) sees the program's spans on the device's own
+timeline. It records a :class:`Span` in memory while SOMEONE IS RECORDING:
 
-Wiring: ``utils/obs.configure`` installs the global tracer from
-``KEYSTONE_TRACE=path`` (or the CLI's ``--trace PATH``) and registers an
-atexit export; library code only ever calls :func:`current`.
+* an installed :class:`Tracer` (``KEYSTONE_TRACE=path`` / ``--trace PATH``
+  through ``utils/obs.configure``, or a fit's own under a profile store):
+  each span costs one dataclass + two clock reads + a device sync at exit,
+  which is the point there — ``cost.finalize`` and ``obs/audit.py`` learn
+  from synced spans;
+* else a profiler session (``TraceAnnotation.is_enabled()``): the spans go
+  to one process-wide session recorder that NEVER syncs and never sizes
+  outputs (the device trace already knows when the chip ran) and keeps at
+  most :data:`SESSION_MAX_SPANS`; :func:`session_spans` returns them after
+  the session has ended.
+
+With neither, the annotation (half a microsecond) is the only cost: no
+``Span`` is allocated and the body gets :data:`NULL_SPAN`.
+
+:func:`current` returns an INSTALLED tracer only: the session recorder is
+not "the tracer" to ``fit_instrumentation``, ``AutoCacheRule`` or
+``cost.finalize`` (unsynced spans are not costs to learn from).
 """
 
 from __future__ import annotations
@@ -26,7 +40,9 @@ import itertools
 import logging
 import threading
 import time
-from typing import Dict, Iterator, List, Optional
+from typing import Any, Dict, Iterator, List, Optional
+
+from jax.profiler import TraceAnnotation as _Annotation
 
 from .span import Span, cheap_nbytes, sync_value
 
@@ -79,7 +95,14 @@ class Tracer:
     """Collects a span tree per thread; thread-safe for concurrent writers
     (the serving worker and N pipeline threads trace into one registry)."""
 
-    def __init__(self) -> None:
+    def __init__(
+        self, *, sync: bool = True, max_spans: Optional[int] = None
+    ) -> None:
+        #: block on a span's ``sync_on`` target (and size it) at exit
+        self.sync = sync
+        #: keep at most this many spans; later ones count as ``dropped``
+        self.max_spans = max_spans
+        self.dropped = 0
         self._lock = threading.Lock()
         self._spans: List[Span] = []
         self._ids = itertools.count(1)
@@ -130,8 +153,14 @@ class Tracer:
         finally:
             stack.pop()
 
-    @contextlib.contextmanager
-    def span(
+    def _keep(self, sp: Span) -> None:
+        with self._lock:
+            if self.max_spans is None or len(self._spans) < self.max_spans:
+                self._spans.append(sp)
+            else:
+                self.dropped += 1
+
+    def _open(
         self,
         name: str,
         *,
@@ -139,9 +168,7 @@ class Tracer:
         op_type: Optional[str] = None,
         cache: Optional[str] = None,
         **attrs,
-    ) -> Iterator[Span]:
-        """Open a span; the yielded handle takes extra attrs and an
-        optional ``sync_on(value)`` target blocked on at exit."""
+    ) -> Span:
         stack = self._stack()
         thread = threading.current_thread()
         sp = Span(
@@ -155,26 +182,40 @@ class Tracer:
             node_id=node_id,
             op_type=op_type,
             cache=cache,
-            attrs=dict(attrs),
+            attrs=attrs,
         )
-        compiles_at_start = _compile_count()
+        # the count at entry, negated: _close adds the count at exit, which
+        # leaves the compile REQUESTS inside the span (cache hits included)
+        sp.compiles = -_compile_count()
         stack.append(sp)
-        try:
-            yield sp
-        finally:
-            stack.pop()
-            target = sp.sync_target
-            if target is not None:
-                sp.sync_target = None
+        return sp
+
+    def _close(self, sp: Span) -> None:
+        self._stack().pop()
+        target = sp.sync_target
+        if target is not None:
+            sp.sync_target = None
+            if self.sync:
                 t0 = time.perf_counter()
                 if sync_value(target):
                     sp.sync_seconds = time.perf_counter() - t0
                 if sp.output_bytes is None:
                     sp.output_bytes = cheap_nbytes(target)
-            sp.end = time.perf_counter()
-            sp.compiles = _compile_count() - compiles_at_start
-            with self._lock:
-                self._spans.append(sp)
+        sp.end = time.perf_counter()
+        sp.compiles += _compile_count()
+        self._keep(sp)
+
+    @contextlib.contextmanager
+    def span(self, name: str, **kw) -> Iterator[Span]:
+        """Open a span IN THIS TRACER (``node_id``, ``op_type``, ``cache``
+        and free attrs as keywords); the yielded handle takes extra attrs
+        and an optional ``sync_on(value)`` target blocked on at exit.
+        Instrumentation sites use the module-level :func:`span`."""
+        sp = self._open(name, **kw)
+        try:
+            yield sp
+        finally:
+            self._close(sp)
 
     def instant(
         self,
@@ -204,8 +245,7 @@ class Tracer:
             instant=True,
             attrs=dict(attrs),
         )
-        with self._lock:
-            self._spans.append(sp)
+        self._keep(sp)
         return sp
 
     def record_complete(self, sp: Span) -> None:
@@ -220,8 +260,7 @@ class Tracer:
             sp.depth = len(stack)
         sp.tid = thread.ident or 0
         sp.thread_name = thread.name
-        with self._lock:
-            self._spans.append(sp)
+        self._keep(sp)
 
     # -- reads ----------------------------------------------------------
 
@@ -365,6 +404,128 @@ def current() -> Optional[Tracer]:
     return _current
 
 
+# -- the span primitive ------------------------------------------------------
+
+#: the session recorder's bound: a fit job leaves about a hundred spans, so
+#: this holds minutes of back-to-back jobs and a forgotten session stays small
+SESSION_MAX_SPANS = 65536
+
+_session: Optional[Tracer] = None
+_session_live = False
+_session_lock = threading.Lock()
+
+
+class _NullAttrs(dict):
+    """``sp.attrs[...] = v`` / ``sp.attrs.update(...)`` on a span nobody
+    records: accepted and dropped."""
+
+    def __setitem__(self, key, value) -> None:
+        pass
+
+    def update(self, *args, **kw) -> None:
+        pass
+
+
+class _NullSpan:
+    """What :func:`span` hands its body when nothing records."""
+
+    __slots__ = ()
+    attrs = _NullAttrs()
+
+    def sync_on(self, value: Any) -> None:
+        pass
+
+
+NULL_SPAN = _NullSpan()
+
+
+def _recorder() -> Optional[Tracer]:
+    """Who keeps a span opened now on this thread: the installed tracer,
+    else the session recorder while a profiler session records, else
+    nobody. A session that begins after one has ended starts a new list."""
+    global _session, _session_live
+    if getattr(_suspend, "depth", 0):
+        return None
+    if _current is not None:
+        return _current
+    if not _Annotation.is_enabled():
+        _session_live = False
+        return None
+    if not _session_live:
+        with _session_lock:
+            if not _session_live:
+                _session = Tracer(sync=False, max_spans=SESSION_MAX_SPANS)
+                _session_live = True
+    return _session
+
+
+def session_spans() -> List[Span]:
+    """The spans of the newest profiler session (readable after it has
+    ended; ``[]`` before the first)."""
+    return [] if _session is None else _session.spans()
+
+
+class span:
+    """``with span("block_ls.solve", n=n) as sp:`` — the program's one way
+    to mark a region: a ``ks:<name>`` annotation in whatever profile is
+    being taken, and a :class:`Span` with whoever records (module doc).
+    ``sp.sync_on(value)`` names what an installed tracer blocks on at
+    exit; a no-op where nothing syncs. Not for per-item or per-request
+    loops (those build finished spans: :meth:`Tracer.record_complete`)."""
+
+    __slots__ = ("_name", "_kw", "_annotation", "_tracer", "_span")
+
+    def __init__(self, name: str, **kw) -> None:
+        self._name = name
+        self._kw = kw
+
+    def __enter__(self):
+        self._annotation = _Annotation("ks:" + self._name)
+        self._annotation.__enter__()
+        self._tracer = _recorder()
+        if self._tracer is None:
+            return NULL_SPAN
+        self._span = self._tracer._open(self._name, **self._kw)
+        return self._span
+
+    def __exit__(self, *exc) -> bool:
+        if self._tracer is not None:
+            self._tracer._close(self._span)
+        self._annotation.__exit__(*exc)
+        return False
+
+
+#: what a suspended thread hands its workers
+_SUSPENDED = object()
+
+
+def handoff() -> Any:
+    """What a thread that starts workers hands them so that their spans
+    nest under its open span — and stay out of the record where the
+    caller is :func:`suspended`: the recorder with the caller's innermost
+    open span, or None when nothing records. Opaque: for :func:`adopt`."""
+    if getattr(_suspend, "depth", 0):
+        return _SUSPENDED
+    tracer = _recorder()
+    if tracer is None:
+        return None
+    return tracer, tracer.current_span()
+
+
+@contextlib.contextmanager
+def adopt(token: Any) -> Iterator[None]:
+    """Run the body on THIS thread under the :func:`handoff` of another."""
+    if token is None:
+        yield
+    elif token is _SUSPENDED:
+        with suspended():
+            yield
+    else:
+        tracer, parent = token
+        with tracer.adopt(parent):
+            yield
+
+
 def install(tracer: Tracer) -> Tracer:
     global _current
     _current = tracer
@@ -423,20 +584,25 @@ def stop() -> Optional[Tracer]:
 
 
 def reset() -> None:
-    """Drop the installed tracer AND the export path (test hygiene)."""
+    """Drop the installed tracer, the session recorder AND the export
+    path (test hygiene)."""
     global _current, _export_path, _exported_span_count
+    global _session, _session_live
     _current = None
+    _session, _session_live = None, False
     _export_path = None
     _exported_span_count = None
 
 
 @contextlib.contextmanager
 def suspended() -> Iterator[None]:
-    """Temporarily disable tracing ON THIS THREAD — used around the
-    autocache PROFILING runs so sampled-scale executions don't pollute
-    the real trace (their node ids would collide with the production
-    pull's). Thread-local so a serving worker tracing micro-batches is
-    unaffected by a concurrent fit's profiling window."""
+    """Temporarily disable span recording ON THIS THREAD, for the installed
+    tracer and the session recorder alike (the ``ks:`` annotations stay) —
+    used around the autocache PROFILING runs so sampled-scale executions
+    don't pollute the real trace (their node ids would collide with the
+    production pull's). Thread-local so a serving worker tracing
+    micro-batches is unaffected by a concurrent fit's profiling window;
+    workers a suspended thread starts inherit it through :func:`handoff`."""
     _suspend.depth = getattr(_suspend, "depth", 0) + 1
     try:
         yield

@@ -58,6 +58,7 @@ from ..nodes.util import (
     TopKClassifier,
     VectorCombiner,
 )
+from ..obs.tracer import span
 from ..workflow.pipeline import Pipeline
 
 NUM_CLASSES = 1000  # parity: ImageNetLoader.NUM_CLASSES
@@ -118,14 +119,12 @@ def compute_pca_fisher_branch(
     device memory (parity: ImageNetSiftLcsFV.scala:98-135 never collects
     the descriptor RDD)."""
     from ..data.chunked import ChunkedDataset
-    from ..utils.timing import phase
-
     need_pca_sample = not pca_file
     need_gmm_sample = not gmm_mean_file
     pca_sample = desc_sample = None
     if need_pca_sample or need_gmm_sample:
         gmm_per_img = gmm_samples_per_image or num_col_samples_per_image
-        with phase("imagenet.descriptors+samples") as out:
+        with span("imagenet.descriptors+samples") as sp:
             prefix_out = prefix(train_images).get()
             if isinstance(prefix_out, ChunkedDataset):
                 # both samplers share ONE featurize scan, each drawing via
@@ -155,7 +154,7 @@ def compute_pca_fisher_branch(
                     desc_sample = ColumnSampler(
                         gmm_per_img, seed=seed + 1
                     ).apply_batch(prefix_out)
-            out.append((pca_sample or desc_sample).to_array())
+            sp.sync_on((pca_sample or desc_sample).to_array())
 
     if pca_file:
         pca_mat = np.loadtxt(pca_file, delimiter=",", ndmin=2).T
@@ -177,9 +176,9 @@ def compute_pca_fisher_branch(
         # a loaded codebook sets this branch's FV width (see voc_sift_fisher)
         vocab_size = int(gmm.k)
     else:
-        with phase("imagenet.pca_fit+gmm_project") as out:
+        with span("imagenet.pca_fit+gmm_project") as sp:
             gmm_sample = pca_apply(desc_sample).get()
-            out.append(gmm_sample.to_array())
+            sp.sync_on(gmm_sample.to_array())
         fv = GMMFisherVectorEstimator(
             vocab_size, max_iterations=20, min_cluster_size=1
         ).with_data(gmm_sample)

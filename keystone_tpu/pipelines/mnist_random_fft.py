@@ -25,6 +25,8 @@ from ..loaders.csv_loader import LabeledData, load_labeled_csv
 from ..nodes.learning.linear import BlockLeastSquaresEstimator
 from ..nodes.stats import LinearRectifier, PaddedFFT, RandomSignNode
 from ..nodes.util import ClassLabelIndicators, MaxClassifier, VectorCombiner
+from ..obs.tracer import span
+from ..utils.params import to_device
 from ..workflow.pipeline import Pipeline
 
 MNIST_IMAGE_SIZE = 784
@@ -56,25 +58,30 @@ def build_featurizer(conf: MnistRandomFFTConfig) -> Pipeline:
 def run(train: LabeledData, test: LabeledData, conf: MnistRandomFFTConfig):
     """Train + evaluate; returns (pipeline, train_err, test_err, seconds)."""
     start = time.perf_counter()
+    with span("job", pipeline="MnistRandomFFT"):
+        labels = ClassLabelIndicators(NUM_CLASSES).apply_batch(
+            to_device(train.labels)
+        )
+        with span("plan.build"):
+            featurizer = build_featurizer(conf)
+            pipeline = featurizer.and_then(
+                BlockLeastSquaresEstimator(
+                    conf.block_size, 1, conf.lam or 0.0
+                ),
+                train.data,
+                labels,
+            ).and_then(MaxClassifier())
 
-    labels = ClassLabelIndicators(NUM_CLASSES).apply_batch(train.labels)
-    featurizer = build_featurizer(conf)
-    pipeline = featurizer.and_then(
-        BlockLeastSquaresEstimator(conf.block_size, 1, conf.lam or 0.0),
-        train.data,
-        labels,
-    ).and_then(MaxClassifier())
-
-    evaluator = MulticlassClassifierEvaluator(NUM_CLASSES)
-    # The "compile step" (SURVEY §3.2): after fit() the pipeline is
-    # estimator-free and applies as ONE fused XLA program.
-    fitted = pipeline.fit()
-    train_eval = evaluator.evaluate(
-        fitted.apply_compiled(train.data.to_array()), train.labels
-    )
-    test_eval = evaluator.evaluate(
-        fitted.apply_compiled(test.data.to_array()), test.labels
-    )
+        evaluator = MulticlassClassifierEvaluator(NUM_CLASSES)
+        # The "compile step" (SURVEY §3.2): after fit() the pipeline is
+        # estimator-free and applies as ONE fused XLA program.
+        fitted = pipeline.fit()
+        train_eval = evaluator.evaluate(
+            fitted.apply_compiled(train.data.to_array()), train.labels
+        )
+        test_eval = evaluator.evaluate(
+            fitted.apply_compiled(test.data.to_array()), test.labels
+        )
     seconds = time.perf_counter() - start
     return pipeline, train_eval.total_error, test_eval.total_error, seconds
 

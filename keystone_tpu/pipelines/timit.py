@@ -27,6 +27,8 @@ from ..loaders.text import TIMIT_DIMENSION, TIMIT_NUM_CLASSES, load_timit_featur
 from ..nodes.learning.linear import BlockLeastSquaresEstimator
 from ..nodes.stats import CosineRandomFeatures
 from ..nodes.util import ClassLabelIndicators, MaxClassifier, VectorCombiner
+from ..obs.tracer import span
+from ..utils.params import to_device
 from ..workflow.pipeline import Pipeline
 
 NUM_COSINE_FEATURES = 4096  # TimitPipeline.scala:51
@@ -80,21 +82,25 @@ def build_featurizer(conf: TimitConfig) -> Pipeline:
 def run(train: LabeledData, test: LabeledData, conf: TimitConfig):
     """Returns (predictor, test evaluation, seconds)."""
     start = time.perf_counter()
-    labels = ClassLabelIndicators(conf.num_classes).apply_batch(train.labels)
-    predictor = (
-        build_featurizer(conf)
-        .and_then(
-            BlockLeastSquaresEstimator(
-                conf.cosine_features, conf.num_epochs, conf.lam
-            ),
-            train.data,
-            labels,
+    with span("job", pipeline="Timit"):
+        labels = ClassLabelIndicators(conf.num_classes).apply_batch(
+            to_device(train.labels)
         )
-        .and_then(MaxClassifier())
-    )
-    evaluation = MulticlassClassifierEvaluator(conf.num_classes).evaluate(
-        predictor(test.data).get().to_array(), test.labels
-    )
+        with span("plan.build"):
+            predictor = (
+                build_featurizer(conf)
+                .and_then(
+                    BlockLeastSquaresEstimator(
+                        conf.cosine_features, conf.num_epochs, conf.lam
+                    ),
+                    train.data,
+                    labels,
+                )
+                .and_then(MaxClassifier())
+            )
+        evaluation = MulticlassClassifierEvaluator(conf.num_classes).evaluate(
+            predictor(test.data).get().to_array(), test.labels
+        )
     return predictor, evaluation, time.perf_counter() - start
 
 

@@ -8,11 +8,10 @@ read used by tests and the demo, and ``maybe_log`` emits a rate-limited
 one-line INFO summary through the same stdlib logging that
 ``utils.obs.configure`` levels.
 
-Phase stats from ``utils.timing`` (the hot-solver profiling registry) are
-embedded in every snapshot under ``"phases"`` — the engine wraps its batch
-execution in ``timing.phase("serve.batch", ...)``, so under
-``KEYSTONE_PROFILE=1`` the serving batches show up in the same per-phase
-device-time table as the solvers.
+The ``utils.timing`` counters under ``serve.`` are embedded in every
+snapshot under ``"phases"`` — each replica batch feeds
+``timing.record("serve.batch", seconds)`` (dispatch to the batch span's
+exit: synced under an installed tracer, the enqueue alone without).
 
 Tracer spans (``keystone_tpu.obs``) land under ``"spans"`` in the SAME
 ``{name: {"seconds", "calls", ...}}`` schema as ``"phases"`` — and the

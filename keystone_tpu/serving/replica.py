@@ -47,6 +47,7 @@ from ..faults import (
 from ..obs import flight as _flight
 from ..obs import resource as _resource
 from ..obs.span import Span
+from ..obs.tracer import NULL_SPAN
 from ..obs.tracer import current as _trace_current
 from ..utils import timing
 from ..workflow.pipeline import FittedPipeline, NotTraceableError
@@ -498,8 +499,8 @@ class Replica:
         compiled = self._compiled  # one read: the whole batch runs on it
         t0 = time.perf_counter()
         try:
-            # span name differs from the phase's "serve.batch" so a merged
-            # {name: {seconds, calls, ...}} export of phases + spans never
+            # span name differs from the "serve.batch" counter so a merged
+            # {name: {seconds, calls, ...}} export of counters + spans never
             # collides on keys
             span_attrs = {"items": len(valid), "bucket": bucket}
             if self.index is not None:
@@ -513,23 +514,16 @@ class Replica:
                 # below (consumers group by args.trace_id, so every
                 # coalesced member must own a span over the interval)
                 span_attrs["trace_id"] = traced_ids[0]
-            with contextlib.ExitStack() as stack:
-                sp = (
-                    stack.enter_context(
-                        tracer.span(
-                            self._span_name,
-                            op_type="Replica",
-                            **span_attrs,
-                        )
-                    )
-                    if tracer is not None
-                    else None
-                )
-                with timing.phase("serve.batch") as hold:
-                    out = compiled(padded)
-                    hold.append(out)
-                if sp is not None:
-                    sp.sync_on(out)
+            # an installed tracer's span, not obs.tracer.span: a batch is
+            # the serving path's per-request loop, which stays unannotated
+            with (
+                tracer.span(self._span_name, op_type="Replica", **span_attrs)
+                if tracer is not None
+                else contextlib.nullcontext(NULL_SPAN)
+            ) as sp:
+                out = compiled(padded)
+                sp.sync_on(out)
+            timing.record("serve.batch", time.perf_counter() - t0)
             out = jax.device_get(out)  # one D2H fetch for the whole batch
         except Exception as e:  # batch-level failure → every member errors
             self.consecutive_failures += 1

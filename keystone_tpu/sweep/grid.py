@@ -33,6 +33,7 @@ import threading
 from dataclasses import dataclass, field
 from typing import Any, Callable, Dict, List, Mapping, Optional, Sequence, Tuple
 
+from ..obs import tracer as _obs_tracer
 from ..obs.tracer import current as _trace_current
 from ..workflow import analysis
 from ..workflow.env import PipelineEnv
@@ -418,7 +419,7 @@ class GridSweep:
         if len(ungrouped) > 1 and parallel_enabled():
             self._prefetch_concurrent(
                 executor, [est for _, est in ungrouped], fitted_by_est,
-                stats, tracer,
+                stats,
             )
 
         # the sequential rewrite loop (graph edits are main-thread only)
@@ -494,7 +495,6 @@ class GridSweep:
         est_nodes: Sequence[NodeId],
         out: Dict[NodeId, TransformerOperator],
         stats,
-        tracer,
     ) -> None:
         """Force the independent estimator expressions on a bounded pool.
         The shared prefix expression's once-latch serializes its single
@@ -503,15 +503,12 @@ class GridSweep:
         from concurrent.futures import ThreadPoolExecutor
 
         exprs = {n: executor.execute(n) for n in est_nodes}
-        parent = tracer.current_span() if tracer is not None else None
+        spans_to = _obs_tracer.handoff()
         lock = threading.Lock()
 
         def run(n):
             try:
-                if tracer is not None:
-                    with tracer.adopt(parent):
-                        value = exprs[n].get()
-                else:
+                with _obs_tracer.adopt(spans_to):
                     value = exprs[n].get()
             except Exception:
                 # the sequential loop re-pulls this node and raises the

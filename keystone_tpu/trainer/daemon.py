@@ -55,6 +55,7 @@ from ..faults import (
     is_transient,
 )
 from ..obs.tracer import current as _trace_current
+from ..obs.tracer import span as _span
 from ..serving.errors import CanaryMismatch, EngineStopped
 from .drift import DriftMonitor
 from .source import ChunkLog
@@ -424,20 +425,13 @@ class TrainerDaemon:
         """One absorb → canary → swap attempt for the frozen batch.
         Every failure path leaves the old model serving; success
         publishes and re-baselines."""
-        import contextlib
-
-        tracer = _trace_current()
-        with contextlib.ExitStack() as stack:
-            if tracer is not None:
-                stack.enter_context(
-                    tracer.span(
-                        "trainer.refit",
-                        op_type=type(self).__name__,
-                        batch_start=attempt.start,
-                        batch_stop=attempt.stop,
-                        retry=attempt.retries,
-                    )
-                )
+        with _span(
+            "trainer.refit",
+            op_type=type(self).__name__,
+            batch_start=attempt.start,
+            batch_stop=attempt.stop,
+            retry=attempt.retries,
+        ):
             try:
                 candidate = self._absorb(attempt)
             except Exception as e:

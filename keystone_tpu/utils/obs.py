@@ -1,4 +1,4 @@
-"""Observability configuration: one switch for logs + phase profiling.
+"""Observability configuration: one switch for logs, tracer and caches.
 
 Parity: the reference inherits its observability from Spark — log4j
 config, per-stage timing in the Spark UI, and ad-hoc ``logInfo`` phase
@@ -9,15 +9,14 @@ counterparts here:
   single-line format (the log4j analogue). Every module already logs
   through ``logging.getLogger(__name__)``; this makes those logs visible
   and uniform.
-* phase profiling — ``utils.timing`` accumulates named phase durations in
-  every hot solver; under profiling each phase exit synchronizes the
-  device stream so attribution is accurate, and phases log at INFO (the
-  Spark-UI-stage-timing analogue).
+* spans — every layer boundary and hot-solver phase is an
+  ``obs.tracer.span``: a ``ks:`` annotation in any profiler session (the
+  Spark-UI-stage-timing analogue is the profile's own timeline), synced
+  and kept in memory under an installed tracer.
 
 Environment switches (read by the CLI and by ``configure(None)``):
 
 * ``KEYSTONE_LOG=debug|info|warning|error`` — log level.
-* ``KEYSTONE_PROFILE=1`` — enable phase profiling + phase logs.
 * ``KEYSTONE_TRACE=/path/trace.json`` — install the pipeline tracer
   (``keystone_tpu.obs``) and export a Chrome-trace/Perfetto JSON at
   process exit (or explicitly via :func:`export_trace`).
@@ -73,18 +72,15 @@ def reset_rate_limits() -> None:
 
 def configure(
     level: Optional[str] = None,
-    profile: Optional[bool] = None,
     trace: Optional[str] = None,
     aot_cache: Optional[str] = None,
     profiles: Optional[str] = None,
 ) -> None:
-    """Configure logging (and optionally phase profiling) process-wide.
+    """Configure logging (and optionally the tracer and caches) process-wide.
 
     ``level=None`` reads ``KEYSTONE_LOG`` (default: warning, stdlib's
     default visibility; unknown env values warn and fall back rather than
-    crash the CLI). ``profile`` is the single profiling switch: True/False
-    enable/disable phase syncs+logs, ``None`` follows ``KEYSTONE_PROFILE``
-    (off unless set to something truthy). ``trace`` is a Chrome-trace
+    crash the CLI). ``trace`` is a Chrome-trace
     output path enabling the pipeline tracer (``keystone_tpu.obs``);
     ``None`` follows ``KEYSTONE_TRACE`` (off unless set). ``aot_cache``
     is a directory path enabling the persistent AOT executable cache
@@ -92,8 +88,8 @@ def configure(
     (off unless set). ``profiles`` is a directory path enabling the
     persistent operator profile store (``keystone_tpu.cost``); ``None``
     follows ``KEYSTONE_PROFILE_DIR`` (off unless set). Idempotent; later
-    calls re-level the root handler and re-apply the profiling switch,
-    and an already-installed tracer is kept (spans survive).
+    calls re-level the root handler, and an already-installed tracer is
+    kept (spans survive).
     """
     global _configured
     from_env = level is None
@@ -116,17 +112,6 @@ def configure(
         root.addHandler(handler)
         _configured = True
     root.setLevel(lvl)
-
-    if profile is None:
-        raw = os.environ.get("KEYSTONE_PROFILE", "")
-        profile = raw.strip().lower() not in ("", "0", "false", "no", "off")
-    from . import timing
-
-    timing.enable(bool(profile))
-    if profile:
-        # phase logs are INFO; make sure they are visible when profiling
-        if lvl > logging.INFO:
-            root.setLevel(logging.INFO)
 
     if trace is None:
         trace = os.environ.get("KEYSTONE_TRACE") or None

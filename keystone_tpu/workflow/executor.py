@@ -35,7 +35,9 @@ import time
 from concurrent.futures import ThreadPoolExecutor
 from typing import Any, Dict, List, Optional, Tuple
 
+from ..obs import tracer as _obs_tracer
 from ..obs.tracer import current as _trace_current
+from ..obs.tracer import span as _span
 from ..utils.timing import degraded
 from .env import PipelineEnv
 from .expressions import DatasetExpression, Expression
@@ -221,8 +223,8 @@ class GraphExecutor:
                 graph.get_sink_dependency(graph_id), transient, built,
                 segments=segments,
             )
-        # tracing is opt-in: disabled, the ONLY cost per pull is this None
-        # check — no span allocation anywhere on the path
+        # memo hits are instants of an INSTALLED tracer only; a node that
+        # computes runs under obs.tracer.span whoever records
         tracer = _trace_current()
         if graph_id in self._state:
             expr = self._state[graph_id]
@@ -250,12 +252,7 @@ class GraphExecutor:
         ]
         op = graph.get_operator(graph_id)
         retained = self._retain(graph, graph_id)
-        if tracer is None:
-            expr = op.execute(deps)
-        else:
-            expr = self._traced_execute(
-                tracer, graph_id, op, deps, retained=retained
-            )
+        expr = self._traced_execute(graph_id, op, deps, retained=retained)
         # ``built`` records every node of this pull in dependencies-first
         # order — the scheduler's topological order comes straight from it
         built[graph_id] = expr
@@ -284,20 +281,22 @@ class GraphExecutor:
             from ..compile.segment import bind_segment
 
             graph = self.graph
-            verdicts = {
-                n: lattice.classify(graph.get_operator(n))
-                for n in graph.nodes
-            }
-            planned, _barriers = plan_segments(graph, verdicts, {})
-            table: Dict[NodeId, Any] = {}
-            for seg in planned:
-                binding = bind_segment(
-                    graph, seg, annotations=self._annotations
-                )
-                if binding is None:
-                    continue
-                for out in binding.outputs:
-                    table[out] = binding
+            with _span("plan.segments", nodes=len(graph.nodes)) as sp:
+                verdicts = {
+                    n: lattice.classify(graph.get_operator(n))
+                    for n in graph.nodes
+                }
+                planned, _barriers = plan_segments(graph, verdicts, {})
+                table: Dict[NodeId, Any] = {}
+                for seg in planned:
+                    binding = bind_segment(
+                        graph, seg, annotations=self._annotations
+                    )
+                    if binding is None:
+                        continue
+                    for out in binding.outputs:
+                        table[out] = binding
+                sp.attrs["segments"] = len(planned)
             return table
         except Exception:
             logger.warning(
@@ -355,11 +354,7 @@ class GraphExecutor:
 
         def run_bundle():
             xs = [e.get() for e in in_exprs]
-            tracer = _trace_current()
-            if tracer is None:
-                outs, _path = binding.run(xs)
-                return outs
-            with tracer.span(
+            with _span(
                 "exec.segment",
                 op_type="Segment",
                 segment=binding.index,
@@ -454,7 +449,7 @@ class GraphExecutor:
         root_expr.map_thunk(wrap)
         root_expr._sched_armed = True
 
-    # -- tracing hooks (active only with an installed obs.Tracer) -------
+    # -- tracing hooks ---------------------------------------------------
 
     @staticmethod
     def _trace_hit(tracer, graph: Graph, graph_id: NodeId, store: str) -> None:
@@ -470,17 +465,17 @@ class GraphExecutor:
         )
 
     @staticmethod
-    def _traced_execute(tracer, graph_id: NodeId, op, deps, retained: bool):
+    def _traced_execute(graph_id: NodeId, op, deps, retained: bool):
         """Build the node's expression with its eventual EVALUATION wrapped
         in a span. Evaluation is lazy (``Expression`` thunks), so the span
         opens when ``.get()`` first forces this node — upstream thunks
         forced from inside it become child spans, giving the pull's true
-        tree. Exit blocks on the result so async-dispatched device time is
-        attributed here (recorded as ``sync_seconds``). When the concurrent
-        scheduler forces this node, the worker's task context adds
-        ``queue_wait_seconds`` (ready-to-started latency) and ``worker``."""
-        from ..obs.span import Span, cheap_nbytes
-
+        tree. Under an installed tracer, exit blocks on the result so
+        async-dispatched device time is attributed here (recorded as
+        ``sync_seconds``); a profiler session records without. When the
+        concurrent scheduler forces this node, the worker's task context
+        adds ``queue_wait_seconds`` (ready-to-started latency) and
+        ``worker``."""
         name = f"node.{op.label}"
         op_type = type(op).__name__
         node_id = str(graph_id.id)
@@ -488,18 +483,22 @@ class GraphExecutor:
         expr = op.execute(deps)
         if expr.computed:
             # eager operator (Dataset/Datum leaves, saved state): the work
-            # happened inside op.execute — record it directly
-            sp = Span(
-                name=name,
-                start=t0,
-                end=time.perf_counter(),
-                node_id=node_id,
-                op_type=op_type,
-                cache="miss",
-                output_bytes=cheap_nbytes(expr.get()),
-                attrs={"retained": retained, "eager": True},
-            )
-            tracer.record_complete(sp)
+            # happened inside op.execute — an installed tracer records it
+            # directly (a finished span: no annotation, no session record)
+            tracer = _trace_current()
+            if tracer is not None:
+                from ..obs.span import Span, cheap_nbytes
+
+                tracer.record_complete(Span(
+                    name=name,
+                    start=t0,
+                    end=time.perf_counter(),
+                    node_id=node_id,
+                    op_type=op_type,
+                    cache="miss",
+                    output_bytes=cheap_nbytes(expr.get()),
+                    attrs={"retained": retained, "eager": True},
+                ))
             return expr
 
         def _wrap(thunk):
@@ -514,7 +513,7 @@ class GraphExecutor:
                         "queue_wait_seconds": round(_TASK_CTX.queue_wait, 6),
                         "worker": _TASK_CTX.worker,
                     }
-                with tracer.span(
+                with _span(
                     name,
                     node_id=node_id,
                     op_type=op_type,
@@ -553,8 +552,7 @@ def _force_scheduled(
     remaining = [n for n, e in exprs.items() if not e.computed]
     if not remaining:
         return
-    tracer = _trace_current()
-    parent = tracer.current_span() if tracer is not None else None
+    spans_to = _obs_tracer.handoff()
 
     # init-only snapshot; live ready-tracking is indeg/children below
     in_remaining = set(remaining)
@@ -577,10 +575,7 @@ def _force_scheduled(
         _TASK_CTX.queue_wait = time.perf_counter() - ready_since
         _TASK_CTX.worker = threading.current_thread().name
         try:
-            if tracer is not None:
-                with tracer.adopt(parent):
-                    expr.get()
-            else:
+            with _obs_tracer.adopt(spans_to):
                 expr.get()
         except BaseException as e:  # noqa: BLE001 — must reach the caller
             err = e

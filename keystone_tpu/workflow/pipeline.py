@@ -18,6 +18,7 @@ from typing import Any, Callable, Dict, List, Optional, Sequence, Union
 
 from ..data.dataset import Dataset
 from ..obs.tracer import current as _trace_current
+from ..obs.tracer import span as _span
 from ..utils.timing import degraded
 from .env import PipelineEnv
 from .executor import GraphExecutor
@@ -111,13 +112,10 @@ class PipelineResult:
         return self._executor.execute(self._sink)
 
     def get(self) -> Any:
-        tracer = _trace_current()
-        if tracer is None:
-            return self.expression().get()
         # the pull root: every node span of this execution nests under it —
         # including spans from scheduler worker threads, which the executor
-        # explicitly links under this thread's open span (Tracer.adopt)
-        with tracer.span("pipeline.pull", op_type=type(self).__name__) as sp:
+        # explicitly links under this thread's open span (obs.tracer.adopt)
+        with _span("pipeline.pull", op_type=type(self).__name__) as sp:
             value = self.expression().get()
             sp.sync_on(value)
         return value
@@ -183,11 +181,8 @@ def fit_instrumentation(op_type: str, span_name: str = "pipeline.fit"):
         with cost_mod.pending_plan(store) as plan:
             if plan is not None and tracer is not None:
                 plan.span_watermark = len(tracer.spans())
-            if tracer is None:
+            with _span(span_name, op_type=op_type):
                 yield
-            else:
-                with tracer.span(span_name, op_type=op_type):
-                    yield
             # after the fit span closes: every node span is complete,
             # so the estimate-vs-observed join sees the whole run
             cost_mod.finalize(plan, tracer)
@@ -664,15 +659,9 @@ class FittedPipeline(Chainable):
             graph, optimize=False,
             segment_plan=self._segment_plan if plain_splice else None,
         )
-        tracer = _trace_current()
-        if tracer is None:
+        with _span("pipeline.apply", op_type=type(self).__name__) as sp:
             value = executor.execute(self._sink).get()
-        else:
-            with tracer.span(
-                "pipeline.apply", op_type=type(self).__name__
-            ) as sp:
-                value = executor.execute(self._sink).get()
-                sp.sync_on(value)
+            sp.sync_on(value)
         if plain_splice and self._segment_plan is None:
             self._segment_plan = executor.segment_plan
         return value
@@ -1120,18 +1109,11 @@ class FittedPipeline(Chainable):
         state = mapper.solver_state.snapshot()
         prefix_exec, prefix_sink = self._prefix_executor(node, new_data)
 
-        tracer = _trace_current()
-        with contextlib.ExitStack() as stack:
-            if tracer is not None:
-                sp = stack.enter_context(
-                    tracer.span(
-                        "pipeline.absorb",
-                        op_type=type(self).__name__,
-                        prior_rows=int(state.n),
-                    )
-                )
-            else:
-                sp = None
+        with _span(
+            "pipeline.absorb",
+            op_type=type(self).__name__,
+            prior_rows=int(state.n),
+        ) as sp:
             import jax.numpy as jnp
 
             feats = prefix_exec.execute(prefix_sink).get()
@@ -1195,12 +1177,11 @@ class FittedPipeline(Chainable):
                     on_chunk(0, feats)
                 state.update(_Dataset.of(feats).to_array(), y)
             new_mapper = state.rebuild_mapper(mapper)
-            if sp is not None:
-                sp.attrs["absorbed_rows"] = int(state.rows_folded)
-                sp.attrs["total_rows"] = int(state.n)
-                solved_w = getattr(new_mapper, "W", None)
-                if solved_w is not None:
-                    sp.sync_on(solved_w)
+            sp.attrs["absorbed_rows"] = int(state.rows_folded)
+            sp.attrs["total_rows"] = int(state.n)
+            solved_w = getattr(new_mapper, "W", None)
+            if solved_w is not None:
+                sp.sync_on(solved_w)
         updated = FittedPipeline(
             self._graph.set_operator(node, new_mapper),
             self._source,

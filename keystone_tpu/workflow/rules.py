@@ -74,28 +74,34 @@ class RuleExecutor:
             # Tracer.record_node_estimate)
             t.begin_plan_epoch()
         ann = dict(annotations or {})
-        for batch in self.batches():
-            iteration = 0
-            while True:
-                iteration += 1
-                before = (graph, dict(ann))
-                for rule in batch.rules:
-                    graph, ann = rule.apply(graph, ann)
-                if batch.strategy == Strategy.ONCE:
-                    break
-                # Cost note: every rule returns its input graph object
-                # unchanged on a no-op pass, and tuple/dict equality
-                # short-circuits on identity (PyObject_RichCompareBool), so
-                # the converged iteration costs O(len(ann)) identity checks,
-                # not a whole-graph structural compare; the deep compare
-                # only runs when a rule rebuilt the graph, where it fails
-                # fast on the first differing field.
-                if (graph, ann) == before:
-                    break
-                if iteration >= batch.max_iterations:
-                    logger.warning("batch %s hit max iterations (%d)", batch.name,
-                                   batch.max_iterations)
-                    break
+        with obs_tracer.span("plan.optimize", nodes=len(graph.nodes)):
+            for batch in self.batches():
+                iteration = 0
+                while True:
+                    iteration += 1
+                    before = (graph, dict(ann))
+                    for rule in batch.rules:
+                        with obs_tracer.span(
+                            "plan.rule", rule=rule.rule_name, batch=batch.name
+                        ):
+                            graph, ann = rule.apply(graph, ann)
+                    if batch.strategy == Strategy.ONCE:
+                        break
+                    # Cost note: every rule returns its input graph object
+                    # unchanged on a no-op pass, and tuple/dict equality
+                    # short-circuits on identity (PyObject_RichCompareBool),
+                    # so the converged iteration costs O(len(ann)) identity
+                    # checks, not a whole-graph structural compare; the deep
+                    # compare only runs when a rule rebuilt the graph, where
+                    # it fails fast on the first differing field.
+                    if (graph, ann) == before:
+                        break
+                    if iteration >= batch.max_iterations:
+                        logger.warning(
+                            "batch %s hit max iterations (%d)", batch.name,
+                            batch.max_iterations,
+                        )
+                        break
         return graph, ann
 
 

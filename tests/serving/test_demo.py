@@ -1,5 +1,5 @@
 """The --serve-demo CLI path (what bin/serve-smoke.sh runs) and the
---log/--profile observability flags."""
+--log observability flag."""
 
 import logging
 

@@ -1,8 +1,11 @@
-"""Observability configuration (utils/obs.py + CLI --logLevel/--profile)."""
+"""Observability configuration (utils/obs.py + CLI --logLevel/--trace)."""
 
 import logging
 import threading
 
+import pytest
+
+from keystone_tpu.obs import tracer
 from keystone_tpu.utils import obs, timing
 
 
@@ -18,33 +21,45 @@ def test_configure_sets_level_and_format(capsys):
 
 
 def test_configure_rejects_unknown_level():
-    import pytest
-
     with pytest.raises(ValueError):
         obs.configure("loud")
 
 
-def test_profile_enables_phase_logs(capsys):
-    obs.configure("warning", profile=True)
-    try:
-        timing.reset()
-        with timing.phase("obs.test_phase"):
-            pass
-        snap = timing.snapshot()
-        assert "obs.test_phase" in snap
-        assert "obs.test_phase" in capsys.readouterr().err
-    finally:
-        obs.configure("warning", profile=False)
+@pytest.fixture
+def no_tracer():
+    """The four span tests install a tracer: leave none behind."""
+    tracer.reset()
+    yield
+    tracer.reset()
 
 
-def test_profile_env_parsing(monkeypatch):
-    for raw, want in [("1", True), ("true", True), ("0", False),
-                      ("false", False), ("", False), ("off", False)]:
-        monkeypatch.setenv("KEYSTONE_PROFILE", raw)
-        obs.configure("warning", profile=None)
-        assert timing._profiling is want, (raw, want)
-    monkeypatch.delenv("KEYSTONE_PROFILE")
-    obs.configure("warning", profile=False)
+def test_trace_records_span_totals_and_logs(no_tracer, tmp_path, caplog):
+    """``configure(trace=...)`` is the switch now: spans land in the
+    installed tracer (totals and calls in ``span_summary``) and the
+    export logs them by name."""
+    path = str(tmp_path / "trace.json")
+    obs.configure("warning", trace=path)
+    with tracer.span("obs.test_phase"):
+        pass
+    with tracer.span("obs.test_phase"):
+        pass
+    row = tracer.current().span_summary()["obs.test_phase"]
+    assert row["calls"] == 2 and row["seconds"] >= 0.0
+    with caplog.at_level(logging.INFO, logger="keystone_tpu.obs.tracer"):
+        assert obs.export_trace() == path
+    assert "obs.test_phase" in caplog.text
+
+
+@pytest.mark.parametrize("raw,want", [("", False), ("trace.json", True)])
+def test_trace_env_parsing(no_tracer, monkeypatch, tmp_path, raw, want):
+    """``KEYSTONE_TRACE`` alone decides whether ``configure(None)``
+    installs a tracer; ``KEYSTONE_PROFILE`` is gone and switches nothing."""
+    monkeypatch.setenv("KEYSTONE_PROFILE", "1")
+    monkeypatch.setenv("KEYSTONE_TRACE", raw and str(tmp_path / raw))
+    obs.configure("warning")
+    assert (tracer.current() is not None) is want
+    with tracer.span("obs.env") as sp:
+        assert (sp is not tracer.NULL_SPAN) is want
 
 
 def test_bad_env_level_falls_back(monkeypatch, capsys):
@@ -97,45 +112,38 @@ def test_timing_reset_clears_rate_limits():
     assert obs.every(key, 3600.0) is True  # fresh epoch logs immediately
 
 
-def test_phase_holder_sync_path():
-    """A value appended to the yielded holder is what the phase blocks on
-    at exit (the async-dispatch attribution contract)."""
+def test_span_sync_on_blocks_at_exit(no_tracer):
+    """The value handed to ``sync_on`` is what an installed tracer blocks
+    on (and sizes) at span exit — the async-dispatch attribution contract."""
     import jax.numpy as jnp
 
-    obs.configure("warning", profile=True)
-    try:
-        timing.reset()
-        with timing.phase("obs.holder_sync") as holder:
-            holder.append(jnp.ones((4,)) * 2.0)
-        snap = timing.snapshot()
-        assert snap["obs.holder_sync"]["calls"] == 1
-        assert snap["obs.holder_sync"]["seconds"] >= 0.0
-    finally:
-        obs.configure("warning", profile=False)
+    t = tracer.install(tracer.Tracer())
+    with tracer.span("obs.holder_sync") as sp:
+        sp.sync_on(jnp.ones((4,)) * 2.0)
+    (recorded,) = t.spans()
+    assert recorded.sync_target is None and recorded.output_bytes == 16
+    row = t.span_summary()["obs.holder_sync"]
+    assert row["calls"] == 1 and row["sync_seconds"] >= 0.0
 
 
-def test_phase_sync_failure_is_logged_not_swallowed(caplog):
-    """A REAL device error during the phase-exit sync must surface at
-    WARNING (the bare-except that ate stream failures is gone) while the
-    phase still records; non-blockable values stay silent."""
+def test_span_sync_failure_is_logged_not_swallowed(no_tracer, caplog):
+    """A REAL device error during the span-exit sync must surface at
+    WARNING while the span still records; non-blockable values stay
+    silent."""
 
     class _Boom:
         def block_until_ready(self):
             raise RuntimeError("sync exploded")
 
-    obs.configure("warning", profile=True)
-    try:
-        timing.reset()
-        with caplog.at_level(logging.WARNING, logger="keystone_tpu.utils.timing"):
-            with timing.phase("obs.sync_fail", sync=_Boom()):
-                pass
-        assert "device sync failed" in caplog.text
-        assert timing.snapshot()["obs.sync_fail"]["calls"] == 1
+    t = tracer.install(tracer.Tracer())
+    with caplog.at_level(logging.WARNING, logger="keystone_tpu.obs.span"):
+        with tracer.span("obs.sync_fail") as sp:
+            sp.sync_on(_Boom())
+    assert "block_until_ready failed" in caplog.text
+    assert t.span_summary()["obs.sync_fail"]["calls"] == 1
 
-        caplog.clear()
-        with caplog.at_level(logging.WARNING, logger="keystone_tpu.utils.timing"):
-            with timing.phase("obs.sync_plain", sync=object()):
-                pass  # plain objects pass through jax untouched — no noise
-        assert "device sync failed" not in caplog.text
-    finally:
-        obs.configure("warning", profile=False)
+    caplog.clear()
+    with caplog.at_level(logging.WARNING, logger="keystone_tpu.obs.span"):
+        with tracer.span("obs.sync_plain") as sp:
+            sp.sync_on(object())  # plain objects pass through jax untouched
+    assert "block_until_ready failed" not in caplog.text

@@ -4,6 +4,7 @@ estimate-vs-observed audit, serving micro-batch spans, and the CLI
 ``--trace`` wiring."""
 
 import json
+import re
 import threading
 
 import numpy as np
@@ -336,3 +337,297 @@ def test_cli_alias_rejects_unknown_name():
 
     with pytest.raises(SystemExit):
         main(["mnits"])
+
+
+# ---------------------------------------------------------------------------
+# the span primitive (obs.tracer.span): annotation always, a Span in memory
+# while an installed tracer or a profiler session records
+# ---------------------------------------------------------------------------
+
+
+@pytest.fixture
+def session(tmp_path):
+    """A profiler session around the body: ``with session() as done:`` and,
+    after it, ``done.annotations()`` — the host plane's ``ks:`` events as
+    ``(name, start_ns, end_ns, line)`` read from the xplane."""
+    import contextlib
+    import glob
+
+    import jax
+
+    class _Done:
+        def annotations(self):
+            from jax.profiler import ProfileData
+
+            (path,) = glob.glob(
+                str(tmp_path / "plugins" / "profile" / "*" / "*.xplane.pb")
+            )
+            return [
+                (ev.name, ev.start_ns, ev.start_ns + ev.duration_ns, line.name)
+                for plane in ProfileData.from_file(path).planes
+                if plane.name.startswith("/host:")
+                for line in plane.lines
+                for ev in line.events
+                if ev.name.startswith("ks:")
+            ]
+
+    @contextlib.contextmanager
+    def run():
+        options = jax.profiler.ProfileOptions()
+        options.python_tracer_level = 0
+        jax.profiler.start_trace(str(tmp_path), profiler_options=options)
+        try:
+            yield _Done()
+        finally:
+            jax.profiler.stop_trace()
+
+    return run
+
+
+def test_span_off_runs_the_body_and_allocates_no_span(monkeypatch):
+    made = []
+    real = trace_mod.Span
+
+    def counting(*a, **kw):
+        made.append(kw.get("name"))
+        return real(*a, **kw)
+
+    monkeypatch.setattr(trace_mod, "Span", counting)
+    ran = []
+    with trace_mod.span("off.region", rows=3) as sp:
+        ran.append(True)
+        sp.sync_on(object())
+        sp.attrs["path"] = "compiled"
+        sp.attrs.update(more=1)
+    assert ran == [True] and made == []
+    assert sp is trace_mod.NULL_SPAN and dict(sp.attrs) == {}
+    assert trace_mod.session_spans() == []
+    # the counter does count once someone records
+    with trace_mod.install(trace_mod.Tracer()).span("on.region"):
+        pass
+    assert made == ["on.region"]
+
+
+def test_span_propagates_the_bodys_exception_and_still_records():
+    t = _installed()
+    with pytest.raises(KeyError):
+        with trace_mod.span("raises"):
+            raise KeyError("boom")
+    (sp,) = t.spans()
+    assert sp.name == "raises" and sp.end >= sp.start
+    assert t.current_span() is None  # the stack unwound
+
+
+def test_session_records_unsynced_spans_and_the_same_annotations(
+    session, monkeypatch
+):
+    import jax
+    import jax.numpy as jnp
+
+    from keystone_tpu.obs import span as span_mod
+
+    blocked = []
+    monkeypatch.setattr(
+        jax, "block_until_ready", lambda x: blocked.append(x) or x
+    )
+    sized = []
+    monkeypatch.setattr(
+        trace_mod, "cheap_nbytes", lambda x: sized.append(x) or 0
+    )
+    with session() as done:
+        assert trace_mod.current() is None
+        with trace_mod.span("outer", rows=4) as outer:
+            with trace_mod.span("inner") as inner:
+                inner.sync_on(jnp.ones((4,)))
+            with trace_mod.span("inner"):
+                pass
+    with trace_mod.span("after.the.session") as sp:
+        assert sp is trace_mod.NULL_SPAN
+    # never synced, never sized: the device trace knows when the chip ran
+    assert blocked == [] and sized == []
+    assert span_mod.sync_value is trace_mod.sync_value  # what was not called
+    spans = trace_mod.session_spans()  # readable after stop_trace
+    assert [sp.name for sp in spans] == ["inner", "inner", "outer"]
+    assert all(sp.parent_id == outer.span_id for sp in spans[:2])
+    assert spans[2].attrs == {"rows": 4} and spans[0].sync_seconds == 0.0
+    assert all(sp.output_bytes is None for sp in spans)
+
+    # the xplane's host plane holds the same spans with the same nesting
+    notes = sorted(done.annotations(), key=lambda a: a[1])
+    assert [a[0] for a in notes] == ["ks:outer", "ks:inner", "ks:inner"]
+    (_, o_start, o_end, o_line), first, second = notes
+    for _, start, end, line in (first, second):
+        assert o_start <= start <= end <= o_end and line == o_line
+    assert first[2] <= second[1]
+    # ... and on one clock once shifted: durations agree to a millisecond
+    for sp, note in zip(sorted(spans, key=lambda s: s.start), notes):
+        assert abs((note[2] - note[1]) * 1e-9 - sp.seconds) < 1e-3
+
+
+def test_a_new_session_starts_a_new_list_and_the_list_is_bounded(
+    session, monkeypatch
+):
+    monkeypatch.setattr(trace_mod, "SESSION_MAX_SPANS", 3)
+    with session():
+        for i in range(5):
+            with trace_mod.span(f"s{i}"):
+                pass
+    assert [sp.name for sp in trace_mod.session_spans()] == ["s0", "s1", "s2"]
+    assert trace_mod._session.dropped == 2
+    with trace_mod.span("between"):  # no session: seen, not recorded
+        pass
+    with session():
+        with trace_mod.span("again"):
+            pass
+    assert [sp.name for sp in trace_mod.session_spans()] == ["again"]
+
+
+def test_installed_tracer_wins_over_a_session_and_syncs(session):
+    import jax.numpy as jnp
+
+    t = _installed()
+    with session():
+        with trace_mod.span("synced") as sp:
+            sp.sync_on(jnp.ones((8,), jnp.float32))
+    assert trace_mod.session_spans() == []  # not "the tracer" of anyone
+    (recorded,) = t.spans()
+    assert recorded.output_bytes == 32 and recorded.sync_target is None
+    assert t.span_summary()["synced"]["calls"] == 1
+
+
+def test_session_recorder_is_not_the_current_tracer(session):
+    """``fit_instrumentation``, ``AutoCacheRule`` and ``cost.finalize`` ask
+    ``current()``: a profiler session must switch none of them on."""
+    with session():
+        with trace_mod.span("seen"):
+            assert trace_mod.current() is None
+        with trace_mod.suspended():
+            with trace_mod.span("profiling.run") as sp:
+                assert sp is trace_mod.NULL_SPAN
+    assert [sp.name for sp in trace_mod.session_spans()] == ["seen"]
+
+
+def _gather_pull():
+    """A pull with width: two host-bound branches the concurrent executor
+    forces on ``keystone-exec`` workers, under one ``pipeline.pull``."""
+    import time as _time
+
+    from keystone_tpu.workflow.pipeline import Pipeline
+
+    def slow(tag):
+        # per-item and untraceable: fusion and segments leave it a node
+        def fn(x):
+            _time.sleep(0.005)
+            return np.asarray(x) + tag
+
+        return FunctionNode(item_fn=fn, label=f"slow{tag}")
+
+    pipe = Pipeline.gather([slow(1), slow(2)])
+    return pipe.apply(np.ones((4, 2), np.float32))
+
+
+@pytest.mark.parametrize("recorder", ["installed", "session"])
+def test_adopt_parents_worker_spans_under_the_pull(recorder, session):
+    if recorder == "installed":
+        t = _installed()
+        _gather_pull().get()
+        spans = t.spans()
+    else:
+        with session():
+            _gather_pull().get()
+        spans = trace_mod.session_spans()
+    pull = next(sp for sp in spans if sp.name == "pipeline.pull")
+    by_id = {sp.span_id: sp for sp in spans}
+    slow = [sp for sp in spans if sp.name.startswith("node.slow")]
+    assert len(slow) == 2
+    for sp in slow:
+        assert sp.thread_name.startswith("keystone-exec")
+        up = sp
+        while up.parent_id is not None:
+            up = by_id[up.parent_id]
+        assert up is pull, f"{sp.name} is a root on its worker thread"
+
+
+def test_suspension_reaches_the_workers_a_suspended_thread_starts(session):
+    with session():
+        with trace_mod.suspended():
+            _gather_pull().get()
+    assert trace_mod.session_spans() == []
+
+
+# ---------------------------------------------------------------------------
+# one tiny TIMIT job: the spans of every layer, the scopes on the kernels
+# ---------------------------------------------------------------------------
+
+
+@pytest.fixture(scope="module")
+def timit_job(tmp_path_factory):
+    """One tiny TIMIT job on the CPU under a profiler session: its spans,
+    and the lowered text (with debug info) of the block solver's scan and
+    of the fitted apply."""
+    import jax
+    import jax.numpy as jnp
+
+    from keystone_tpu.linalg.bcd import _bcd_scan
+    from keystone_tpu.pipelines.timit import TimitConfig, run, synthetic_timit
+    from keystone_tpu.workflow.env import PipelineEnv
+
+    conf = TimitConfig(
+        num_cosines=2, cosine_features=64, num_classes=5, num_epochs=2
+    )
+    train = synthetic_timit(256, 5, seed=1)
+    test = synthetic_timit(64, 5, seed=2)
+    PipelineEnv.get_or_create().reset()
+    trace_mod.reset()
+    options = jax.profiler.ProfileOptions()
+    options.python_tracer_level = 0
+    jax.profiler.start_trace(
+        str(tmp_path_factory.mktemp("timit_job")), profiler_options=options
+    )
+    try:
+        predictor, _, _ = run(train, test, conf)
+    finally:
+        jax.profiler.stop_trace()
+    spans = trace_mod.session_spans()
+    solver = _bcd_scan.lower(
+        jnp.ones((256, 128)), jnp.ones((256, 5)), jnp.float32(0.0),
+        jnp.zeros((128,)), block_size=64, num_iter=2,
+    ).as_text(debug_info=True)
+    apply = jax.jit(predictor.fit().trace_fn()).lower(
+        jnp.ones((64, 440), jnp.float32)
+    ).as_text(debug_info=True)
+    PipelineEnv.get_or_create().reset()
+    return {"spans": spans, "solver": solver, "apply": apply}
+
+
+@pytest.mark.parametrize("name", [
+    "plan.build", "plan.optimize", "plan.segments", "exec.segment",
+    "block_ls.solve", "xfer.d2h", "eval.metrics",
+])
+def test_a_timit_job_emits_the_span_inside_job(timit_job, name):
+    spans = timit_job["spans"]
+    (job,) = [sp for sp in spans if sp.name == "job"]
+    assert job.attrs == {"pipeline": "Timit"} and job.parent_id is None
+    by_id = {sp.span_id: sp for sp in spans}
+    named = [sp for sp in spans if sp.name == name]
+    assert named, sorted({sp.name for sp in spans})
+    for sp in named:
+        up = sp
+        while up.parent_id is not None:
+            up = by_id[up.parent_id]
+        assert up is job, f"{name} is not inside job"
+        assert job.start <= sp.start <= sp.end <= job.end
+
+
+@pytest.mark.parametrize("text,scope", [
+    ("solver", "ks.solver.gram"), ("solver", "ks.solver.cross"),
+    ("solver", "ks.solver.factor_solve"), ("solver", "ks.solver.residual"),
+    ("apply", "ks.featurize.cosine"), ("apply", "ks.apply.scores"),
+    ("apply", "ks.apply.argmax"),
+])
+def test_the_kernels_lower_under_their_named_scopes(timit_job, text, scope):
+    # loc("jit(fn)/<scope>") on a call, loc("<scope>/<op>") inside a scan
+    assert re.search(rf'["/]{re.escape(scope)}["/]', timit_job[text])
+    # metadata only: the jitted functions keep the names the benchmark's
+    # older patterns match on
+    assert "_bcd_scan_impl" in timit_job["solver"]
