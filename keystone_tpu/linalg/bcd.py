@@ -29,7 +29,13 @@ import jax.numpy as jnp
 # most visible to solver readers.
 from ..data.pipeline_scan import scan_pipeline
 from ..obs.tracer import span
-from .row_matrix import SOLVER_PRECISION, _mm, solve_spd  # noqa: F401
+from .row_matrix import (  # noqa: F401
+    SOLVER_PRECISION,
+    _mm,
+    factor_spd,
+    solve_factored,
+    solve_spd,
+)
 
 
 def _block_update_impl(
@@ -170,8 +176,13 @@ def solve_blockwise_l2_scan(
     (d,) column-mean vector; centering is fused into the block GEMMs.
     Returns the full (d, k) weight matrix.
 
-    Measured on one v5e (n=131072, d=16384, k=147, precision=high):
-    bs=1024 → 30.8% of f32 peak, bs=2048 → 36.1%, bs=4096 → 42.5%.
+    With ``num_iter > 1`` each block's Gram is formed and Cholesky-factored
+    once a fit, ahead of the epochs, and the lower factors stay in HBM as
+    one (d / block_size, block_size, block_size) array — d·block_size·4
+    bytes, a block_size/n share of A (268 MB at d=16384, bs=4096); every
+    epoch then does the residual, the cross product, the two triangular
+    solves and the prediction update. ``num_iter == 1`` keeps nothing.
+    What it costs and gains on the chip: ``PERF.md`` §5 and §6 (PR 26).
     """
     A = jnp.asarray(A, dtype=dtype)
     y = jnp.asarray(y, dtype=dtype)
@@ -235,7 +246,8 @@ def _bcd_scan_model_sharded(n, d, block_size, num_iter, has_means):
 
         def fn(A, y, reg, means):
             return _bcd_scan_impl(
-                A, y, reg, means, block_size=block_size, num_iter=num_iter
+                A, y, reg, means, block_size=block_size, num_iter=num_iter,
+                factor_sharding=w_s,
             )
 
         jitted = jax.jit(
@@ -628,7 +640,41 @@ def stream_column_means(chunk_scan, dtype=jnp.float32, lanes: Optional[int] = No
     return total / n, n
 
 
-def _bcd_scan_impl(A, y, reg, means, init=None, *, block_size, num_iter):
+def _keeps_factors(num_iter: int) -> bool:
+    """Whether :func:`_bcd_scan_impl` factors each block once, ahead of its
+    epochs, and keeps the factors: whenever a second epoch would otherwise
+    form and factor the same matrices again."""
+    return num_iter > 1
+
+
+def scan_solver_work(d: int, block_size: int, num_iter: int) -> dict:
+    """What the program :func:`_bcd_scan_impl` lowers to computes and keeps
+    (the ``block_ls.solve`` span's attrs): its (block_size, block_size) Gram
+    products, each followed by one Cholesky factorisation, and the bytes of
+    the float32 factor stack it holds for the length of the fit."""
+    nblocks = d // block_size
+    if not _keeps_factors(num_iter):
+        return {"gram_products": nblocks * num_iter, "factor_bytes": 0}
+    return {
+        "gram_products": nblocks,
+        "factor_bytes": nblocks * block_size * block_size * 4,
+    }
+
+
+def _bcd_scan_impl(
+    A, y, reg, means, init=None, *, block_size, num_iter, factor_sharding=None
+):
+    """The whole BCD solve as one program: a scan over epochs of a scan over
+    blocks.
+
+    Invariant: ``G_j = Ã_jᵀÃ_j`` and the factor of ``G_j + reg·I`` depend on
+    ``A``, ``means`` and ``reg`` only — never on the epoch. So with more
+    than one epoch a scan over blocks AHEAD of the epochs forms and factors
+    each once, and every epoch solves against the kept (nblocks, block_size,
+    block_size) stack; a one-pass fit factors inside its only epoch and
+    keeps nothing. ``factor_sharding`` (the model-sharded compile's) lays
+    the stack out by blocks, as ``W`` is.
+    """
     n, d = A.shape
     nblocks = d // block_size
     k = y.shape[1]
@@ -642,27 +688,57 @@ def _bcd_scan_impl(A, y, reg, means, init=None, *, block_size, num_iter):
         Ac = A if means is None else A - means
         pred0 = _mm(Ac, init)
 
+    def block(j):
+        Aj = jax.lax.dynamic_slice_in_dim(A, j * block_size, block_size, axis=1)
+        if means is not None:
+            mj = jax.lax.dynamic_slice_in_dim(means, j * block_size, block_size)
+            Aj = Aj - mj
+        return Aj
+
+    # named scopes are metadata (a profile groups device time by them, the
+    # benchmark's readers match on them): they change neither the lowering
+    # nor the persistent cache's key
+    def factor(_, j):
+        Aj = block(j)
+        with jax.named_scope("ks.solver.gram"):
+            G = _mm(Aj.T, Aj)
+        with jax.named_scope("ks.solver.factor_solve"):
+            return None, factor_spd(G, reg)
+
+    L = None
+    if _keeps_factors(num_iter):
+        _, L = jax.lax.scan(factor, None, jnp.arange(nblocks))
+        if factor_sharding is not None:
+            L = jax.lax.with_sharding_constraint(L, factor_sharding)
+
+    def kept_factor(j):
+        if factor_sharding is None:
+            return L[j]
+        # sharded by blocks: a masked sum is a reduce on the shard that holds
+        # block j plus one (block_size, block_size) all-reduce, where L[j]
+        # would all-gather the whole stack in every block step
+        mine = (jnp.arange(nblocks) == j)[:, None, None]
+        return jnp.sum(jnp.where(mine, L, 0), axis=0)
+
     def epoch(carry, _):
         W, pred = carry
 
         def block_step(carry, j):
             W, pred = carry
-            Aj = jax.lax.dynamic_slice_in_dim(A, j * block_size, block_size, axis=1)
-            if means is not None:
-                mj = jax.lax.dynamic_slice_in_dim(means, j * block_size, block_size)
-                Aj = Aj - mj
+            Aj = block(j)
             Wj = W[j]
-            # named scopes are metadata (a profile groups device time by
-            # them, the benchmark's readers match on them): they change
-            # neither the lowering nor the persistent cache's key
             with jax.named_scope("ks.solver.residual"):
                 r = y - pred + _mm(Aj, Wj)
-            with jax.named_scope("ks.solver.gram"):
-                G = _mm(Aj.T, Aj)
+            if L is None:
+                with jax.named_scope("ks.solver.gram"):
+                    G = _mm(Aj.T, Aj)
             with jax.named_scope("ks.solver.cross"):
                 c = _mm(Aj.T, r)
             with jax.named_scope("ks.solver.factor_solve"):
-                Wj_new = solve_spd(G, c, reg)
+                if L is None:
+                    Wj_new = solve_spd(G, c, reg)
+                else:
+                    Wj_new = solve_factored(kept_factor(j), c)
             with jax.named_scope("ks.solver.residual"):
                 pred = pred + _mm(Aj, Wj_new - Wj)
             W = W.at[j].set(Wj_new)

@@ -53,12 +53,22 @@ def cross(A: jax.Array, B: jax.Array) -> jax.Array:
     return _mm(A.T, B)
 
 
+def factor_spd(G: jax.Array, reg: float = 0.0) -> jax.Array:
+    """The lower Cholesky factor of (G + reg·I), for :func:`solve_factored`:
+    a caller that solves against one G many times factors it once."""
+    G = G + reg * jnp.eye(G.shape[0], dtype=G.dtype)
+    return jax.scipy.linalg.cho_factor(G, lower=True)[0]
+
+
+def solve_factored(L: jax.Array, rhs: jax.Array) -> jax.Array:
+    """Solve (L Lᵀ) X = rhs for a lower factor from :func:`factor_spd`."""
+    return jax.scipy.linalg.cho_solve((L, True), rhs)
+
+
 def solve_spd(G: jax.Array, rhs: jax.Array, reg: float = 0.0) -> jax.Array:
     """Solve (G + reg·I) X = rhs for symmetric positive-definite G via
     Cholesky (the reference's driver-side ``(G+λI) \\ rhs``)."""
-    G = G + reg * jnp.eye(G.shape[0], dtype=G.dtype)
-    cho = jax.scipy.linalg.cho_factor(G, lower=True)
-    return jax.scipy.linalg.cho_solve(cho, rhs)
+    return solve_factored(factor_spd(G, reg), rhs)
 
 
 class RowShardedMatrix:

@@ -399,7 +399,11 @@ class BlockLeastSquaresEstimator(LabelEstimator, CostModel):
         ragged blocks take the per-block-dispatch path.
         """
         from ...data.chunked import ChunkedDataset
-        from ...linalg.bcd import _block_means, solve_blockwise_l2_scan
+        from ...linalg.bcd import (
+            _block_means,
+            scan_solver_work,
+            solve_blockwise_l2_scan,
+        )
         warm = getattr(self, "warm_start_ws", None)  # pre-sweep pickles
         self.warm_start_ws = None
         if isinstance(data, ChunkedDataset):
@@ -438,6 +442,9 @@ class BlockLeastSquaresEstimator(LabelEstimator, CostModel):
                 y_mean = jnp.mean(y, axis=0)
                 sp.sync_on((mean_vec, y_mean))
             with span("block_ls.solve") as sp:
+                sp.attrs.update(
+                    scan_solver_work(d, self.block_size, self.num_iter)
+                )
                 init = None
                 if warm is not None:
                     cat = jnp.concatenate(

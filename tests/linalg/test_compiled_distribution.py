@@ -158,6 +158,38 @@ def test_block_solver_model_axis_sharding():
     np.testing.assert_allclose(np.asarray(W), W_rep, rtol=2e-4, atol=2e-5)
 
 
+def test_kept_factor_stack_shards_by_blocks_over_the_model_axis():
+    """A multi-epoch fit keeps its factors (PR 26); on a data×model mesh the
+    stack lies by blocks over MODEL_AXIS as W does — the axis exists to buy
+    memory — and no block step gathers it whole."""
+    from keystone_tpu.linalg import solve_blockwise_l2_scan
+    from keystone_tpu.linalg.bcd import _bcd_scan_model_sharded
+
+    n, d, k, bs, n_model = 64, 32, 3, 4, 2
+    nblocks = d // bs
+    rng = np.random.default_rng(8)
+    An = rng.standard_normal((n, d)).astype(np.float32)
+    yn = rng.standard_normal((n, k)).astype(np.float32)
+    means = An.mean(axis=0)
+
+    def solve():
+        return solve_blockwise_l2_scan(
+            jnp.asarray(An), jnp.asarray(yn), reg=1.0, block_size=bs,
+            num_iter=3, means=jnp.asarray(means),
+        )
+
+    W_rep = np.asarray(solve())
+    with use_mesh(make_mesh(n_data=4, n_model=n_model)):
+        W = np.asarray(solve())
+        txt = _bcd_scan_model_sharded(n, d, bs, 3, True).lower(
+            jnp.asarray(An), jnp.asarray(yn), jnp.float32(1.0),
+            jnp.asarray(means),
+        ).compile().as_text()
+    np.testing.assert_allclose(W, W_rep, rtol=2e-4, atol=2e-5)
+    assert f"f32[{nblocks // n_model},{bs},{bs}]" in txt, "no local stack"
+    assert f"f32[{nblocks},{bs},{bs}]" not in txt, "the whole stack somewhere"
+
+
 def test_block_estimator_uses_model_axis_on_mixed_mesh():
     """BlockLeastSquaresEstimator.fit on a data×model mesh produces the
     same model as on a pure data mesh (the sharded compile is routed

@@ -175,3 +175,127 @@ def test_injected_bf16_gram_fails_the_bar():
         A64.T @ A64 + reg * np.eye(A.shape[1]), A64.T @ y.astype(np.float64)
     )
     assert _rel(W_bf16, W64) > RTOL
+
+
+# ---------------------------------------------------------------------------
+# the scan solver factors each block once a fit (PR 26)
+# ---------------------------------------------------------------------------
+
+
+@pytest.mark.parametrize("reg", [0.0, 0.1])
+@pytest.mark.parametrize("warm", [False, True], ids=["cold", "init"])
+@pytest.mark.parametrize("centered", [False, True], ids=["raw", "means"])
+@pytest.mark.parametrize("num_iter", [2, 5])
+def test_kept_factors_equal_the_recomputing_block_path(
+    num_iter, centered, warm, reg
+):
+    """``_bcd_scan`` solves every epoch against factors made once; the
+    per-block path still forms and factors each block's Gram in every
+    epoch. Same numbers in the same order: they agree far inside the
+    float64 bar."""
+    from keystone_tpu.linalg.bcd import _bcd_scan
+
+    n, d, k, bs = 512, 64, 3, 16
+    rng = np.random.default_rng(7)
+    A = rng.standard_normal((n, d)).astype(np.float32) + 0.5
+    y = rng.standard_normal((n, k)).astype(np.float32)
+    means = A.mean(axis=0) if centered else None
+    init = (
+        0.1 * rng.standard_normal((d, k)).astype(np.float32) if warm else None
+    )
+    blocks = [jnp.asarray(A[:, i : i + bs]) for i in range(0, d, bs)]
+    Ws = solve_blockwise_l2(
+        blocks, jnp.asarray(y), reg=reg, num_iter=num_iter,
+        means=None if means is None else [
+            jnp.asarray(means[i : i + bs]) for i in range(0, d, bs)
+        ],
+        init=None if init is None else [
+            jnp.asarray(init[i : i + bs]) for i in range(0, d, bs)
+        ],
+    )
+    W_loop = np.concatenate([np.asarray(w) for w in Ws], axis=0)
+    args = (
+        jnp.asarray(A), jnp.asarray(y), jnp.float32(reg),
+        None if means is None else jnp.asarray(means),
+    )
+    if init is not None:
+        args += (jnp.asarray(init),)
+    W_scan = np.asarray(_bcd_scan(*args, block_size=bs, num_iter=num_iter))
+    assert _rel(W_scan, W_loop) < 1e-5
+
+
+def _eqns(jaxpr):
+    """Every equation of a jaxpr and of the jaxprs its equations hold."""
+    for eqn in jaxpr.eqns:
+        yield eqn
+        for sub in jax.core.jaxprs_in_params(eqn.params):
+            yield from _eqns(sub)
+
+
+def _scan_program(num_iter, nblocks=4, bs=8, n=32, k=3):
+    from keystone_tpu.linalg.bcd import _bcd_scan_impl
+
+    return jax.make_jaxpr(
+        lambda A, y, reg, means: _bcd_scan_impl(
+            A, y, reg, means, block_size=bs, num_iter=num_iter
+        )
+    )(
+        jnp.ones((n, nblocks * bs)), jnp.ones((n, k)), jnp.float32(0.1),
+        jnp.zeros((nblocks * bs,)),
+    ).jaxpr
+
+
+def _shapes(eqns):
+    return {
+        tuple(v.aval.shape)
+        for eqn in eqns
+        for v in list(eqn.invars) + list(eqn.outvars)
+        if hasattr(v.aval, "shape")
+    }
+
+
+def test_five_epochs_factor_outside_the_epoch_scan():
+    nblocks, bs = 4, 8
+    program = _scan_program(num_iter=5, nblocks=nblocks, bs=bs)
+    (epochs,) = [
+        e for e in program.eqns
+        if e.primitive.name == "scan" and e.params["length"] == 5
+    ]
+    body = list(_eqns(epochs.params["jaxpr"].jaxpr))
+    assert not [e for e in body if e.primitive.name == "cholesky"]
+    assert not [
+        e for e in body
+        if e.primitive.name == "dot_general"
+        and tuple(e.outvars[0].aval.shape) == (bs, bs)
+    ]
+    # the epochs do solve, against the kept stack
+    assert [e for e in body if e.primitive.name == "triangular_solve"]
+    assert (nblocks, bs, bs) in _shapes(body)
+    whole = list(_eqns(program))
+    assert len([e for e in whole if e.primitive.name == "cholesky"]) == 1
+    assert len([
+        e for e in whole
+        if e.primitive.name == "dot_general"
+        and tuple(e.outvars[0].aval.shape) == (bs, bs)
+    ]) == 1
+
+
+def test_one_epoch_factors_in_place_and_keeps_no_stack():
+    nblocks, bs = 4, 8
+    whole = list(_eqns(_scan_program(num_iter=1, nblocks=nblocks, bs=bs)))
+    assert len([e for e in whole if e.primitive.name == "cholesky"]) == 1
+    assert (nblocks, bs, bs) not in _shapes(whole)
+
+
+@pytest.mark.parametrize("num_iter,kept", [(1, False), (3, True)])
+def test_scan_solver_work_states_what_the_program_keeps(num_iter, kept):
+    from keystone_tpu.linalg.bcd import scan_solver_work
+
+    nblocks, bs = 4, 8
+    work = scan_solver_work(nblocks * bs, bs, num_iter)
+    whole = list(_eqns(_scan_program(num_iter=num_iter, nblocks=nblocks, bs=bs)))
+    assert ((nblocks, bs, bs) in _shapes(whole)) is kept
+    assert work == {
+        "gram_products": nblocks,
+        "factor_bytes": nblocks * bs * bs * 4 if kept else 0,
+    }

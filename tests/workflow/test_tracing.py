@@ -631,3 +631,29 @@ def test_the_kernels_lower_under_their_named_scopes(timit_job, text, scope):
     # metadata only: the jitted functions keep the names the benchmark's
     # older patterns match on
     assert "_bcd_scan_impl" in timit_job["solver"]
+
+
+@pytest.mark.parametrize("num_iter,keeps", [(3, True), (1, False)])
+def test_block_ls_solve_span_says_what_the_solver_program_keeps(
+    num_iter, keeps
+):
+    """The counter that says factor-once engaged: ``gram_products`` is the
+    Gram products (and factorisations) the dispatched program computes,
+    ``factor_bytes`` the stack it holds across the epochs."""
+    import jax.numpy as jnp
+
+    from keystone_tpu.nodes.learning import BlockLeastSquaresEstimator
+
+    n, d, k, bs = 64, 32, 3, 8
+    rng = np.random.default_rng(0)
+    tracer = _installed()
+    BlockLeastSquaresEstimator(block_size=bs, num_iter=num_iter, lam=0.1).fit(
+        Dataset.of(jnp.asarray(rng.standard_normal((n, d)), jnp.float32)),
+        Dataset.of(jnp.asarray(rng.standard_normal((n, k)), jnp.float32)),
+    )
+    (solve,) = [sp for sp in tracer.spans() if sp.name == "block_ls.solve"]
+    assert solve.attrs["gram_products"] == d // bs
+    if keeps:
+        assert solve.attrs["factor_bytes"] == (d // bs) * bs * bs * 4
+    else:
+        assert solve.attrs["factor_bytes"] == 0
