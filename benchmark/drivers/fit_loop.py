@@ -1,9 +1,12 @@
 """``fit_loop``: whole fit jobs back to back on device-resident data — a
 fit is a batch job, so the loop is closed and has one client. Every job
-builds fresh estimators and ends synchronised."""
+builds fresh estimators, starts with the last job's garbage collected and
+ends synchronised. ``fit_s`` is the whole window over its jobs."""
 
 from __future__ import annotations
 
+import gc
+import statistics
 import time
 
 import numpy as np
@@ -36,21 +39,36 @@ def setup(run):
 def window(run, state, seconds: float):
     import jax
 
-    fits = 0
+    job_s = []
     handle = None
-    t0 = time.perf_counter()
+    t0 = start = time.perf_counter()
     while True:
         with jax.profiler.TraceAnnotation("bench:fit.step"):
             del handle  # two jobs' features do not fit side by side
+            # A job starts as in a process of its own: with none of an
+            # earlier job's garbage. Left to the collector's own schedule,
+            # where its young passes fall among the jobs decides which jobs
+            # take 586 ms and which 606-620, a pattern that moves as a
+            # whole with any change to what the process allocates (PERF.md
+            # section 6, PR 28). 0.1 ms, inside the timed window; the
+            # collector stays on.
+            gc.collect(1)
             handle = _job(run, state)
-        fits += 1
-        elapsed = time.perf_counter() - t0
-        if elapsed >= seconds:
+        now = time.perf_counter()
+        job_s.append(now - start)
+        start = now
+        if now - t0 >= seconds:
             break
+    fits, elapsed = len(job_s), now - t0
+    # fit_s is all of the window over all of its jobs: a stalled job is a
+    # job the user paid. The steadier statistics stand beside it.
     run.facts.update(
-        fit_s=elapsed / fits, fits=fits, units=fits, window_s=elapsed,
-        attempted=fits, failed=0,
+        fit_s=elapsed / fits, fit_median_s=statistics.median(job_s),
+        fits=fits, units=fits, window_s=elapsed, attempted=fits, failed=0,
+        job_ms=[round(1e3 * s, 2) for s in job_s],
     )
+    if fits > 10:  # the highest percentile with ten jobs beyond it
+        run.facts["fit_p_high_s"] = sorted(job_s)[-11]
     model = run.program.model(handle)
     return {"model": model, "test_error": handle.test_error}
 

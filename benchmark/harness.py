@@ -239,6 +239,15 @@ def _read_layer_metrics(manifest: Manifest, run: Run) -> dict:
     return out
 
 
+def _is_shown(fact) -> bool:
+    """A fact the report line prints: a number, or up to 256 of them."""
+    number = (int, float)
+    return isinstance(fact, number) or (
+        isinstance(fact, list) and len(fact) <= 256
+        and all(isinstance(x, number) for x in fact)
+    )
+
+
 def _end_to_end(manifest: Manifest, run: Run, setup_s: float) -> dict:
     facts = dict(run.facts, setup_s=setup_s)
     out = {}
@@ -392,6 +401,11 @@ def execute(manifest, driver, *, cell, config, traffic, args, device, peak,
                         if k.startswith("window.")}
     report["window"]["cache_entries_added"] = entries_window - entries_setup
     report["window"]["seconds"] = run.facts.get("window_s")
+    # the driver's own numbers: the metric's statistic beside the others,
+    # and a short list such as each job's duration
+    report["window"]["facts"] = {
+        k: v for k, v in run.facts.items() if _is_shown(v)
+    }
     report["reference_s"] = round(reference_s, 3)
     # every number the comparison read, those that have no limit too
     report["compared_all"] = run.facts.get("compared_all")

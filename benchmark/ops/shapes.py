@@ -22,18 +22,21 @@ def featurize_row(config: dict) -> dict:
 
 
 def solve(config: dict, n: int) -> dict:
-    """Block coordinate descent over n rows: per epoch and block the Gram
-    (2·n·bs²), the residual, cross and prediction products (6·n·bs·k) and
-    a Cholesky (bs³/3) — bench.py's ``block_shape`` arithmetic."""
+    """The least work of block coordinate descent over n rows, whatever
+    implements it — not a description of ``_bcd_scan_impl``. A block's Gram
+    does not depend on the epoch, so it and its Cholesky (bs³/3) are needed
+    once a block: 2·n·d·bs in all. Every epoch and block needs the three
+    k-wide products (the residual, the cross product, the prediction
+    update: 6·n·bs·k) and a pair of triangular solves (2·bs²·k). The bytes
+    are one read of the features for the Gram and one for each k-wide
+    product of each epoch. With one epoch the products are bench.py's
+    ``block_shape`` arithmetic."""
     d, bs, k = config["d"], config["block_size"], config["num_classes"]
     nb, epochs = d // bs, config["epochs"]
-    gemm = epochs * (2.0 * n * d * bs + 6.0 * n * d * k)
     return {
-        "gemm_flops": gemm,
-        "other_flops": epochs * nb * bs**3 / 3.0,
-        # each block of columns is read for the Gram, the cross product
-        # and the prediction update
-        "bytes": epochs * 3.0 * F32 * n * d,
+        "gemm_flops": 2.0 * n * d * bs + epochs * 6.0 * n * d * k,
+        "other_flops": nb * bs**3 / 3.0 + epochs * nb * 2.0 * bs**2 * k,
+        "bytes": F32 * n * d * (1.0 + 3.0 * epochs),
     }
 
 
