@@ -109,13 +109,16 @@ def plan_segments(
     specs: Dict[Any, Any],
     *,
     cost_estimator: Any = None,
+    materialized: Any = (),
 ) -> Tuple[List[Segment], Dict[Any, str]]:
     """Partition ``graph`` into maximal traceable segments.
 
     Returns ``(segments, barriers)`` where ``barriers`` maps each
     non-segment node to its reason. Segments are connected components of
     the segment-eligible node set under graph edges, numbered in
-    topological order of their first node.
+    topological order of their first node. ``materialized`` are nodes whose
+    value the caller already holds (an executor's memo): data, like saved
+    state — a segment through one would compute it again from its inputs.
     """
     from ..workflow import analysis
     from ..workflow.graph import NodeId
@@ -135,7 +138,7 @@ def plan_segments(
 
     for n in order:
         op = graph.get_operator(n)
-        reason = barrier_reason(
+        reason = BARRIER_SAVED if n in materialized else barrier_reason(
             op, verdicts.get(n, lattice.OPAQUE),
             is_chunked_leaf=leaf_is_chunked(op),
         )

@@ -17,7 +17,10 @@ A warm FIT therefore boots zero-trace.
 lowered steps, the content digest, and the three runtime paths —
 
 * **compiled** — all-batched array inputs dispatch the whole segment as
-  one program (one Python dispatch for N nodes);
+  one program (one Python dispatch for N nodes); where the members'
+  outputs over the whole batch outgrow what the device has free, the ROWS
+  are dispatched in fixed-size slices through one program of the slice's
+  shape and the outputs joined (:meth:`SegmentBinding.row_plan`);
 * **chunked** — a single-output segment over chunked data rides the
   out-of-core scan per chunk through :class:`ChunkPadder` (ragged final
   chunks pad to the bucket ladder, results slice back);
@@ -35,6 +38,7 @@ layer (read per pull by the executor, not here).
 
 from __future__ import annotations
 
+import functools
 import logging
 import threading
 import time
@@ -118,6 +122,10 @@ class SegmentDispatcher:
         self._n_nodes = n_nodes
         self._env = environment_key() if cache is not None else None
         self._by_sig: Dict[Tuple[Signature, ...], Callable] = {}
+        #: input signatures -> bytes of every member's output for ONE row
+        #: (:func:`_item_bytes`); kept here, so a refit of the same
+        #: pipeline pays the abstract evaluation once a process
+        self.item_bytes: Dict[Tuple[Signature, ...], int] = {}
         self._structural: Optional[Callable] = None
         self._lock = threading.Lock()
         self._loaded = 0
@@ -374,6 +382,118 @@ def reset_dispatchers() -> None:
 
 
 # ---------------------------------------------------------------------------
+# What a compiled dispatch can observe of its size: the bytes its members'
+# outputs take a row, and the device's memory.
+# ---------------------------------------------------------------------------
+
+
+def _item_bytes(
+    sigs: Tuple[Signature, ...], steps: List[Step],
+    out_slots: Tuple[int, ...],
+) -> int:
+    """Bytes ONE row generates across the segment's member outputs at
+    these input shapes — what ``check/segments.py`` prices from specs,
+    taken here from the shapes the dispatch really has (``jax.eval_shape``:
+    no operation runs). 0 where an output does not keep the row axis (the
+    segment cannot be cut by rows) or the evaluation fails."""
+    import jax
+    import numpy as np
+
+    from ..workflow.fusion import FusedTransformerOperator
+    from ..workflow.operators import GatherTransformerOperator
+
+    produced: List[Any] = []
+
+    def trace(op, args):
+        # a fused chain is priced by ITS members: the optimizer has folded
+        # the user's nodes into one operator, their outputs are still made
+        if isinstance(op, FusedTransformerOperator):
+            values = list(args)
+            for inner, deps in op.steps:
+                values.append(trace(inner, [values[i] for i in deps]))
+            return values[-1]
+        if isinstance(op, GatherTransformerOperator):
+            return tuple(args)
+        out = op.trace_batch(*args)
+        produced.append(out)
+        return out
+
+    def members(*xs):
+        values = list(xs)
+        for op, slots in steps:
+            values.append(trace(op, [values[s] for s in slots]))
+        return produced, [values[s] for s in out_slots]
+
+    rows = sigs[0][0][0]
+    try:
+        specs = [jax.ShapeDtypeStruct(s, np.dtype(d)) for s, d in sigs]
+        made, outs = jax.eval_shape(members, *specs)
+        leaves = jax.tree_util.tree_leaves(made)
+        row_wise = all(
+            o.shape and o.shape[0] == rows
+            for o in jax.tree_util.tree_leaves(outs)
+        )
+        total = sum(
+            int(np.prod(v.shape)) * np.dtype(v.dtype).itemsize for v in leaves
+        )
+        return -(-total // rows) if row_wise else 0
+    except Exception:
+        logger.debug("segment: no per-row size estimate", exc_info=True)
+        return 0
+
+
+def _device_memory() -> Optional[Tuple[int, int]]:
+    """``(free, limit)`` bytes of the fullest device, as jax's memory
+    stats give them (what ``benchmark/harness.peak_bytes`` reads), or None
+    where the backend reports none (the CPU)."""
+    import jax
+
+    worst = None
+    for dev in jax.local_devices():
+        stats = dev.memory_stats() or {}
+        limit = stats.get("bytes_limit")
+        if not limit:
+            return None
+        free = int(limit) - int(stats.get("bytes_in_use", 0))
+        if worst is None or free < worst[0]:
+            worst = (free, int(limit))
+    return worst
+
+
+@functools.lru_cache(maxsize=None)
+def _row_slice_jit() -> Callable:
+    import jax
+
+    return jax.jit(
+        lambda a, start, rows: jax.lax.dynamic_slice_in_dim(
+            a, start, rows, axis=0
+        ),
+        static_argnums=(2,),
+    )
+
+
+@functools.lru_cache(maxsize=None)
+def _row_put_jit() -> Callable:
+    """``buf[start : start + len(part)] = part`` as one program whatever
+    the start — in place on an accelerator; the CPU backend's donation is
+    not to be trusted (``linalg/bcd.py`` ``_block_update``)."""
+    import jax
+
+    return jax.jit(
+        lambda buf, part, start: jax.lax.dynamic_update_slice_in_dim(
+            buf, part, start, axis=0
+        ),
+        donate_argnums=() if jax.default_backend() == "cpu" else (0,),
+    )
+
+
+def _row_slice(a: Any, start: int, rows: int):
+    """``a[start : start + rows]`` by ONE compiled program whatever the
+    start (a Python slice compiles a program a start)."""
+    return _row_slice_jit()(a, start, rows)
+
+
+# ---------------------------------------------------------------------------
 # SegmentBinding: the executor-facing handle
 # ---------------------------------------------------------------------------
 
@@ -425,16 +545,23 @@ class SegmentBinding:
             n_nodes=len(self.steps),
         )
 
-    def run(self, datasets: List[Any]) -> Tuple[Tuple[Any, ...], str]:
+    def run(
+        self, datasets: List[Any], facts: Optional[dict] = None
+    ) -> Tuple[Tuple[Any, ...], str]:
+        """``facts`` (the ``exec.segment`` span's attrs) is given what the
+        compiled path dispatched: ``rows``, ``row_slices``, ``slice_rows``
+        (one slice of all the rows is the whole-batch dispatch)."""
         if self._demoted:
             return self._fallback(datasets), "fallback"
         try:
-            return self._run(datasets)
+            return self._run(datasets, {} if facts is None else facts)
         except Exception as e:
             self._demote(f"runtime failure: {e!r}")
             return self._fallback(datasets), "fallback"
 
-    def _run(self, datasets: List[Any]) -> Tuple[Tuple[Any, ...], str]:
+    def _run(
+        self, datasets: List[Any], facts: dict
+    ) -> Tuple[Tuple[Any, ...], str]:
         from ..data.chunked import ChunkedDataset, align_and_zip
         from ..data.dataset import Dataset
         from ..data.pipeline_scan import ChunkPadder
@@ -461,7 +588,17 @@ class SegmentBinding:
 
             disp = self._dispatcher()
             t0 = time.perf_counter()
-            raw = disp(*[ds.to_array() for ds in datasets])
+            arrays = [ds.to_array() for ds in datasets]
+            rows, slice_rows = self.row_plan(disp, arrays)
+            if slice_rows < rows:
+                raw = self._dispatch_row_slices(disp, arrays, rows, slice_rows)
+            else:
+                raw = disp(*arrays)
+            if rows:
+                facts.update(
+                    rows=rows, row_slices=-(-rows // slice_rows),
+                    slice_rows=slice_rows,
+                )
             seg_cost.record_run(
                 self.digest, time.perf_counter() - t0,
                 n_nodes=len(self.steps),
@@ -472,6 +609,86 @@ class SegmentBinding:
             )
         # item-list inputs: per-node dispatch is the honest semantics
         return self._fallback(datasets), "fallback"
+
+    def row_plan(
+        self, disp: "SegmentDispatcher", arrays: List[Any]
+    ) -> Tuple[int, int]:
+        """``(rows, slice_rows)`` for one compiled dispatch over ``arrays``.
+        ``slice_rows == rows`` is the whole batch in one program — every
+        segment whose members' outputs fit what the device has free, and
+        every segment this cannot judge: rows that couple, inputs that do
+        not share a leading axis, a device that reports no memory (the
+        CPU), an estimate that failed. ``rows`` is 0 where the inputs are
+        not arrays over one row axis.
+
+        Otherwise the rows go through in slices: the largest power of two
+        of rows whose members' outputs take at most a quarter of the
+        device's memory (a constant, so that every job of a process cuts
+        alike and reuses one program), halved while that outgrows half of
+        what is free now."""
+        try:
+            sigs = tuple(signature_of(a) for a in arrays)
+        except (AttributeError, TypeError):
+            return 0, 0
+        rows = sigs[0][0][0] if sigs[0][0] else 0
+        if not rows or any(not s or s[0] != rows for s, _ in sigs):
+            return 0, 0
+        if self.batch_coupled:
+            return rows, rows
+        memory = _device_memory()
+        if memory is None:
+            return rows, rows
+        free, limit = memory
+        item_bytes = disp.item_bytes.get(sigs)
+        if item_bytes is None:
+            item_bytes = disp.item_bytes[sigs] = _item_bytes(
+                sigs, self.steps, self.out_slots
+            )
+        if not item_bytes:
+            return rows, rows
+        if rows * item_bytes <= free:
+            return rows, rows
+        slice_rows = 1
+        while 2 * slice_rows * item_bytes <= limit // 4:
+            slice_rows *= 2
+        while slice_rows > 1 and slice_rows * item_bytes > free // 2:
+            slice_rows //= 2
+        return rows, min(slice_rows, rows)
+
+    @staticmethod
+    def _dispatch_row_slices(
+        disp: "SegmentDispatcher", arrays: List[Any], rows: int,
+        slice_rows: int,
+    ) -> Tuple[Any, ...]:
+        """The segment over ``rows`` rows, ``slice_rows`` at a time through
+        the one program of that shape, each slice's outputs written into
+        their place in one buffer an output; the last slice is padded with
+        its first row (as ``ChunkPadder`` pads) and the padding cut from
+        its outputs. Every row goes through once."""
+        import jax.numpy as jnp
+
+        from ..data.pipeline_scan import _pad_rows
+
+        put = _row_put_jit()
+        outs: Optional[List[Any]] = None
+        for start in range(0, rows, slice_rows):
+            take = min(slice_rows, rows - start)
+            xs = [_row_slice(a, start, take) for a in arrays]
+            if take < slice_rows:
+                xs = [_pad_rows(x, take, slice_rows) for x in xs]
+            part = disp(*xs)
+            if outs is None:
+                # one buffer an output, written slice by slice: joining the
+                # slices at the end holds them twice, and XLA's many-operand
+                # concatenate a third time (16.06 of 16.9 GB read on the
+                # chip at 16,384 × 80,000 float32; PERF.md §6, PR 29)
+                outs = [
+                    jnp.zeros((rows,) + o.shape[1:], o.dtype) for o in part
+                ]
+            if take < slice_rows:
+                part = tuple(o[:take] for o in part)
+            outs = [put(buf, o, start) for buf, o in zip(outs, part)]
+        return tuple(outs)
 
     def _fallback(self, datasets: List[Any]) -> Tuple[Any, ...]:
         """Exact node semantics: same operators, same topo order, same

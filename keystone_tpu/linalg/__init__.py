@@ -11,6 +11,7 @@ from .normal_equations import (
 )
 from .bcd import (
     solve_blockwise_l2,
+    solve_blockwise_l2_columns,
     solve_blockwise_l2_scan,
     solve_blockwise_l2_streaming,
     stream_column_means,
@@ -39,6 +40,7 @@ __all__ = [
     "gram_accumulate",
     "solve_least_squares_with_intercept",
     "solve_blockwise_l2",
+    "solve_blockwise_l2_columns",
     "solve_blockwise_l2_scan",
     "solve_blockwise_l2_streaming",
     "solve_weighted_streaming",

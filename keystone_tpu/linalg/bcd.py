@@ -57,25 +57,48 @@ def _block_update_impl(
     W_j ← (Ã_jᵀÃ_j + λI)⁻¹ Ã_jᵀ r_j ; pred ← pred + Ã_j (W_j − W_j_old)
     """
     Ajc = Aj - mj
-    r = y - pred + _mm(Ajc, Wj_old)
-    G = _mm(Ajc.T, Ajc)    # psum over data axis
-    c = _mm(Ajc.T, r)      # psum over data axis
-    Wj = solve_spd(G, c, reg)
-    pred = pred + _mm(Ajc, Wj - Wj_old)
+    # the scopes of _bcd_scan_impl: metadata, not lowering
+    with jax.named_scope("ks.solver.residual"):
+        r = y - pred + _mm(Ajc, Wj_old)
+    with jax.named_scope("ks.solver.gram"):
+        G = _mm(Ajc.T, Ajc)    # psum over data axis
+    with jax.named_scope("ks.solver.cross"):
+        c = _mm(Ajc.T, r)      # psum over data axis
+    with jax.named_scope("ks.solver.factor_solve"):
+        Wj = solve_spd(G, c, reg)
+    with jax.named_scope("ks.solver.residual"):
+        pred = pred + _mm(Ajc, Wj - Wj_old)
     return Wj, pred
+
+
+def _block_update_at_impl(A, means, start, Wj_old, pred, y, reg, *, width):
+    """:func:`_block_update_impl` on the ``width`` columns of ``A`` from
+    ``start``. The block is cut inside the program (as ``_bcd_scan_impl``
+    cuts its blocks), so no column slice of a design matrix sits in HBM
+    beside it, and one program serves every block of one width; a block
+    that is an array of its own is its columns from 0."""
+    Aj = jax.lax.dynamic_slice_in_dim(A, start, width, axis=1)
+    mj = jax.lax.dynamic_slice_in_dim(means, start, width)
+    return _block_update_impl(Aj, mj, Wj_old, pred, y, reg)
 
 
 # Donate the prediction buffer on accelerators (in-place HBM update per
 # block). On the CPU backend donation intermittently aborts the process
 # (observed under the 8-device virtual mesh), so plain jit there.
-_block_update_donating = jax.jit(_block_update_impl, donate_argnums=(3,))
-_block_update_plain = jax.jit(_block_update_impl)
+_block_update_donating = jax.jit(
+    _block_update_at_impl, static_argnames=("width",), donate_argnums=(4,)
+)
+_block_update_plain = jax.jit(
+    _block_update_at_impl, static_argnames=("width",)
+)
 
 
-def _block_update(Aj, mj, Wj_old, pred, y, reg):
-    if jax.default_backend() == "cpu":
-        return _block_update_plain(Aj, mj, Wj_old, pred, y, reg)
-    return _block_update_donating(Aj, mj, Wj_old, pred, y, reg)
+def _block_update(A, means, start, Wj_old, pred, y, reg, width):
+    step = (
+        _block_update_plain if jax.default_backend() == "cpu"
+        else _block_update_donating
+    )
+    return step(A, means, start, Wj_old, pred, y, reg, width=width)
 
 
 @jax.jit
@@ -106,6 +129,43 @@ def cost_signature(
     }
 
 
+#: a block of the descent: (matrix, its column means, first column, width)
+_Block = Tuple[jax.Array, jax.Array, int, int]
+
+
+def _descend(
+    blocks: Sequence[_Block], y: jax.Array, reg, num_iter: int, dtype,
+    init: Optional[Sequence[jax.Array]],
+) -> List[jax.Array]:
+    """The host loop of the per-block-dispatch solvers: ``num_iter`` sweeps
+    over ``blocks``, one :func:`_block_update` program a block."""
+    k = y.shape[1]
+    pred = jnp.zeros_like(y)
+    if init is None:
+        Ws = [jnp.zeros((width, k), dtype=dtype) for *_, width in blocks]
+    else:
+        if len(init) != len(blocks):
+            raise ValueError(
+                f"init has {len(init)} blocks, expected {len(blocks)}"
+            )
+        Ws = [jnp.asarray(w, dtype=dtype) for w in init]
+        for (A, means, start, width), Wj in zip(blocks, Ws):
+            Aj = jax.lax.dynamic_slice_in_dim(A, start, width, axis=1)
+            mj = jax.lax.dynamic_slice_in_dim(means, start, width)
+            pred = pred + _mm(Aj - mj, Wj)
+    # Per-block spans (parity: KernelRidgeRegression.scala:216-224's
+    # per-block phase table). Gram/solve/update run as ONE compiled program
+    # per block shape, so one span covers the device step.
+    for _ in range(num_iter):
+        for j, (A, means, start, width) in enumerate(blocks):
+            with span("bcd.block_update") as sp:
+                Ws[j], pred = _block_update(
+                    A, means, start, Ws[j], pred, y, reg, width
+                )
+                sp.sync_on(pred)
+    return Ws
+
+
 def solve_blockwise_l2(
     blocks: Sequence[jax.Array],
     y: jax.Array,
@@ -128,31 +188,44 @@ def solve_blockwise_l2(
     consistently (pred = Σ Ãⱼ Wⱼ⁰). Returns per-block (b_j, k) weights.
     """
     y = jnp.asarray(y, dtype=dtype)
-    n, k = y.shape
     blocks = [jnp.asarray(b, dtype=dtype) for b in blocks]
     if means is None:
         means = [jnp.zeros((b.shape[1],), dtype=dtype) for b in blocks]
-    if init is None:
-        Ws = [jnp.zeros((b.shape[1], k), dtype=dtype) for b in blocks]
-        pred = jnp.zeros_like(y)
-    else:
-        if len(init) != len(blocks):
-            raise ValueError(
-                f"init has {len(init)} blocks, expected {len(blocks)}"
-            )
-        Ws = [jnp.asarray(w, dtype=dtype) for w in init]
-        pred = jnp.zeros_like(y)
-        for Aj, mj, Wj in zip(blocks, means, Ws):
-            pred = pred + _mm(Aj - mj, Wj)
-    # Per-block spans (parity: KernelRidgeRegression.scala:216-224's
-    # per-block phase table). Gram/solve/update run as ONE compiled program
-    # per block shape, so one span covers the device step.
-    for _ in range(num_iter):
-        for j, Aj in enumerate(blocks):
-            with span("bcd.block_update") as sp:
-                Ws[j], pred = _block_update(Aj, means[j], Ws[j], pred, y, reg)
-                sp.sync_on(pred)
-    return Ws
+    return _descend(
+        [(b, m, 0, int(b.shape[1])) for b, m in zip(blocks, means)],
+        y, reg, num_iter, dtype, init,
+    )
+
+
+def solve_blockwise_l2_columns(
+    A: jax.Array,
+    y: jax.Array,
+    reg: float,
+    block_size: int,
+    num_iter: int = 1,
+    dtype=jnp.float32,
+    means: Optional[jax.Array] = None,
+    init: Optional[Sequence[jax.Array]] = None,
+) -> List[jax.Array]:
+    """:func:`solve_blockwise_l2` over the column blocks of ONE (n, d)
+    matrix whose last block may be narrower (d = 80,000 in blocks of 4,096
+    leaves 2,176): the same host loop and the same block step, one dispatch
+    a block, each block read out of ``A`` by the program that uses it.
+    ``means`` is the (d,) column-mean vector. A d that ``block_size``
+    divides belongs to :func:`solve_blockwise_l2_scan`."""
+    A = jnp.asarray(A, dtype=dtype)
+    d = A.shape[1]
+    means = (
+        jnp.zeros((d,), dtype=dtype) if means is None
+        else jnp.asarray(means, dtype=dtype).reshape(d)
+    )
+    return _descend(
+        [
+            (A, means, start, min(block_size, d - start))
+            for start in range(0, d, block_size)
+        ],
+        jnp.asarray(y, dtype=dtype), reg, num_iter, dtype, init,
+    )
 
 
 def solve_blockwise_l2_scan(

@@ -86,13 +86,14 @@ class SymmetricRectifier(Transformer):
         self.alpha = alpha
 
     def trace_batch(self, X):
-        return jnp.concatenate(
-            [
-                jnp.maximum(self.max_val, X - self.alpha),
-                jnp.maximum(self.max_val, -X - self.alpha),
-            ],
-            axis=-1,
-        )
+        with jax.named_scope("ks.featurize.rectify"):
+            return jnp.concatenate(
+                [
+                    jnp.maximum(self.max_val, X - self.alpha),
+                    jnp.maximum(self.max_val, -X - self.alpha),
+                ],
+                axis=-1,
+            )
 
 
 def pack_filter_images(filters):
@@ -112,9 +113,10 @@ class Convolver(Transformer):
     same: Convolver.scala:75-81 folds W·Wᵀ into the filters).
 
     out(x,y,k) = p̂(x,y)·f_k − means·f_k where p̂ is the
-    mean/variance-normalized patch; computed as conv + window moments:
+    mean/variance-normalized patch; computed as conv + window moments, with
+    the patch mean taken out of the FILTER (f̃ = f − f̄, so p·f̃ = (p − μ)·f):
 
-        p̂·f = (conv(img, f) − μ_patch · Σf) / sd_patch
+        p̂·f = conv(img − c, f̃) / sd_patch      (any constant c an image)
     """
 
     def __init__(
@@ -141,15 +143,27 @@ class Convolver(Transformer):
             raise ValueError("filters must be square patches")
 
     def trace_batch(self, X):
+        with jax.named_scope("ks.featurize.conv"):
+            return self._convolve(X)
+
+    def _convolve(self, X):
         S, C = self.conv_size, self.img_channels
         K = self.filters.shape[0]
         m = S * S * C
         X = X.astype(jnp.float32)
+        filters = self.filters
+        if self.normalize_patches:
+            # A normalized patch has no mean, so only the mean-free part of
+            # a filter meets it: (p − μ)·f = p·(f − f̄), and f − f̄ sees no
+            # constant added to the image. A whitened filter's mean is
+            # rounding scaled by ε^−½ (16 times the rest of the bank at
+            # ε = 1e-5) and pixels are 128 ± 40: contracted as they come in
+            # one bf16 pass, conv − μ·Σf cancels most of its digits.
+            filters = filters - filters.mean(axis=1, keepdims=True)
+            X = X - jnp.mean(X, axis=(1, 2, 3), keepdims=True)
 
         # kernel[pox, poy, c, k] from row layout c + pox*C + poy*C*S
-        kernel = jnp.transpose(
-            self.filters.reshape(K, S, S, C), (2, 1, 3, 0)
-        )
+        kernel = jnp.transpose(filters.reshape(K, S, S, C), (2, 1, 3, 0))
         conv = jax.lax.conv_general_dilated(
             X, kernel, window_strides=(1, 1), padding="VALID",
             dimension_numbers=_DIMNUMS,
@@ -163,11 +177,8 @@ class Convolver(Transformer):
             p_sumsq = jax.lax.reduce_window(
                 X * X, 0.0, jax.lax.add, ones_spec, (1, 1, 1, 1), "valid"
             ).sum(axis=-1, keepdims=True)
-            mu = p_sum / m
-            var = (p_sumsq - p_sum * mu) / (m - 1)
-            sd = jnp.sqrt(var + self.var_constant)
-            f_sum = self.filters.sum(axis=1)  # (K,)
-            conv = (conv - mu * f_sum) / sd
+            var = (p_sumsq - p_sum * (p_sum / m)) / (m - 1)
+            conv = conv / jnp.sqrt(var + self.var_constant)
 
         if self.whitener is not None:
             bias = self.whitener.means @ self.filters.T  # (K,)
@@ -192,7 +203,10 @@ class Convolver(Transformer):
             f = f[:, ::-1, ::-1, :]
         packed = pack_filter_images(f)
         if whitener is not None:
-            packed = whitener.transform(packed) @ whitener.whitener.T
+            packed = jnp.matmul(
+                whitener.transform(packed), whitener.whitener.T,
+                precision=jax.lax.Precision.HIGHEST,
+            )
         return Convolver(
             packed, img_x, img_y, img_channels, whitener,
             normalize_patches, var_constant,
@@ -220,6 +234,10 @@ class Pooler(Transformer):
         self.pool_fn = pool_fn
 
     def trace_batch(self, X):
+        with jax.named_scope("ks.featurize.pool"):
+            return self._pool(X)
+
+    def _pool(self, X):
         ps, st = self.pool_size, self.stride
         start = ps // 2
         # The reference window is [x−ps/2, x+ps/2) with integer division —

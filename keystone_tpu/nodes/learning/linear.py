@@ -251,6 +251,15 @@ class LinearMapEstimator(LabelEstimator, CostModel):
         )
 
 
+def _per_block_work(widths: Sequence[int], block_size: int) -> dict:
+    """The ``block_ls.solve`` span's attrs on the per-block-dispatch path
+    (``scan_solver_work`` gives the scan path's): how many block programs an
+    epoch dispatches, and the columns of a last block narrower than
+    ``block_size`` (0: none)."""
+    ragged = widths[-1] if widths and widths[-1] < block_size else 0
+    return {"blocks": len(widths), "ragged_cols": int(ragged)}
+
+
 class BlockLinearMapper(Transformer):
     """Fused apply of a block-solved model: block weights are vertically
     concatenated and per-block means concatenated, so application is one
@@ -395,15 +404,14 @@ class BlockLeastSquaresEstimator(LabelEstimator, CostModel):
 
         A contiguous (n, d) matrix with d divisible by ``block_size`` solves
         through :func:`solve_blockwise_l2_scan` — the whole BCD pass is ONE
-        compiled program (zero host round trips per block). Pre-split or
-        ragged blocks take the per-block-dispatch path.
+        compiled program (zero host round trips per block). A contiguous
+        matrix with a ragged last block, and pre-split blocks, take the
+        per-block-dispatch path; the contiguous one is never cut into
+        column slices (a second copy of the features in HBM).
         """
         from ...data.chunked import ChunkedDataset
-        from ...linalg.bcd import (
-            _block_means,
-            scan_solver_work,
-            solve_blockwise_l2_scan,
-        )
+        from ...linalg.bcd import _block_means
+
         warm = getattr(self, "warm_start_ws", None)  # pre-sweep pickles
         self.warm_start_ws = None
         if isinstance(data, ChunkedDataset):
@@ -432,50 +440,9 @@ class BlockLeastSquaresEstimator(LabelEstimator, CostModel):
 
         y = Dataset.of(labels).to_array().astype(jnp.float32)
 
-        if X is not None and X.shape[-1] % self.block_size == 0:
-            d = X.shape[-1]
-            with span("block_ls.center") as sp:
-                X = shard_batch(
-                    X if X.dtype == jnp.float32 else X.astype(jnp.float32)
-                )
-                mean_vec = jnp.mean(X, axis=0)
-                y_mean = jnp.mean(y, axis=0)
-                sp.sync_on((mean_vec, y_mean))
-            with span("block_ls.solve") as sp:
-                sp.attrs.update(
-                    scan_solver_work(d, self.block_size, self.num_iter)
-                )
-                init = None
-                if warm is not None:
-                    cat = jnp.concatenate(
-                        [jnp.asarray(w) for w in warm], axis=0
-                    )
-                    if cat.shape == (d, y.shape[1]):
-                        init = cat
-                W = solve_blockwise_l2_scan(
-                    X, shard_batch(y - y_mean), reg=self.lam,
-                    block_size=self.block_size, num_iter=self.num_iter,
-                    means=mean_vec, init=init,
-                )
-                sp.sync_on(W)
-            ws = [
-                W[i : i + self.block_size]
-                for i in range(0, d, self.block_size)
-            ]
-            means = [
-                mean_vec[i : i + self.block_size]
-                for i in range(0, d, self.block_size)
-            ]
-            return BlockLinearMapper(
-                ws, self.block_size, b=y_mean, feature_means=means
-            )
+        if X is not None:
+            return self._fit_contiguous(X, y, warm)
 
-        if blocks is None:
-            d = X.shape[-1]
-            blocks = [
-                X[..., i : min(i + self.block_size, d)]
-                for i in range(0, d, self.block_size)
-            ]
         with span("block_ls.center") as sp:
             blocks = [
                 shard_batch(b if b.dtype == jnp.float32 else b.astype(jnp.float32))
@@ -485,7 +452,11 @@ class BlockLeastSquaresEstimator(LabelEstimator, CostModel):
             # per-block solve so centered copies never hit HBM
             means, y_mean = _block_means(blocks, y)
             sp.sync_on(y_mean)
-        with span("block_ls.solve"):
+        with span(
+            "block_ls.solve", **_per_block_work(
+                [int(b.shape[1]) for b in blocks], self.block_size
+            )
+        ):
             init = None
             if warm is not None and len(warm) == len(blocks) and all(
                 tuple(w.shape) == (int(b.shape[1]), int(y.shape[1]))
@@ -499,6 +470,58 @@ class BlockLeastSquaresEstimator(LabelEstimator, CostModel):
         return BlockLinearMapper(
             ws, self.block_size, b=y_mean, feature_means=means
         )
+
+    def _fit_contiguous(self, X, y, warm) -> BlockLinearMapper:
+        """The fit on one (n, d) matrix in HBM: the scan solver where
+        ``block_size`` divides d, else one dispatch a block with the last
+        block narrower."""
+        from ...linalg.bcd import (
+            scan_solver_work,
+            solve_blockwise_l2_columns,
+            solve_blockwise_l2_scan,
+        )
+
+        d, bs = X.shape[-1], self.block_size
+        starts = range(0, d, bs)
+        widths = [min(bs, d - i) for i in starts]
+        with span("block_ls.center") as sp:
+            X = shard_batch(
+                X if X.dtype == jnp.float32 else X.astype(jnp.float32)
+            )
+            mean_vec = jnp.mean(X, axis=0)
+            y_mean = jnp.mean(y, axis=0)
+            sp.sync_on((mean_vec, y_mean))
+        y_zm = shard_batch(y - y_mean)
+        if d % bs == 0:
+            with span("block_ls.solve") as sp:
+                sp.attrs.update(scan_solver_work(d, bs, self.num_iter))
+                init = None
+                if warm is not None:
+                    cat = jnp.concatenate(
+                        [jnp.asarray(w) for w in warm], axis=0
+                    )
+                    if cat.shape == (d, y.shape[1]):
+                        init = cat
+                W = solve_blockwise_l2_scan(
+                    X, y_zm, reg=self.lam, block_size=bs,
+                    num_iter=self.num_iter, means=mean_vec, init=init,
+                )
+                sp.sync_on(W)
+            ws = [W[i : i + bs] for i in starts]
+        else:
+            with span("block_ls.solve", **_per_block_work(widths, bs)) as sp:
+                init = None
+                if warm is not None and [
+                    tuple(w.shape) for w in warm
+                ] == [(w, int(y.shape[1])) for w in widths]:
+                    init = [jnp.asarray(w) for w in warm]
+                ws = solve_blockwise_l2_columns(
+                    X, y_zm, reg=self.lam, block_size=bs,
+                    num_iter=self.num_iter, means=mean_vec, init=init,
+                )
+                sp.sync_on(ws[-1])
+        means = [mean_vec[i : i + bs] for i in starts]
+        return BlockLinearMapper(ws, bs, b=y_mean, feature_means=means)
 
     def _fit_streaming(self, data, labels: Dataset) -> BlockLinearMapper:
         """Fit from a :class:`~keystone_tpu.data.chunked.ChunkedDataset`

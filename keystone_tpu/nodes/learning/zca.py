@@ -24,11 +24,17 @@ class ZCAWhitener(Transformer):
         self.means = as_param(means)
 
     def trace_batch(self, X):
-        return (X - self.means) @ self.whitener
+        # float32 all the way (six bf16 passes on a TPU): the whitener
+        # scales its smallest directions by ε^−½ — 316 at the published
+        # 1e-5 — and one bf16 pass is wrong in the third digit before that
+        return jnp.matmul(X - self.means, self.whitener, precision=_EXACT)
 
     # alias used by Convolver.build and host-side callers
     def transform(self, X):
-        return (jnp.asarray(X) - self.means) @ self.whitener
+        return self.trace_batch(jnp.asarray(X))
+
+
+_EXACT = jax.lax.Precision.HIGHEST
 
 
 @jax.jit
@@ -36,9 +42,13 @@ def _fit_zca(X, eps):
     means = jnp.mean(X, axis=0)
     Xc = (X - means).astype(jnp.float32)
     n = X.shape[0]
-    _, s, vt = jnp.linalg.svd(Xc, full_matrices=False)
-    scale = (s * s / (n - 1.0) + eps) ** -0.5
-    W = vt.T @ (scale[:, None] * vt)
+    # the reference's "deliberate float path" is float32, not a TPU's
+    # default of one bf16 pass a product: on the chip the filter bank this
+    # whitener makes was 10% off the float64 one (PERF.md §6, PR 29)
+    with jax.default_matmul_precision("highest"):
+        _, s, vt = jnp.linalg.svd(Xc, full_matrices=False)
+        scale = (s * s / (n - 1.0) + eps) ** -0.5
+        W = vt.T @ (scale[:, None] * vt)
     return W, means
 
 

@@ -164,6 +164,16 @@ def _chan_merge(a, b):
     return tot, mean, m2
 
 
+@jax.jit
+def _standardize(X, mean, std):
+    """``(X − mean) / std`` as ONE program where a node runs eagerly: op by
+    op the centred copy would sit in HBM beside its input and the result
+    (three times 5.2 GB at 16,384 × 80,000). Inside a traced segment the
+    call is inlined."""
+    out = X - mean
+    return out if std is None else out / std
+
+
 class StandardScalerModel(Transformer):
     """(x − mean) / std; std of None means center-only
     (parity: StandardScaler.scala:16-32)."""
@@ -173,10 +183,7 @@ class StandardScalerModel(Transformer):
         self.std = as_param(std)
 
     def trace_batch(self, X):
-        out = X - self.mean
-        if self.std is not None:
-            out = out / self.std
-        return out
+        return _standardize(X, self.mean, self.std)
 
 
 class StandardScaler(Estimator):

@@ -12,8 +12,10 @@ from __future__ import annotations
 import argparse
 import time
 from dataclasses import dataclass
+from functools import partial
 from typing import Optional
 
+import jax
 import jax.numpy as jnp
 import numpy as np
 
@@ -26,12 +28,13 @@ from ..nodes.images.core import (
     ImageVectorizer,
     Pooler,
     SymmetricRectifier,
-    Windower,
+    vectorize_images,
 )
 from ..nodes.learning.linear import BlockLeastSquaresEstimator
 from ..nodes.learning.zca import ZCAWhitenerEstimator
-from ..nodes.stats import Sampler, StandardScaler
+from ..nodes.stats import StandardScaler
 from ..nodes.util import ClassLabelIndicators, MaxClassifier
+from ..obs.tracer import span
 from ..utils.stats import normalize_rows
 
 NUM_CLASSES = 10
@@ -56,28 +59,72 @@ class RandomCifarConfig:
     seed: int = 0
 
 
+@partial(jax.jit, static_argnums=(4,))
+def _patches_at(X, img, x0, y0, size: int):
+    """The ``size``×``size`` windows of ``X`` (n, X, Y, C) whose corners are
+    ``(img[i], x0[i], y0[i])``, as (len(img), size, size, C)."""
+    def one(i, x, y):
+        return jax.lax.dynamic_slice(
+            X, (i, x, y, 0), (1, size, size, X.shape[-1])
+        )[0]
+
+    return jax.vmap(one)(img, x0, y0)
+
+
+def sample_patches(images, conf: "RandomCifarConfig"):
+    """``whitener_size`` vectorized patches of ``images``: what
+    ``Windower → ImageVectorizer → Sampler`` gives (every window of every
+    image in the reference's emission order — per image, for x, for y —
+    then a seeded draw without replacement, sorted), with the draw made
+    FIRST and only the drawn windows cut. All windows of 16,384 images are
+    5.2 GB twice over; the sample is 43 MB."""
+    X = jnp.asarray(images)
+    n, xd, yd, _ = X.shape
+    w, st = conf.patch_size, conf.patch_steps
+    nx = len(range(0, xd - w + 1, st))
+    ny = len(range(0, yd - w + 1, st))
+    total = n * nx * ny
+    idx = np.sort(np.random.default_rng(conf.seed).choice(
+        total, size=min(conf.whitener_size, total), replace=False
+    ))
+    img, window = np.divmod(idx, nx * ny)
+    xi, yi = np.divmod(window, ny)
+    as_index = lambda a: jnp.asarray(a, jnp.int32)  # noqa: E731
+    patches = _patches_at(
+        X, as_index(img), as_index(xi * st), as_index(yi * st), w
+    )
+    return vectorize_images(patches)
+
+
 def learn_filters(train_images: Dataset, conf: RandomCifarConfig):
     """Sample patches, whiten, pick + scale random filters
     (parity: RandomPatchCifar.scala:41-58). Returns (filters, whitener)."""
-    patch_extractor = (
-        Windower(conf.patch_steps, conf.patch_size)
-        .and_then(ImageVectorizer())
-        .and_then(Sampler(conf.whitener_size, seed=conf.seed))
-    )
-    base = patch_extractor(train_images).get().to_array()
-    base_mat = normalize_rows(jnp.asarray(base), 10.0)
-    whitener = ZCAWhitenerEstimator(conf.whitening_epsilon).fit_single(base_mat)
+    with span("cifar.sample_patches") as sp:
+        base = sample_patches(Dataset.of(train_images).to_array(), conf)
+        base_mat = normalize_rows(base, 10.0)
+        sp.sync_on(base_mat)
+    with span("zca.fit") as sp:
+        whitener = ZCAWhitenerEstimator(
+            conf.whitening_epsilon
+        ).fit_single(base_mat)
+        sp.sync_on(whitener.whitener)
 
-    rng = np.random.default_rng(conf.seed)
-    idx = rng.choice(
-        base_mat.shape[0],
-        size=min(conf.num_filters, base_mat.shape[0]),
-        replace=False,
-    )
-    sample = base_mat[jnp.asarray(np.sort(idx))]
-    unnorm = whitener.transform(sample)
-    norms = jnp.sqrt(jnp.sum(unnorm * unnorm, axis=1))
-    filters = (unnorm / (norms + 1e-10)[:, None]) @ whitener.whitener.T
+    with span("cifar.choose_filters") as sp:
+        rng = np.random.default_rng(conf.seed)
+        idx = rng.choice(
+            base_mat.shape[0],
+            size=min(conf.num_filters, base_mat.shape[0]),
+            replace=False,
+        )
+        sample = base_mat[jnp.asarray(np.sort(idx))]
+        unnorm = whitener.transform(sample)
+        norms = jnp.sqrt(jnp.sum(unnorm * unnorm, axis=1))
+        # float32 like the whitener's own products (nodes/learning/zca.py)
+        filters = jnp.matmul(
+            unnorm / (norms + 1e-10)[:, None], whitener.whitener.T,
+            precision=jax.lax.Precision.HIGHEST,
+        )
+        sp.sync_on(filters)
     return filters, whitener
 
 
@@ -104,26 +151,32 @@ def build_pipeline(train: LabeledData, conf: RandomCifarConfig):
 
 def run(train: LabeledData, test: LabeledData, conf: RandomCifarConfig):
     start = time.perf_counter()
-    if conf.sample_frac is not None:
-        # parity: RandomPatchCifar.scala:29-32 (sample training data)
-        rng = np.random.default_rng(conf.seed)
-        n = len(train)
-        keep = np.sort(
-            rng.choice(n, size=max(1, int(n * conf.sample_frac)), replace=False)
+    with span("job", pipeline="RandomPatchCifar"):
+        if conf.sample_frac is not None:
+            # parity: RandomPatchCifar.scala:29-32 (sample training data)
+            rng = np.random.default_rng(conf.seed)
+            n = len(train)
+            keep = np.sort(rng.choice(
+                n, size=max(1, int(n * conf.sample_frac)), replace=False
+            ))
+            train = LabeledData(
+                np.asarray(train.labels.to_array())[keep],
+                np.asarray(train.data.to_array())[keep],
+            )
+        with span("plan.build"):
+            pipeline = build_pipeline(train, conf)
+        fitted = pipeline.fit()
+        # through the executor, as the fit's own featurization went: a
+        # segment whose intermediates outgrow the device is dispatched in
+        # row slices (compile/segment.py); one whole-batch program of the
+        # chain is 29 MB of convolution output an image at 10,000 filters
+        ev = MulticlassClassifierEvaluator(NUM_CLASSES)
+        train_eval = ev.evaluate(
+            fitted.apply(train.data).to_array(), train.labels
         )
-        train = LabeledData(
-            np.asarray(train.labels.to_array())[keep],
-            np.asarray(train.data.to_array())[keep],
+        test_eval = ev.evaluate(
+            fitted.apply(test.data).to_array(), test.labels
         )
-    pipeline = build_pipeline(train, conf)
-    fitted = pipeline.fit()
-    ev = MulticlassClassifierEvaluator(NUM_CLASSES)
-    train_eval = ev.evaluate(
-        fitted.apply_compiled(train.data.to_array()), train.labels
-    )
-    test_eval = ev.evaluate(
-        fitted.apply_compiled(test.data.to_array()), test.labels
-    )
     return pipeline, train_eval.total_error, test_eval.total_error, \
         time.perf_counter() - start
 

@@ -286,7 +286,13 @@ class GraphExecutor:
                     n: lattice.classify(graph.get_operator(n))
                     for n in graph.nodes
                 }
-                planned, _barriers = plan_segments(graph, verdicts, {})
+                # what this executor already holds (a fit hands each
+                # estimator's executor the upstream results of the last)
+                # is data: a segment through it would featurize again
+                held = {n for n, e in self._state.items() if e.computed}
+                planned, _barriers = plan_segments(
+                    graph, verdicts, {}, materialized=held
+                )
                 table: Dict[NodeId, Any] = {}
                 for seg in planned:
                     binding = bind_segment(
@@ -363,7 +369,7 @@ class GraphExecutor:
                 digest=(binding.digest or "")[:16],
                 label=binding.label,
             ) as sp:
-                outs, path = binding.run(xs)
+                outs, path = binding.run(xs, facts=sp.attrs)
                 sp.attrs["path"] = path
                 if path == "compiled":
                     # chunked outputs are lazy scans — syncing them here

@@ -229,3 +229,29 @@ def test_cropper_and_patcher_and_grayscale():
     assert gray.shape == (2, 8, 8, 1)
     scaled = np.asarray(PixelScaler().apply_batch(Dataset.of(imgs)).to_array())
     assert scaled.max() <= 1.0
+
+
+def test_convolver_sees_no_constant_in_filter_or_image():
+    """A normalized patch has no mean: a constant added to a filter (what
+    whitening at a small epsilon leaves in the bank, scaled by eps^-1/2) or
+    to an image changes nothing — exactly, not up to a cancellation."""
+    rng = np.random.default_rng(5)
+    n, X, Y, C, S, K = 2, 8, 8, 3, 3, 6
+    imgs = (128 + 40 * rng.standard_normal((n, X, Y, C))).astype(np.float32)
+    filters = rng.standard_normal((K, S * S * C)).astype(np.float32)
+
+    def out(f, x):
+        conv = Convolver(f, X, Y, C, normalize_patches=True)
+        return np.asarray(conv.apply_batch(Dataset.of(x)).to_array())
+
+    base = out(filters, imgs)
+    # 100 a filter entry against pixels of 128: conv − μ·Σf would subtract
+    # two numbers of 3e5 to get one of 1e1, and keep two digits in float32
+    np.testing.assert_allclose(out(filters + 100.0, imgs), base, atol=1e-3)
+    np.testing.assert_allclose(out(filters, imgs + 1000.0), base, atol=1e-3)
+    for i in range(n):
+        pm = _norm_rows_np(_patches_naive(imgs[i], S), 10.0)
+        got = np.stack(
+            [base[i, x, y] for y in range(Y - S + 1) for x in range(X - S + 1)]
+        )
+        np.testing.assert_allclose(got, pm @ filters.T, rtol=1e-3, atol=1e-3)
