@@ -3,7 +3,7 @@
 One process drives the main path — fit a pipeline, then serve it — once,
 through the entry points a user would call, at the full width of
 MnistRandomFFT (numFFTs=4, blockSize=2048, λ=1000; 60,000 train / 10,000
-test rows of the seeded synthetic task, generated in HBM). Three legs:
+test rows of the seeded synthetic task, generated in HBM). Four legs:
 
 * ``fit``    — CLI dispatch and backend selection through
   ``python -m keystone_tpu MnistRandomFFT --backend tpu``'s ``main``, then
@@ -12,8 +12,11 @@ test rows of the seeded synthetic task, generated in HBM). Three legs:
 * ``serve``  — the fitted pipeline behind ``ServingFleet`` at its default
   of one replica per device; every reply must equal ``fitted.apply`` on
   the same row.
-* ``kernel`` — the one Pallas kernel, compiled, against the XLA lowering
-  of the same algebra.
+* ``kernel`` — the Gaussian Pallas kernel, compiled, against the XLA
+  lowering of the same algebra.
+* ``conv_chain`` — the fused conv → rectify → pool Pallas kernel at
+  ``cifar_patch10k``'s 10,000 filters, compiled, against the XLA lowering
+  of the three bodies it replaces, and that the front door picked it.
 
 It refuses anything but a TPU, fails if any catch-and-degrade site fired
 on its path, and exits 0 only if every leg passed. Stdout is two lines of
@@ -53,6 +56,10 @@ TEST_ERROR_BAND = (0.040, 0.055)
 #: docstring shape of ops/gaussian_kernel.py (its measured n is 131072;
 #: the grid only repeats over n, so a shorter n compiles the same tile)
 KERNEL_SHAPE = dict(n=8192, d=512, b=2048)
+
+#: cifar_patch10k's filter bank (benchmark/configs/cifar_patch10k.json) over
+#: as many images as the three XLA bodies hold at once (29 MB an image)
+CONV_CHAIN_SHAPE = dict(filters=10000, images=64)
 
 
 def require_tpu() -> dict:
@@ -369,6 +376,86 @@ def kernel_leg(*, n, d, b, interpret=False):
     return report
 
 
+def conv_chain_leg(*, filters, images, side=32, interpret=False):
+    """The fused conv → rectify → pool kernel (``ops/conv_rectify_pool.py``)
+    against the XLA lowering of the three bodies it replaces
+    (``Convolver`` → ``SymmetricRectifier`` → ``Pooler``), both at the
+    backend's default precision: one bf16 pass on the chip. Compiled, the
+    front door (``ConvRectifyPool.kernel_mode``) must pick the kernel for
+    this shape; interpreted (the CPU rehearsal) the bodies' product is
+    float32, so only the shape and finiteness are held."""
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    from keystone_tpu.nodes.images.chain import ConvRectifyPool
+    from keystone_tpu.nodes.images.core import (
+        Convolver,
+        Pooler,
+        SymmetricRectifier,
+    )
+    from keystone_tpu.nodes.learning.zca import ZCAWhitener
+    patch, channels = 6, 3
+    m = patch * patch * channels
+    kf, km, kx = jax.random.split(jax.random.PRNGKey(0), 3)
+    conv = Convolver(
+        0.1 * jax.random.normal(kf, (filters, m), jnp.float32),
+        side, side, channels,
+        whitener=ZCAWhitener(
+            np.eye(m, dtype=np.float32),
+            np.asarray(jax.random.normal(km, (m,), jnp.float32)),
+        ),
+        normalize_patches=True,
+    )
+    rect, pool = SymmetricRectifier(alpha=0.25), Pooler(13, 14, None, "sum")
+    node = ConvRectifyPool(conv, rect, pool)
+    X = jax.random.uniform(
+        kx, (images, side, side, channels), jnp.float32, 0.0, 255.0
+    )
+    fused = jax.jit(lambda X: node.fused(X, interpret=interpret))
+    t0 = time.perf_counter()
+    got = jax.block_until_ready(fused(X))
+    first_seconds = time.perf_counter() - t0
+    t0 = time.perf_counter()
+    jax.block_until_ready(fused(X))
+    repeat_seconds = time.perf_counter() - t0
+    want = jax.jit(lambda X: pool.trace_batch(
+        rect.trace_batch(conv.trace_batch(X))
+    ))(X)
+    got, want = np.asarray(got), np.asarray(want)
+    gap = float(np.max(np.abs(got - want)) / np.max(np.abs(want)))
+    report = {
+        "shape": {"filters": filters, "images": images, "side": side},
+        "interpret": interpret,
+        "finite": bool(np.isfinite(got).all()),
+        "max_rel_gap_xla": gap,
+        "first_seconds": round(first_seconds, 3),
+        "repeat_seconds": round(repeat_seconds, 4),
+    }
+    ok = report["finite"] and got.shape == want.shape
+    if not interpret:
+        chosen = node.kernel_mode(X.shape) == "compiled" and bool(
+            np.array_equal(np.asarray(jax.jit(node.trace_batch)(X)), got)
+        )
+        # as segment dispatch ships a program to another process
+        # (compile/segment.py:_trace_and_export): exported, serialized,
+        # read back, the same numbers
+        from jax import export as jax_export
+
+        shipped = jax_export.deserialize(bytearray(jax_export.export(
+            jax.jit(node.trace_batch)
+        )(jax.ShapeDtypeStruct(X.shape, X.dtype)).serialize()))
+        report["matches_xla"] = gap <= 1e-5
+        report["front_door_chose_kernel"] = chosen
+        report["survives_export"] = bool(
+            np.array_equal(np.asarray(jax.jit(shipped.call)(X)), got)
+        )
+        ok = ok and chosen and report["matches_xla"]
+        ok = ok and report["survives_export"]
+    report["ok"] = bool(ok)
+    return report
+
+
 def sync_check(*, size, steps):
     """Does a timing that ends in ``block_until_ready`` agree with one
     that ends in bench.py's scalar read-back, on the same chain of
@@ -462,6 +549,7 @@ def main() -> int:
         fitted, rows, buckets=(8, 32, 128), n_requests=400
     ))
     leg("kernel", lambda: kernel_leg(**KERNEL_SHAPE))
+    leg("conv_chain", lambda: conv_chain_leg(**CONV_CHAIN_SHAPE))
     leg("sync", lambda: {"ok": True, **sync_check(size=8192, steps=24)})
 
     fallbacks = fallbacks_fired()

@@ -391,7 +391,8 @@ def _item_bytes(
     sigs: Tuple[Signature, ...], steps: List[Step],
     out_slots: Tuple[int, ...],
 ) -> int:
-    """Bytes ONE row generates across the segment's member outputs at
+    """Bytes ONE row generates across the segment's member outputs (and
+    what a member declares it holds a row besides, ``row_scratch_bytes``) at
     these input shapes — what ``check/segments.py`` prices from specs,
     taken here from the shapes the dispatch really has (``jax.eval_shape``:
     no operation runs). 0 where an output does not keep the row axis (the
@@ -403,6 +404,7 @@ def _item_bytes(
     from ..workflow.operators import GatherTransformerOperator
 
     produced: List[Any] = []
+    scratch: List[int] = []
 
     def trace(op, args):
         # a fused chain is priced by ITS members: the optimizer has folded
@@ -416,6 +418,11 @@ def _item_bytes(
             return tuple(args)
         out = op.trace_batch(*args)
         produced.append(out)
+        # what a member holds a row besides its output, where it says so
+        # (a kernel's operands laid out in HBM ahead of it)
+        row_scratch = getattr(op, "row_scratch_bytes", None)
+        if row_scratch is not None and args:
+            scratch.append(int(row_scratch(args[0].shape)))
         return out
 
     def members(*xs):
@@ -436,10 +443,29 @@ def _item_bytes(
         total = sum(
             int(np.prod(v.shape)) * np.dtype(v.dtype).itemsize for v in leaves
         )
-        return -(-total // rows) if row_wise else 0
+        return -(-total // rows) + sum(scratch) if row_wise else 0
     except Exception:
         logger.debug("segment: no per-row size estimate", exc_info=True)
         return 0
+
+
+def _runs_conv_kernel(steps: List[Step], shapes: List[Any]) -> bool:
+    """Whether a member of ``steps`` (a fused chain's included) sends its
+    rows through the fused convolution kernel
+    (``nodes/images/chain.py:ConvRectifyPool.kernel_mode``), judged where it
+    reads one of the inputs, whose ``shapes`` are known without a trace."""
+    from ..workflow.fusion import FusedTransformerOperator
+
+    for op, slots in steps:
+        args = [shapes[s] if s < len(shapes) else None for s in slots]
+        if isinstance(op, FusedTransformerOperator):
+            if _runs_conv_kernel(op.steps, args):
+                return True
+        elif args and args[0] is not None:
+            mode = getattr(op, "kernel_mode", None)
+            if mode is not None and mode(args[0]) is not None:
+                return True
+    return False
 
 
 def _device_memory() -> Optional[Tuple[int, int]]:
@@ -599,6 +625,9 @@ class SegmentBinding:
                     rows=rows, row_slices=-(-rows // slice_rows),
                     slice_rows=slice_rows,
                 )
+                shapes = [(slice_rows,) + a.shape[1:] for a in arrays]
+                if _runs_conv_kernel(self.steps, shapes):
+                    facts["conv_fused_rows"] = rows
             seg_cost.record_run(
                 self.digest, time.perf_counter() - t0,
                 n_nodes=len(self.steps),

@@ -146,10 +146,8 @@ class Convolver(Transformer):
         with jax.named_scope("ks.featurize.conv"):
             return self._convolve(X)
 
-    def _convolve(self, X):
-        S, C = self.conv_size, self.img_channels
-        K = self.filters.shape[0]
-        m = S * S * C
+    def _operands(self, X):
+        """``(X, filters)`` as the patch product takes them, float32."""
         X = X.astype(jnp.float32)
         filters = self.filters
         if self.normalize_patches:
@@ -161,6 +159,33 @@ class Convolver(Transformer):
             # one bf16 pass, conv − μ·Σf cancels most of its digits.
             filters = filters - filters.mean(axis=1, keepdims=True)
             X = X - jnp.mean(X, axis=(1, 2, 3), keepdims=True)
+        return X, filters
+
+    def _patch_sd(self, X):
+        """sqrt(var + var_constant) of every window of ``X`` (the product's
+        operand), (n, resX, resY, 1): two window sums, no patch matrix."""
+        S, C = self.conv_size, self.img_channels
+        m = S * S * C
+        ones_spec = (1, S, S, C)  # window over the whole patch
+        p_sum = jax.lax.reduce_window(
+            X, 0.0, jax.lax.add, ones_spec, (1, 1, 1, 1), "valid"
+        ).sum(axis=-1, keepdims=True)
+        p_sumsq = jax.lax.reduce_window(
+            X * X, 0.0, jax.lax.add, ones_spec, (1, 1, 1, 1), "valid"
+        ).sum(axis=-1, keepdims=True)
+        var = (p_sumsq - p_sum * (p_sum / m)) / (m - 1)
+        return jnp.sqrt(var + self.var_constant)
+
+    def _bias(self):
+        """What the whitener's mean leaves in every output, (K,) or None."""
+        if self.whitener is None:
+            return None
+        return self.whitener.means @ self.filters.T
+
+    def _convolve(self, X):
+        S, C = self.conv_size, self.img_channels
+        K = self.filters.shape[0]
+        X, filters = self._operands(X)
 
         # kernel[pox, poy, c, k] from row layout c + pox*C + poy*C*S
         kernel = jnp.transpose(filters.reshape(K, S, S, C), (2, 1, 3, 0))
@@ -170,18 +195,9 @@ class Convolver(Transformer):
         )  # (n, resX, resY, K)
 
         if self.normalize_patches:
-            ones_spec = (1, S, S, C)  # window over the whole patch
-            p_sum = jax.lax.reduce_window(
-                X, 0.0, jax.lax.add, ones_spec, (1, 1, 1, 1), "valid"
-            ).sum(axis=-1, keepdims=True)
-            p_sumsq = jax.lax.reduce_window(
-                X * X, 0.0, jax.lax.add, ones_spec, (1, 1, 1, 1), "valid"
-            ).sum(axis=-1, keepdims=True)
-            var = (p_sumsq - p_sum * (p_sum / m)) / (m - 1)
-            conv = conv / jnp.sqrt(var + self.var_constant)
-
-        if self.whitener is not None:
-            bias = self.whitener.means @ self.filters.T  # (K,)
+            conv = conv / self._patch_sd(X)
+        bias = self._bias()
+        if bias is not None:
             conv = conv - bias
         return conv
 

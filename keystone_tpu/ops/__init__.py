@@ -3,6 +3,11 @@ the XLA lowering. Current kernels:
 
 * :mod:`.gaussian_kernel` — fused Gaussian kernel block (GEMM + norms +
   exp in one VMEM-resident tile), the KRR hot loop's block generator.
+* :mod:`.conv_rectify_pool` — filter-bank convolution, symmetric rectifier
+  and sum-pool as one kernel (patch rows × filter tile on the MXU; the
+  normalisation, bias, both rectified halves and the pool's sums on the
+  tile in VMEM), the RandomPatchCifar featurizer: the convolution's
+  (n, 27, 27, K) output never reaches HBM. Cell: ``cifar_patch10k.fit``.
 """
 
 from .gaussian_kernel import (

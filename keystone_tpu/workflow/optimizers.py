@@ -185,10 +185,16 @@ class DefaultOptimizer(Optimizer):
     def _fusion_batch(self) -> Batch:
         """Last batch always: collapse traceable chains into single jitted
         operators (one XLA program instead of N eager dispatches). Runs after
-        every structural rule so Cachers/estimators bound the fusion groups."""
+        every structural rule so Cachers/estimators bound the fusion groups.
+        Ahead of it a convolution chain becomes the one node that keeps the
+        convolution's output on the chip (``nodes/images/chain.py``)."""
+        from ..nodes.images.chain import ConvChainRule
         from .fusion import TraceFusionRule
 
-        return Batch("Trace Fusion", Strategy.ONCE, [TraceFusionRule()])
+        return Batch(
+            "Trace Fusion", Strategy.ONCE,
+            [ConvChainRule(), TraceFusionRule()],
+        )
 
 
 class AutoCachingOptimizer(DefaultOptimizer):

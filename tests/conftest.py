@@ -46,3 +46,29 @@ def pipeline_env():
     cost.reset()
     faults.clear()
     flight.reset()
+
+
+@pytest.fixture
+def bf16_products(monkeypatch):
+    """``lax.conv_general_dilated`` as ONE bf16 pass — operands rounded to
+    bf16, float32 accumulation — which is how the TPU's default precision
+    runs it and how ``ops/conv_rectify_pool.py`` rounds on any backend: a
+    test that holds the fused kernel to the XLA bodies rounds both alike."""
+    import jax.numpy as jnp
+
+    exact = jax.lax.conv_general_dilated
+
+    def bf16(a):
+        return jnp.asarray(a).astype(jnp.bfloat16).astype(jnp.float32)
+
+    def rounded(lhs, rhs, *args, **kw):
+        return exact(bf16(lhs), bf16(rhs), *args, **kw)
+
+    monkeypatch.setattr(jax.lax, "conv_general_dilated", rounded)
+    yield
+    # programs traced under the patch must not serve another test
+    from keystone_tpu.compile.segment import reset_dispatchers
+    from keystone_tpu.workflow import fusion
+
+    reset_dispatchers()
+    fusion._FUSED_JIT_CACHE.clear()

@@ -1,0 +1,108 @@
+"""The fused conv → rectify → pool program compiled for the chip it runs on
+(TPU v5e) at ``cifar_patch10k``'s widths, without a chip: the TPU compiler
+installed here takes a described device. Nothing runs — this holds what
+interpret mode cannot: that Mosaic accepts the kernel's tiling, that the
+convolution's (n, 27, 27, 10,000) output is nowhere an HBM buffer, and that
+what an image holds ahead of the kernel is what segment dispatch is told.
+
+The topology is described inside a fixture, never at import: one process at
+a time may load the TPU's library, and every xdist worker imports this file.
+"""
+
+import os
+import re
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+from keystone_tpu.nodes.images.chain import ConvRectifyPool
+from keystone_tpu.nodes.images.core import (
+    Convolver,
+    ImageVectorizer,
+    Pooler,
+    SymmetricRectifier,
+)
+from keystone_tpu.nodes.learning.zca import ZCAWhitener
+from keystone_tpu.ops import conv_rectify_pool as crp
+
+FILTERS, IMAGES, SIDE = 10000, 1024, 32
+
+
+@pytest.fixture(scope="module")
+def one_chip():
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    from jax.experimental import topologies
+    from jax.sharding import SingleDeviceSharding
+
+    try:
+        topo = topologies.get_topology_desc(
+            platform="tpu", topology_name="v5e:2x2"
+        )
+    except Exception as e:  # noqa: BLE001 - no TPU compiler here
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    return SingleDeviceSharding(topo.devices[0])
+
+
+@pytest.fixture(scope="module")
+def compiled(one_chip):
+    """The chain and the vectorizer behind it, as a fit's segment holds
+    them, over one row slice. A described device's executable cannot be
+    read back from the persistent cache: keep it out of it."""
+    from jax.experimental.compilation_cache import compilation_cache
+
+    rng = np.random.default_rng(0)
+    m = 6 * 6 * 3
+    node = ConvRectifyPool(
+        Convolver(
+            rng.standard_normal((FILTERS, m)).astype(np.float32),
+            SIDE, SIDE, 3,
+            whitener=ZCAWhitener(
+                np.eye(m, dtype=np.float32), np.zeros(m, np.float32)
+            ),
+        ),
+        SymmetricRectifier(alpha=0.25), Pooler(13, 14, None, "sum"),
+    )
+    vectorizer = ImageVectorizer()
+    images = jax.ShapeDtypeStruct(
+        (IMAGES, SIDE, SIDE, 3), jnp.float32, sharding=one_chip
+    )
+    cache_was_on = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    mode = crp.kernel_mode
+    crp.kernel_mode = lambda: "compiled"  # the backend here is the CPU
+    try:
+        return jax.jit(
+            lambda X: vectorizer.trace_batch(node.trace_batch(X))
+        ).lower(images).compile()
+    finally:
+        crp.kernel_mode = mode
+        jax.config.update("jax_enable_compilation_cache", cache_was_on)
+        compilation_cache.reset_cache()
+
+
+def test_mosaic_takes_the_kernel_at_10000_filters(compiled):
+    text = compiled.as_text()
+    assert "tpu_custom_call" in text
+    assert re.search(r"%conv_rectify_pool[.\d]* = f32\[1024,8,10000\]", text)
+
+
+def test_the_convolutions_output_is_no_hbm_buffer(compiled):
+    """No array of the program holds 27 × 27 windows by thousands of
+    filters; the widest thing a window has is its 128-lane patch row."""
+    shapes = re.findall(r"(?:f32|bf16)\[([\d,]+)\]", compiled.as_text())
+    widest = max(
+        int(np.prod([int(d) for d in s.split(",")])) for s in shapes
+    )
+    # the patch rows (784 × 128) and the features (80,000) are the widest
+    # an image has; the convolution's output would be 7,290,000
+    assert widest == IMAGES * crp.pool_plan(27, 27, 13, 14).rows * 128
+    assert not [s for s in shapes if re.search(r",27,27,\d{4,}$", s)]
+
+
+def test_an_images_scratch_is_what_dispatch_is_told(compiled):
+    told = crp.scratch_bytes(27, 27, 13, 14)
+    held = compiled.memory_analysis().temp_size_in_bytes / IMAGES
+    assert 0.8 * told <= held <= 1.1 * told, (told, held)

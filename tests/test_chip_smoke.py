@@ -91,6 +91,17 @@ def test_kernel_leg_interpreted_and_sync_check():
     assert sync["block_until_ready_seconds"] > 0
 
 
+def test_conv_chain_leg_interpreted():
+    """The leg's control flow at a tiny size. Interpreted on the CPU the
+    bodies' product is float32 and the kernel's operands bf16, so the gap
+    is one bf16 pass's, far from the compiled leg's 1e-5."""
+    report = chip_smoke.conv_chain_leg(filters=100, images=5, interpret=True)
+    assert report["ok"] and report["finite"], report
+    assert report["shape"] == {"filters": 100, "images": 5, "side": 32}
+    assert 0 < report["max_rel_gap_xla"] < 2e-2
+    assert "front_door_chose_kernel" not in report
+
+
 def test_a_forced_segment_demotion_is_seen():
     """A segment whose compiled program raises at run time is served node
     by node with a warning (tests/compile/test_segment.py pins that the
