@@ -456,14 +456,22 @@ def conv_chain_leg(*, filters, images, side=32, interpret=False):
     return report
 
 
+def _fetch_scalar(x) -> None:
+    """Read one element back to the host: the device stream has really
+    completed when it arrives."""
+    import numpy as np
+
+    while getattr(x, "ndim", 0) > 0:
+        x = x[0]
+    np.asarray(x)
+
+
 def sync_check(*, size, steps):
     """Does a timing that ends in ``block_until_ready`` agree with one
-    that ends in bench.py's scalar read-back, on the same chain of
-    dependent matmul dispatches?"""
+    that ends in a scalar read-back, on the same chain of dependent matmul
+    dispatches?"""
     import jax
     import jax.numpy as jnp
-
-    from bench import _fetch_scalar
 
     w = jax.random.normal(jax.random.PRNGKey(1), (size, size), jnp.float32)
     w = w / jnp.sqrt(size)

@@ -34,7 +34,7 @@ can pin its verdict explicitly (``check_verdict = "stateful"`` or
 from __future__ import annotations
 
 import logging
-from typing import Any, Dict, Iterable, Optional, Set
+from typing import Any, Dict, Optional, Set
 
 logger = logging.getLogger(__name__)
 
@@ -50,14 +50,6 @@ OPAQUE = "opaque"
 SEVERITY = (OPAQUE, STATEFUL, HOST_CALLBACK, BATCH_COUPLED, TRACEABLE)
 
 VERDICTS = frozenset(SEVERITY)
-
-
-def worst(verdicts: Iterable[str]) -> str:
-    """The lattice meet: the worst verdict present (traceable if empty)."""
-    best = len(SEVERITY) - 1
-    for v in verdicts:
-        best = min(best, SEVERITY.index(v))
-    return SEVERITY[best]
 
 
 def blocks_jit(verdict: str) -> bool:
@@ -222,11 +214,6 @@ def classify(op: Any) -> str:
                 f"lattice verdict {sorted(VERDICTS)}"
             )
         return declared
-
-    # fused chains: the composite is exactly as good as its worst step
-    steps = getattr(op, "steps", None)
-    if steps is not None and cls.__name__ == "FusedTransformerOperator":
-        return worst(classify(s) for s, _ in steps)
 
     if isinstance(op, GatherTransformerOperator):
         return TRACEABLE  # structural zip: identity inside a traced fn

@@ -7,13 +7,14 @@ which could lower into ONE fused XLA program, and each barrier is a point
 where data must materialize — a Cacher (the result must hit the state
 table / HBM pin), an out-of-core scan seam (chunked leaves produce data
 chunk-at-a-time), a host-side node (opaque / callback / stateful), an
-estimator boundary (fit-time solve), or a gather join (N branch programs
-meet in one zip — today's trace fusion also treats the join's consumers
-as a fresh group root).
+estimator boundary (fit-time solve), a saveable prefix (its result must
+hit the state table), a ``no_fuse`` node (dataset-sized operands must not
+become a program's literals), or a gather join whose zipped value must
+leave the program (a reader that is no member, or a sink).
 
-Today the plan is consumed for *validation and reporting*
-(``Pipeline.check()``, ``--check``); tomorrow the per-segment lowering
-starts from exactly these boundaries.
+This is the ONE grouping decision: ``Pipeline.check()`` / ``--check``
+report the plan, and the executor lowers and dispatches exactly these
+segments (``compile/segment.py``).
 """
 
 from __future__ import annotations
@@ -35,6 +36,7 @@ BARRIER_ESTIMATOR = "estimator"
 BARRIER_GATHER = "gather_join"
 BARRIER_SAVED = "saved_state"
 BARRIER_DATA = "data_leaf"
+BARRIER_NO_FUSE = "no_fuse"
 
 
 @dataclass
@@ -68,7 +70,9 @@ def barrier_reason(
 
     Barrier-ness is orthogonal to the verdict for Cachers (their traced
     form is identity — traceable — but their *purpose* is to
-    materialize)."""
+    materialize). A gather join is a barrier HERE, where only the operator
+    is seen; :func:`plan_segments`, which sees its readers, makes it a
+    member (a tuple inside the program) where every reader is one."""
     from ..workflow.operators import (
         DatasetOperator,
         DatumOperator,
@@ -88,6 +92,8 @@ def barrier_reason(
         return BARRIER_GATHER
     if type(op).__name__ == "Cacher":
         return BARRIER_CACHER
+    if getattr(op, "no_fuse", False):
+        return BARRIER_NO_FUSE
     if lattice.blocks_jit(verdict) or verdict == lattice.HOST_CALLBACK:
         return BARRIER_HOST
     return None
@@ -110,6 +116,7 @@ def plan_segments(
     *,
     cost_estimator: Any = None,
     materialized: Any = (),
+    annotations: Any = (),
 ) -> Tuple[List[Segment], Dict[Any, str]]:
     """Partition ``graph`` into maximal traceable segments.
 
@@ -119,6 +126,8 @@ def plan_segments(
     topological order of their first node. ``materialized`` are nodes whose
     value the caller already holds (an executor's memo): data, like saved
     state — a segment through one would compute it again from its inputs.
+    ``annotations`` are the optimizer's saveable prefixes: such a node's
+    result must hit the state table, so it bounds segments too.
     """
     from ..workflow import analysis
     from ..workflow.graph import NodeId
@@ -136,16 +145,37 @@ def plan_segments(
     eligible = set()
     from .abstract import leaf_is_chunked
 
+    consumers: Dict[Any, set] = {}
     for n in order:
+        for d in graph.get_dependencies(n):
+            consumers.setdefault(d, set()).add(n)
+    sink_deps = set(graph.sink_dependencies.values())
+
+    # readers first: whether a gather join is a member depends on them
+    for n in reversed(order):
         op = graph.get_operator(n)
-        reason = BARRIER_SAVED if n in materialized else barrier_reason(
-            op, verdicts.get(n, lattice.OPAQUE),
-            is_chunked_leaf=leaf_is_chunked(op),
-        )
+        if n in materialized or n in annotations:
+            reason = BARRIER_SAVED
+        else:
+            reason = barrier_reason(
+                op, verdicts.get(n, lattice.OPAQUE),
+                is_chunked_leaf=leaf_is_chunked(op),
+            )
+            readers = consumers.get(n)
+            if (
+                reason == BARRIER_GATHER
+                and readers
+                and readers <= eligible
+                and n not in sink_deps
+            ):
+                # the join's zipped value never leaves the program: inside
+                # it the join is a tuple of its branches
+                reason = None
         if reason is None:
             eligible.add(n)
         else:
             barriers[n] = reason
+    barriers = {n: barriers[n] for n in order if n in barriers}
 
     # union-find over edges between eligible nodes
     parent: Dict[Any, Any] = {n: n for n in eligible}
@@ -170,12 +200,6 @@ def plan_segments(
     for n in order:
         if n in eligible:
             groups.setdefault(find(n), []).append(n)
-
-    consumers: Dict[Any, set] = {}
-    for n in order:
-        for d in graph.get_dependencies(n):
-            consumers.setdefault(d, set()).add(n)
-    sink_deps = set(graph.sink_dependencies.values())
 
     topo_pos = {n: i for i, n in enumerate(order)}
     segments: List[Segment] = []

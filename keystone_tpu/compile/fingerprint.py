@@ -13,14 +13,14 @@ everything that determines the traced program:
 * **fitted parameters** — every attribute of every operator, canonicalized
   by content: scalars/strings verbatim, numpy and jax arrays as
   shape+dtype+sha256-of-bytes, containers recursively, nested operators
-  (the optimizer's ``FusedTransformerOperator`` holds its steps as state)
-  recursively, plain Python functions as code+constants+closure digests.
+  (``ConvRectifyPool`` holds its three nodes as state) recursively, plain
+  Python functions as code+constants+closure digests.
 
 Anything whose content cannot be proven stable across processes (bound
 native objects, jitted callables, lazy datasets) raises
 :class:`FingerprintError` — the caller falls back to a live compile
 rather than risking a bogus cache key. Derived/memo state a class
-declares in ``aot_fingerprint_exclude`` (e.g. ``FusedTransformerOperator._jit``)
+declares in ``aot_fingerprint_exclude`` (e.g. ``BlockLinearMapper.solver_state``)
 is skipped: a warm operator must fingerprint identically to a fresh one.
 
 The digest is pure content — no ``hash()`` (PYTHONHASHSEED), no ``id()``,

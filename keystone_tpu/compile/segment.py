@@ -1,11 +1,13 @@
 """Segment compilation: lower a planned traceable segment into ONE jitted
 function and dispatch it through the AOT export/cache/manifest plane.
 
-``check/segments.py`` (PR 13) partitions the optimized DAG into maximal
-traceable segments between materialization barriers. This module is the
-payoff: :func:`lower_segment` composes the member operators'
-``trace_batch`` bodies in topo order into a single function over the
-segment's pinned ``inputs`` → ``outputs`` tuple, and
+``check/segments.py`` partitions the optimized DAG into maximal traceable
+segments between materialization barriers — the one grouping decision.
+This module is the one lowering and the one dispatch:
+:func:`lower_segment` composes the member operators' ``trace_batch``
+bodies in topo order into a single function over the segment's pinned
+``inputs`` → ``outputs`` tuple (a gather join among them is a tuple of its
+branches), and
 :class:`SegmentDispatcher` resolves one executable per input-signature
 tuple exactly the way :class:`~keystone_tpu.compile.aot.AotDispatcher`
 does for serving buckets — cache hit ⇒ deserialize, zero traces; miss ⇒
@@ -24,16 +26,19 @@ lowered steps, the content digest, and the three runtime paths —
 * **chunked** — a single-output segment over chunked data rides the
   out-of-core scan per chunk through :class:`ChunkPadder` (ragged final
   chunks pad to the bucket ladder, results slice back);
-* **fallback** — anything else (item-list inputs, batch-coupled members
-  over chunks, multi-output chunked segments, a runtime failure) degrades
-  to exact per-node semantics: same operators, same order, same answers.
+* **fallback** — anything else (item-list inputs, multi-output chunked
+  segments, a runtime failure) degrades to exact per-node semantics: same
+  operators, same order, same answers.
+
+Batch-coupled members over chunked input are the caller's error on every
+path (batch statistics a chunk): :meth:`SegmentBinding.run` raises.
 
 Adaptive boundaries close the loop through ``cost/segments.py``: each
 compile and each run is recorded under the profile store's
 ``plan/segment/`` namespace, and a segment whose observed compile cost
 swamps its cumulative dispatch savings is demoted back to node dispatch
-on the next fit. ``KEYSTONE_SEGMENT_COMPILE=0`` kill-switches the whole
-layer (read per pull by the executor, not here).
+on the next fit. Node dispatch itself is ``GraphExecutor(graph,
+segment_plan={})``: planned, nothing eligible.
 """
 
 from __future__ import annotations
@@ -87,12 +92,31 @@ def lower_segment(graph: Any, segment: Any) -> Tuple[Callable, List[Step], Tuple
     out_slots = tuple(pos[o] for o in segment.outputs)
 
     def fn(*xs):
-        values = list(xs)
-        for op, slots in steps:
-            values.append(op.trace_batch(*[values[s] for s in slots]))
+        values = _trace_steps(steps, list(xs))
         return tuple(values[s] for s in out_slots)
 
     return fn, steps, out_slots
+
+
+def _trace_steps(
+    steps: List[Step], values: List[Any],
+    made: Optional[Callable[[Any, List[Any], Any], None]] = None,
+) -> List[Any]:
+    """``values`` (the segment's inputs) with every step's traced value
+    appended — the one composition of ``trace_batch`` bodies. A gather
+    join is a tuple of its branches; ``made(op, args, out)`` sees every
+    other member's output."""
+    from ..workflow.operators import GatherTransformerOperator
+
+    for op, slots in steps:
+        args = [values[s] for s in slots]
+        if isinstance(op, GatherTransformerOperator):
+            values.append(tuple(args))
+        else:
+            values.append(op.trace_batch(*args))
+            if made is not None:
+                made(op, args, values[-1])
+    return values
 
 
 class SegmentDispatcher:
@@ -400,35 +424,19 @@ def _item_bytes(
     import jax
     import numpy as np
 
-    from ..workflow.fusion import FusedTransformerOperator
-    from ..workflow.operators import GatherTransformerOperator
-
     produced: List[Any] = []
     scratch: List[int] = []
 
-    def trace(op, args):
-        # a fused chain is priced by ITS members: the optimizer has folded
-        # the user's nodes into one operator, their outputs are still made
-        if isinstance(op, FusedTransformerOperator):
-            values = list(args)
-            for inner, deps in op.steps:
-                values.append(trace(inner, [values[i] for i in deps]))
-            return values[-1]
-        if isinstance(op, GatherTransformerOperator):
-            return tuple(args)
-        out = op.trace_batch(*args)
+    def made(op, args, out):
         produced.append(out)
         # what a member holds a row besides its output, where it says so
         # (a kernel's operands laid out in HBM ahead of it)
         row_scratch = getattr(op, "row_scratch_bytes", None)
         if row_scratch is not None and args:
             scratch.append(int(row_scratch(args[0].shape)))
-        return out
 
     def members(*xs):
-        values = list(xs)
-        for op, slots in steps:
-            values.append(trace(op, [values[s] for s in slots]))
+        values = _trace_steps(steps, list(xs), made)
         return produced, [values[s] for s in out_slots]
 
     rows = sigs[0][0][0]
@@ -450,20 +458,14 @@ def _item_bytes(
 
 
 def _runs_conv_kernel(steps: List[Step], shapes: List[Any]) -> bool:
-    """Whether a member of ``steps`` (a fused chain's included) sends its
-    rows through the fused convolution kernel
-    (``nodes/images/chain.py:ConvRectifyPool.kernel_mode``), judged where it
-    reads one of the inputs, whose ``shapes`` are known without a trace."""
-    from ..workflow.fusion import FusedTransformerOperator
-
+    """Whether a member of ``steps`` sends its rows through the fused
+    convolution kernel (``nodes/images/chain.py:ConvRectifyPool.kernel_mode``),
+    judged where it reads one of the inputs, whose ``shapes`` are known
+    without a trace."""
     for op, slots in steps:
-        args = [shapes[s] if s < len(shapes) else None for s in slots]
-        if isinstance(op, FusedTransformerOperator):
-            if _runs_conv_kernel(op.steps, args):
-                return True
-        elif args and args[0] is not None:
-            mode = getattr(op, "kernel_mode", None)
-            if mode is not None and mode(args[0]) is not None:
+        mode = getattr(op, "kernel_mode", None)
+        if mode is not None and slots and slots[0] < len(shapes):
+            if mode(shapes[slots[0]]) is not None:
                 return True
     return False
 
@@ -534,6 +536,11 @@ class SegmentBinding:
     Any runtime failure demotes the binding permanently (this process)
     and re-runs through exact node semantics — segment dispatch must
     never change answers or surface new errors.
+
+    ``digest`` is None where a member's state has no content-stable form
+    (a closure, a lambda): the segment is still one structural ``jax.jit``
+    program, owned by this binding — only sharing across executors, the
+    cost records and the export need a digest.
     """
 
     def __init__(
@@ -545,10 +552,10 @@ class SegmentBinding:
         fn: Callable,
         steps: List[Step],
         out_slots: Tuple[int, ...],
-        digest: str,
+        digest: Optional[str],
         label: str,
         node_ids: List[str],
-        batch_coupled: bool,
+        coupled_labels: List[str],
     ):
         self.index = index
         self.inputs = list(inputs)
@@ -559,13 +566,20 @@ class SegmentBinding:
         self.digest = digest
         self.label = label
         self.node_ids = list(node_ids)
-        self.batch_coupled = batch_coupled
+        #: labels of the members whose ``trace_batch`` couples rows
+        self.coupled_labels = list(coupled_labels)
+        self.batch_coupled = bool(coupled_labels)
+        self._own_dispatcher = None if digest is not None else (
+            SegmentDispatcher(fn, "", None, label=label, n_nodes=len(steps))
+        )
         self._demoted = False
 
     def __len__(self) -> int:
         return len(self.steps)
 
     def _dispatcher(self) -> SegmentDispatcher:
+        if self._own_dispatcher is not None:
+            return self._own_dispatcher
         return dispatcher_for(
             self.digest, lambda: self.fn, label=self.label,
             n_nodes=len(self.steps),
@@ -577,27 +591,38 @@ class SegmentBinding:
         """``facts`` (the ``exec.segment`` span's attrs) is given what the
         compiled path dispatched: ``rows``, ``row_slices``, ``slice_rows``
         (one slice of all the rows is the whole-batch dispatch)."""
+        from ..data.chunked import ChunkedDataset
+
+        # ChunkedDataset reports is_batched=True — check it FIRST
+        chunked = any(isinstance(ds, ChunkedDataset) for ds in datasets)
+        if chunked and self.batch_coupled:
+            # the caller's error on every path, not a failed dispatch
+            raise ValueError(
+                f"batch-coupled node(s) {self.coupled_labels} cannot stream "
+                "per-chunk: batch statistics would be computed per "
+                "chunk — materialize the dataset first"
+            )
         if self._demoted:
             return self._fallback(datasets), "fallback"
         try:
-            return self._run(datasets, {} if facts is None else facts)
+            return self._run(
+                datasets, {} if facts is None else facts, chunked
+            )
         except Exception as e:
             self._demote(f"runtime failure: {e!r}")
             return self._fallback(datasets), "fallback"
 
     def _run(
-        self, datasets: List[Any], facts: dict
+        self, datasets: List[Any], facts: dict, chunked: bool
     ) -> Tuple[Tuple[Any, ...], str]:
-        from ..data.chunked import ChunkedDataset, align_and_zip
+        from ..data.chunked import align_and_zip
         from ..data.dataset import Dataset
         from ..data.pipeline_scan import ChunkPadder
 
-        # ChunkedDataset reports is_batched=True — check it FIRST
-        if any(isinstance(ds, ChunkedDataset) for ds in datasets):
-            if self.batch_coupled or len(self.out_slots) != 1:
-                # batch-coupled members must see whole batches, and a
-                # multi-output chunked segment would rescan the source
-                # once per output — node semantics handle both exactly
+        if chunked:
+            if len(self.out_slots) != 1:
+                # a multi-output chunked segment would rescan the source
+                # once per output — node semantics handle it exactly
                 return self._fallback(datasets), "fallback"
             disp = self._dispatcher()
             if len(datasets) == 1:
@@ -628,10 +653,11 @@ class SegmentBinding:
                 shapes = [(slice_rows,) + a.shape[1:] for a in arrays]
                 if _runs_conv_kernel(self.steps, shapes):
                     facts["conv_fused_rows"] = rows
-            seg_cost.record_run(
-                self.digest, time.perf_counter() - t0,
-                n_nodes=len(self.steps),
-            )
+            if self.digest is not None:
+                seg_cost.record_run(
+                    self.digest, time.perf_counter() - t0,
+                    n_nodes=len(self.steps),
+                )
             return (
                 tuple(Dataset(o, batched=True) for o in raw),
                 "compiled",
@@ -739,6 +765,8 @@ class SegmentBinding:
             "segment %s (%s): %s — demoted to node dispatch",
             self.index, self.label, why, exc_info=True,
         )
+        if self.digest is None:
+            return
         try:
             from ..cost import segments as seg_cost
 
@@ -747,47 +775,39 @@ class SegmentBinding:
             logger.debug("segment: could not record demotion", exc_info=True)
 
 
-def bind_segment(
-    graph: Any, segment: Any, *, annotations: Optional[Dict[Any, str]] = None
-) -> Optional[SegmentBinding]:
+def bind_segment(graph: Any, segment: Any) -> Optional[SegmentBinding]:
     """Lower ``segment`` into a dispatchable binding, or None when it is
     not worth (or not safe to) segment-dispatch:
 
-    * empty, or a singleton whose operator is not already a fused chain —
-      a single plain node gains nothing over its node thunk, but a
-      singleton :class:`FusedTransformerOperator` IS eligible: that is
-      how an optimizer-fused fit graph gets whole-chain AOT export;
-    * any member annotated for the pipeline env whose value would NOT
-      surface (interior annotated nodes must materialize individually);
+    * empty, or a singleton — a single node gains nothing over its node
+      thunk;
     * any member without a traceable ``trace_batch`` (defense in depth —
       the planner's lattice should have barriered these already);
-    * the segment fingerprint is uncomputable (unhashable operator
-      state);
     * the cost model demoted this digest (compile cost exceeded observed
       dispatch savings — the adaptive-boundary split).
+
+    A segment whose fingerprint is uncomputable (unhashable operator
+    state) still binds, with no digest: see :class:`SegmentBinding`.
     """
-    from ..workflow.fusion import FusedTransformerOperator
     from ..workflow.graph import NodeId
-    from ..workflow.operators import TransformerOperator
+    from ..workflow.operators import (
+        GatherTransformerOperator,
+        TransformerOperator,
+    )
 
     members = list(segment.nodes)
-    if not members:
+    if len(members) < 2:
         return None
     ops = []
     for n in members:
         op = graph.get_operator(n)
         if not isinstance(op, TransformerOperator):
             return None
-        if not callable(getattr(op, "trace_batch", None)):
+        if not isinstance(op, GatherTransformerOperator) and not callable(
+            getattr(op, "trace_batch", None)
+        ):
             return None
         ops.append(op)
-    if len(members) == 1 and not isinstance(ops[0], FusedTransformerOperator):
-        return None
-    out_set = set(segment.outputs)
-    if annotations:
-        for n in members:
-            if n in annotations and n not in out_set:
-                return None
     for d in segment.inputs:
         if not isinstance(d, NodeId):
             return None
@@ -810,24 +830,25 @@ def bind_segment(
         if isinstance(a, NodeId) and a in graph.operators:
             stack.extend(graph.get_dependencies(a))
     try:
-        digest = segment_fingerprint(graph, segment)
+        digest: Optional[str] = segment_fingerprint(graph, segment)
     except FingerprintError:
         logger.debug(
-            "segment %s: unfingerprintable — node dispatch", segment.index,
-            exc_info=True,
+            "segment %s: unfingerprintable — a program of its binding's own",
+            segment.index, exc_info=True,
         )
-        return None
+        digest = None
     from ..cost import segments as seg_cost
 
-    if not seg_cost.should_compile(digest, len(members)):
+    if digest is not None and not seg_cost.should_compile(
+        digest, len(members)
+    ):
         logger.info(
             "segment %s: demoted by cost model — node dispatch",
             segment.index,
         )
         return None
     fn, steps, out_slots = lower_segment(graph, segment)
-    labels = [op.label for op in ops]
-    label = "+".join(labels)
+    label = "+".join(op.label for op in ops)
     if len(label) > 96:
         label = label[:93] + "..."
     return SegmentBinding(
@@ -840,9 +861,9 @@ def bind_segment(
         digest=digest,
         label=label,
         node_ids=[str(n.id) for n in members],
-        batch_coupled=any(
-            bool(getattr(op, "batch_coupled", False)) for op in ops
-        ),
+        coupled_labels=[
+            op.label for op in ops if getattr(op, "batch_coupled", False)
+        ],
     )
 
 

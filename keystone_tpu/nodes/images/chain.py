@@ -3,8 +3,8 @@
 
 Pipelines write the chain as the reference does (``Convolver.and_then(
 SymmetricRectifier).and_then(Pooler)``, RandomPatchCifar.scala:72-83);
-:class:`ConvChainRule` recognises it in the optimized graph, ahead of chain
-fusion and segment planning, and puts :class:`ConvRectifyPool` in its place:
+:class:`ConvChainRule` recognises it in the optimized graph, ahead of
+segment planning, and puts :class:`ConvRectifyPool` in its place:
 one member whose output is the pooled features, so segment dispatch prices a
 row at 0.3 MB and not at the 88 MB the three outputs take at 10,000 filters.
 The node runs ``ops/conv_rectify_pool.py`` where that kernel can run — the

@@ -87,10 +87,11 @@ class KernelBlockLinearMapper(Transformer):
     """Apply a kernel model: out = Σ_B K(test, train_B) · W_B
     (parity: KernelBlockLinearMapper.scala:28-90)."""
 
-    # Never trace-fuse: train_X/W are dataset-sized, so baking them into a
-    # fused XLA module as literals (or fetching them host-side) is exactly
-    # the wrong trade. They stay device-resident; _gaussian_block takes them
-    # as jit *arguments*.
+    # Never a segment member (``check/segments.py`` makes it a barrier):
+    # train_X/W are dataset-sized, so baking them into a segment's XLA
+    # module as literals (or fetching them host-side) is exactly the wrong
+    # trade. They stay device-resident; _gaussian_block takes them as jit
+    # *arguments*.
     no_fuse = True
 
     def __init__(self, train_X, model_W, gamma: float, block_size: int):

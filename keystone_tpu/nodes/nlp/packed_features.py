@@ -19,8 +19,7 @@ first-occurrence order. Equality with the composed chain is pinned by
 tests/nodes/test_packed_features.py.
 
 Why it exists: the host featurization substrate is the measured bottleneck
-of the text pipelines (bench.py ``text_featurization``: featurize/solve
-ratio >> 1 at 20k docs). This is the same fusion philosophy the device
+of the text pipelines (featurize/solve ratio >> 1 at 20k docs). This is the same fusion philosophy the device
 side gets from whole-chain jit — collapse a chain of per-item stages into
 one batched program — applied to the host stages in front of the device
 boundary.

@@ -8,8 +8,8 @@ the tracer (``AutoCacheRule.apply``), the executor's spans record what
 each node actually cost, and :func:`cache_audit` joins the two: one row
 per estimated node with estimate, observation, and the ratio between
 them. ``observed=False`` rows are themselves a finding — the node never
-executed under its planned identity (typically trace-fusion absorbed it,
-which also voids its Cacher).
+executed under its planned identity (typically a segment absorbed it: its
+members run as one ``exec.segment`` span, not as nodes).
 """
 
 from __future__ import annotations

@@ -9,8 +9,7 @@ producer-bound) seconds, staged H2D bytes, peak buffer occupancy, and
 chunk count. The overlap a scan achieved is readable straight off the
 span: ``seconds`` ≈ max(producer, consumer) work rather than their sum
 when the pipeline is doing its job, and the stall counters say which side
-bounded it. ``bench.py``'s ``chunk_pipeline`` extra and
-``bin/trace-smoke.sh`` consume these spans.
+bounded it. ``bin/trace-smoke.sh`` consumes these spans.
 
 Mesh-distributed scans (``lanes > 1``) additionally carry the sharding
 schedule: ``lanes``, per-lane chunk/byte totals (``lane_chunks`` /

@@ -35,7 +35,7 @@ class Span:
     thread_name: str = ""
     #: DAG node identity (stringified NodeId.id), None for non-node spans
     node_id: Optional[str] = None
-    #: operator class name (Cacher, FusedTransformerOperator, ...)
+    #: operator class name (Cacher, Segment, ...)
     op_type: Optional[str] = None
     #: "hit" (memoized result returned) | "miss" (computed this pull) | None
     cache: Optional[str] = None

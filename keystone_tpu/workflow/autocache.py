@@ -386,8 +386,8 @@ class AutoCacheRule(Rule):
         profile-guided-caching feedback loop after execution. Node ids are
         recorded BEFORE Cacher insertion (insert_cachers preserves the
         planned nodes' ids) and match the executor's span ``node`` field
-        as long as later rewrites (trace fusion) leave the node in place —
-        the audit flags the ones that disappear."""
+        where the node runs as a node — the audit flags the ones that a
+        segment dispatched instead."""
         tracer = obs_tracer.current()
         if tracer is None:
             return

@@ -57,15 +57,6 @@ def parallel_enabled() -> bool:
     return env_flag("KEYSTONE_PAR_EXEC", True)
 
 
-def segment_compile_enabled() -> bool:
-    """``KEYSTONE_SEGMENT_COMPILE`` kill switch (default on). Read per
-    pull, so one env flip drops the whole layer back to node dispatch
-    without rebuilding executors."""
-    from ..utils import env_flag
-
-    return env_flag("KEYSTONE_SEGMENT_COMPILE", True)
-
-
 def exec_workers() -> int:
     """Worker-pool width for scheduled pulls: ``KEYSTONE_EXEC_WORKERS``,
     default ``min(8, cpu)``. One pool per pull, sized to the pending work —
@@ -132,8 +123,9 @@ class GraphExecutor:
         #: pulls (serving threads) see a consistent ``_state``
         self._build_lock = threading.Lock()
         #: segment-compiled dispatch plan: output NodeId → SegmentBinding,
-        #: planned once per executor on the first segment-enabled pull
-        #: (None = not yet planned; {} = planned, nothing eligible).
+        #: planned once per executor on its first pull (None = not yet
+        #: planned; {} = planned, nothing eligible: node dispatch, operator
+        #: by operator — the reference path the tests compare against).
         #: ``segment_plan`` seeds it with a caller-cached plan — a
         #: FittedPipeline splices an identical graph per apply (node ids
         #: are deterministic, operators are shared objects), so the plan
@@ -144,8 +136,8 @@ class GraphExecutor:
 
     @property
     def segment_plan(self) -> Optional[Dict[NodeId, Any]]:
-        """The planned segment-dispatch table (None until the first
-        segment-enabled pull plans it) — cacheable across executors over
+        """The planned segment-dispatch table (None until the first pull
+        plans it) — cacheable across executors over
         identically-spliced graphs; see ``__init__``."""
         return self._seg_bindings
 
@@ -195,11 +187,9 @@ class GraphExecutor:
 
     def execute(self, graph_id: GraphId) -> Expression:
         with self._build_lock:
-            segments: Optional[Dict[NodeId, Any]] = None
-            if segment_compile_enabled():
-                if self._seg_bindings is None:
-                    self._seg_bindings = self._plan_segment_bindings()
-                segments = self._seg_bindings or None
+            if self._seg_bindings is None:
+                self._seg_bindings = self._plan_segment_bindings()
+            segments = self._seg_bindings or None
             built: Dict[NodeId, Expression] = {}
             expr = self._execute(
                 graph_id, transient={}, built=built, segments=segments
@@ -291,13 +281,12 @@ class GraphExecutor:
                 # is data: a segment through it would featurize again
                 held = {n for n, e in self._state.items() if e.computed}
                 planned, _barriers = plan_segments(
-                    graph, verdicts, {}, materialized=held
+                    graph, verdicts, {}, materialized=held,
+                    annotations=self._annotations,
                 )
                 table: Dict[NodeId, Any] = {}
                 for seg in planned:
-                    binding = bind_segment(
-                        graph, seg, annotations=self._annotations
-                    )
+                    binding = bind_segment(graph, seg)
                     if binding is None:
                         continue
                     for out in binding.outputs:
@@ -344,9 +333,6 @@ class GraphExecutor:
                     self._state[out] = oe
                 else:
                     transient[out] = oe
-                prefix = self._annotations.get(out)
-                if prefix is not None:
-                    PipelineEnv.get_or_create().state[prefix] = oe
             transient[outs_key] = out_exprs
         return out_exprs.get(graph_id)
 

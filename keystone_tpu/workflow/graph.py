@@ -282,63 +282,6 @@ class Graph:
         final_source_map = {s: m for s, m in source_map.items() if s not in splice.values()}
         return merged, final_source_map, sink_map
 
-    def replace_nodes(self, to_remove: FrozenSet[NodeId], replacement: "Graph",
-                      dep_splice: Mapping[SourceId, NodeOrSourceId],
-                      out_splice: Mapping[NodeId, SinkId]) -> "Graph":
-        """Swap the subgraph ``to_remove`` for ``replacement``.
-
-        ``dep_splice`` wires each replacement source to an id of the remaining
-        graph; ``out_splice`` says which replacement sink stands in for each
-        removed node that the remaining graph depended on.
-        """
-        for n in to_remove:
-            self._require_node(n)
-        for src in replacement.sources:
-            if src not in dep_splice:
-                raise GraphError(f"replacement {src} not spliced")
-        # every removed node that is still referenced must have a replacement sink
-        referenced = set()
-        for node, deps in self.dependencies.items():
-            if node in to_remove:
-                continue
-            referenced.update(d for d in deps if isinstance(d, NodeId) and d in to_remove)
-        referenced.update(
-            d for d in self.sink_dependencies.values() if isinstance(d, NodeId) and d in to_remove
-        )
-        for n in referenced:
-            if n not in out_splice:
-                raise GraphError(f"removed {n} is referenced but has no replacement sink")
-        for src, tgt in dep_splice.items():
-            if isinstance(tgt, NodeId) and tgt in to_remove:
-                raise GraphError("dep_splice target is being removed")
-
-        merged, source_map, sink_map = self.add_graph(replacement)
-        # rewire edges into removed nodes -> replacement sinks' dependencies
-        for removed, sink in out_splice.items():
-            new_target = merged.get_sink_dependency(sink_map[sink])
-            merged = merged.replace_dependency(removed, new_target)
-        # wire replacement sources to their splice targets
-        for src, tgt in dep_splice.items():
-            merged = merged.replace_dependency(source_map[src], tgt)
-            merged = merged.remove_source(source_map[src])
-        # drop replacement sinks
-        for sink in replacement.sink_dependencies:
-            merged = merged.remove_sink(sink_map[sink])
-        # drop removed nodes (reverse topological: repeatedly remove unreferenced)
-        remaining = set(to_remove)
-        while remaining:
-            progressed = False
-            for n in list(remaining):
-                try:
-                    merged = merged.remove_node(n)
-                except GraphError:
-                    continue
-                remaining.discard(n)
-                progressed = True
-            if not progressed:
-                raise GraphError(f"could not remove nodes {remaining}: still referenced")
-        return merged
-
     # ---- debugging ------------------------------------------------------
 
     def to_dot(self, name: str = "pipeline") -> str:

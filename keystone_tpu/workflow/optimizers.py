@@ -165,7 +165,7 @@ class DefaultOptimizer(Optimizer):
 
         return self._head_batches() + [
             Batch("Node Level Optimization", Strategy.ONCE, [NodeOptimizationRule()]),
-            self._fusion_batch(),
+            self._conv_chain_batch(),
         ]
 
     def _head_batches(self) -> List[Batch]:
@@ -182,19 +182,15 @@ class DefaultOptimizer(Optimizer):
             ),
         ]
 
-    def _fusion_batch(self) -> Batch:
-        """Last batch always: collapse traceable chains into single jitted
-        operators (one XLA program instead of N eager dispatches). Runs after
-        every structural rule so Cachers/estimators bound the fusion groups.
-        Ahead of it a convolution chain becomes the one node that keeps the
-        convolution's output on the chip (``nodes/images/chain.py``)."""
+    def _conv_chain_batch(self) -> Batch:
+        """Last batch always: a convolution chain becomes the one node that
+        keeps the convolution's output on the chip
+        (``nodes/images/chain.py``). Which nodes make one XLA program is
+        not the optimizer's decision: the executor's segment planner groups
+        what is left (``check/segments.py``)."""
         from ..nodes.images.chain import ConvChainRule
-        from .fusion import TraceFusionRule
 
-        return Batch(
-            "Trace Fusion", Strategy.ONCE,
-            [ConvChainRule(), TraceFusionRule()],
-        )
+        return Batch("Convolution Chain", Strategy.ONCE, [ConvChainRule()])
 
 
 class AutoCachingOptimizer(DefaultOptimizer):
@@ -219,5 +215,5 @@ class AutoCachingOptimizer(DefaultOptimizer):
                 Strategy.ONCE,
                 [AutoCacheRule(self.strategy, self.mem_budget_bytes)],
             ),
-            self._fusion_batch(),
+            self._conv_chain_batch(),
         ]

@@ -14,7 +14,7 @@ becomes one fused XLA computation instead of N kernel launches
 from __future__ import annotations
 
 import logging
-from typing import Any, Callable, Dict, List, Optional, Sequence, Union
+from typing import Any, Callable, List, Optional, Sequence, Union
 
 from ..data.dataset import Dataset
 from ..obs.tracer import current as _trace_current
@@ -636,16 +636,15 @@ class FittedPipeline(Chainable):
             p._datum_hint = (self.datum_shape, self.datum_dtype)
         return p
 
-    # -- application (no optimizer pass and NO re-fusion: parity with the
-    #    reference, which applies FittedPipelines without re-optimizing — and
-    #    a hard numerical invariant besides. The graph arrives here already
-    #    trace-fused by the optimizer (fit() runs fusion before estimators
-    #    execute), so every estimator was fit on features computed under
-    #    exactly this program partitioning. Re-fusing after fit would merge
-    #    the replaced transformer nodes into NEW XLA programs whose
-    #    reassociated float32 arithmetic can disagree with what the solver
-    #    trained on — observed as Fisher-Vector posterior assignments
-    #    flipping between fit and apply, i.e. a broken model.)
+    # -- application (no optimizer pass: parity with the reference, which
+    #    applies FittedPipelines without re-optimizing. Which nodes make one
+    #    XLA program is the segment planner's decision, taken per executor:
+    #    at fit over the graph with its estimators (each a barrier), here
+    #    over the fitted chain — so the apply program holds the fitted
+    #    mapper and the featurizer together, which no fit-time program did.
+    #    Float32 results may differ in the last bits between the two
+    #    partitionings; tests/compile/test_segment.py holds them to node
+    #    dispatch.)
 
     def apply(self, data: Any) -> Dataset:
         graph, data_id = attach_data(self._graph, data)
@@ -743,20 +742,22 @@ class FittedPipeline(Chainable):
     def _build_trace_fn(self) -> Callable:
         """The raw chain builder — callers must have cleared
         :meth:`untraceable_nodes` first."""
-        graph, source, sink = self._graph, self._source, self._sink
+        from ..check.segments import Segment
+        from ..compile.segment import lower_segment
 
-        order = [n for n in analysis.linearize(graph) if isinstance(n, NodeId)]
+        graph = self._graph
+        whole = Segment(
+            index=0,
+            nodes=[
+                n for n in analysis.linearize(graph) if isinstance(n, NodeId)
+            ],
+            inputs=[self._source],
+            outputs=[graph.get_sink_dependency(self._sink)],
+        )
+        chain, _steps, _out_slots = lower_segment(graph, whole)
 
         def fn(x):
-            values: Dict[Any, Any] = {source: x}
-            for node in order:
-                args = [values[d] for d in graph.get_dependencies(node)]
-                op = graph.get_operator(node)
-                if isinstance(op, GatherTransformerOperator):
-                    values[node] = tuple(args)
-                else:
-                    values[node] = op.trace_batch(*args)
-            return values[graph.get_sink_dependency(sink)]
+            return chain(x)[0]
 
         return fn
 

@@ -155,21 +155,28 @@ def test_explicit_verdict_pin():
     assert classify(Pinned()) == STATEFUL
 
 
-def test_fused_chain_is_worst_of_steps():
-    from keystone_tpu.workflow.fusion import FusedTransformerOperator
-
-    fused = FusedTransformerOperator(
-        [(Identity(), (0,)), (LinearRectifier(0.0), (1,))], 1
-    )
-    assert classify(fused) == TRACEABLE
+def test_segment_is_as_coupled_as_its_worst_member():
+    from keystone_tpu.check.segments import plan_segments
+    from keystone_tpu.compile.segment import bind_segment
+    from keystone_tpu.workflow.pipeline import attach_data
 
     class Coupled(Identity):
         batch_coupled = True
 
-    fused2 = FusedTransformerOperator(
-        [(Identity(), (0,)), (Coupled(), (1,))], 1
-    )
-    assert classify(fused2) == BATCH_COUPLED
+    def binding_of(pipe):
+        g, data = attach_data(pipe.graph, np.ones((4, 3), np.float32))
+        g = g.replace_dependency(pipe.source, data).remove_source(pipe.source)
+        verdicts = {n: classify(g.get_operator(n)) for n in g.nodes}
+        (seg,) = [
+            s for s in plan_segments(g, verdicts, {})[0] if len(s.nodes) == 2
+        ]
+        return sorted(verdicts[n] for n in seg.nodes), bind_segment(g, seg)
+
+    verdicts, binding = binding_of(Identity().and_then(LinearRectifier(0.0)))
+    assert verdicts == [TRACEABLE, TRACEABLE] and not binding.batch_coupled
+    verdicts, binding = binding_of(Identity().and_then(Coupled()))
+    assert verdicts == [BATCH_COUPLED, TRACEABLE] and binding.batch_coupled
+    assert binding.coupled_labels == ["Coupled"]
 
 
 # ---------------------------------------------------------------------------

@@ -1,11 +1,11 @@
 """Segment-compiled execution: lowering, dispatch, AOT round trips,
 adaptive boundaries, and — above all — answer preservation.
 
-The executor dispatches the SEGMENT graph when ``KEYSTONE_SEGMENT_COMPILE``
-is on, so the load-bearing contract is bit-equality with node dispatch on
-every path (compiled, chunked/ragged, fallback, kill-switched) plus the
-warm-boot guarantee: a second process loads exported segment executables
-and never re-traces.
+The executor dispatches the SEGMENT graph, so the load-bearing contract is
+equality with node dispatch — operator by operator, ``segment_plan={}``,
+sharing no program with the segment — on every path (compiled,
+chunked/ragged, fallback) plus the warm-boot guarantee: a second process
+loads exported segment executables and never re-traces.
 """
 
 import numpy as np
@@ -29,9 +29,10 @@ from keystone_tpu.cost import segments as seg_cost
 from keystone_tpu.data.chunked import ChunkedDataset
 from keystone_tpu.data.dataset import Dataset
 from keystone_tpu.obs import tracer as tracer_mod
+from keystone_tpu.workflow.executor import GraphExecutor
 from keystone_tpu.workflow.graph import Graph
 from keystone_tpu.workflow.operators import DatasetOperator
-from keystone_tpu.workflow.pipeline import FittedPipeline
+from keystone_tpu.workflow.pipeline import FittedPipeline, attach_data
 from keystone_tpu.workflow.transformer import Transformer
 
 
@@ -73,6 +74,16 @@ class _HostOnly(Transformer):
 def _mul_chain_fitted():
     pipe = _Mul(2.0).and_then(_Mul(3.0)).and_then(_Mul(0.5))
     return FittedPipeline(pipe.graph, pipe.source, pipe.sink)
+
+
+def _apply_by_nodes(fitted, data):
+    """``fitted.apply(data)`` through node dispatch: the plan every
+    executor accepts as "planned, nothing eligible"."""
+    g, data_id = attach_data(fitted.graph, data)
+    g = g.replace_dependency(fitted._source, data_id)
+    g = g.remove_source(fitted._source)
+    executor = GraphExecutor(g, optimize=False, segment_plan={})
+    return executor.execute(fitted._sink).get()
 
 
 def _plan(graph):
@@ -175,10 +186,7 @@ def test_binding_dispatches_two_input_segment_compiled():
 
 def test_singleton_plain_node_is_not_bound():
     pipe = _Mul(2.0).and_then(_HostOnly()).and_then(_Mul(4.0))
-    g, data_id = pipe.graph, None
-    from keystone_tpu.workflow.pipeline import attach_data
-
-    g, data_id = attach_data(g, Dataset.of(X10))
+    g, data_id = attach_data(pipe.graph, Dataset.of(X10))
     g = g.replace_dependency(pipe.source, data_id)
     g = g.remove_source(pipe.source)
     segments, barriers = _plan(g)
@@ -190,11 +198,11 @@ def test_singleton_plain_node_is_not_bound():
 
 
 # ---------------------------------------------------------------------------
-# Executor dispatch: spans, kill switch, parity
+# Executor dispatch: spans, parity with node dispatch
 # ---------------------------------------------------------------------------
 
 
-def test_chain_applies_as_one_segment_span(monkeypatch):
+def test_chain_applies_as_one_segment_span():
     fitted = _mul_chain_fitted()
     tracer = tracer_mod.install(tracer_mod.Tracer())
     try:
@@ -211,19 +219,20 @@ def test_chain_applies_as_one_segment_span(monkeypatch):
     # member nodes emit NO per-node spans — that is the dispatch saving
     assert not any("_Mul" in s.name for s in spans)
 
-    monkeypatch.setenv("KEYSTONE_SEGMENT_COMPILE", "0")
     tracer = tracer_mod.install(tracer_mod.Tracer())
     try:
-        y_node = np.asarray(fitted.apply(Dataset.of(X10)).to_array())
+        y_node = np.asarray(
+            _apply_by_nodes(fitted, Dataset.of(X10)).to_array()
+        )
         node_spans = tracer.spans()
     finally:
         tracer_mod.reset()
     assert not any(s.name == "exec.segment" for s in node_spans)
     assert sum(1 for s in node_spans if "_Mul" in s.name) == 3
-    assert np.array_equal(y, y_node), "kill switch must not change answers"
+    assert np.array_equal(y, y_node), "one program must not change answers"
 
 
-def test_ragged_final_chunk_rides_chunk_padder(monkeypatch):
+def test_ragged_final_chunk_rides_chunk_padder():
     fitted = _mul_chain_fitted()
     chunked = ChunkedDataset.from_array(X10, 4)  # chunks of 4, 4, 2 rows
     tracer = tracer_mod.install(tracer_mod.Tracer())
@@ -236,9 +245,8 @@ def test_ragged_final_chunk_rides_chunk_padder(monkeypatch):
     (sp,) = [s for s in spans if s.name == "exec.segment"]
     assert sp.attrs["path"] == "chunked"
 
-    monkeypatch.setenv("KEYSTONE_SEGMENT_COMPILE", "0")
     y_node = np.asarray(
-        fitted.apply(ChunkedDataset.from_array(X10, 4)).to_array()
+        _apply_by_nodes(fitted, ChunkedDataset.from_array(X10, 4)).to_array()
     )
     assert np.array_equal(y, y_node)
 
@@ -264,7 +272,7 @@ def test_host_callback_chain_degrades_to_node_dispatch():
 # ---------------------------------------------------------------------------
 
 
-def test_mnist_random_fft_segment_vs_node_bit_equality(monkeypatch):
+def test_mnist_random_fft_segment_vs_node_equality(monkeypatch):
     from keystone_tpu.nodes.learning.linear import BlockLeastSquaresEstimator
     from keystone_tpu.nodes.util import ClassLabelIndicators, MaxClassifier
     from keystone_tpu.pipelines.mnist_random_fft import (
@@ -301,17 +309,23 @@ def test_mnist_random_fft_segment_vs_node_bit_equality(monkeypatch):
         tracer_mod.reset()
     assert any(s.name == "exec.segment" for s in spans)
 
-    monkeypatch.setenv("KEYSTONE_SEGMENT_COMPILE", "0")
-    y_node = np.asarray(fitted.apply(test.data).to_array())
+    y_node = np.asarray(_apply_by_nodes(fitted, test.data).to_array())
     assert np.array_equal(y_seg, y_node)
 
     # a fit run entirely under node dispatch trains the same model
+    monkeypatch.setattr(
+        GraphExecutor, "_plan_segment_bindings", lambda self: {}
+    )
     fitted_off = fit()
-    y_off = np.asarray(fitted_off.apply(test.data).to_array())
+    y_off = np.asarray(_apply_by_nodes(fitted_off, test.data).to_array())
     assert np.array_equal(y_seg, y_off)
 
 
-def test_timit_segment_vs_node_bit_equality(monkeypatch):
+def test_timit_segment_vs_node_equality():
+    """One program against operator-by-operator dispatch of the same
+    fitted chain: on the CPU the float32 scores agree to the bit, so the
+    predicted labels do. (Before PR 31 this compared the fused operator's
+    program with itself.)"""
     from keystone_tpu.nodes.learning.linear import BlockLeastSquaresEstimator
     from keystone_tpu.nodes.util import ClassLabelIndicators, MaxClassifier
     from keystone_tpu.pipelines.timit import (
@@ -327,22 +341,23 @@ def test_timit_segment_vs_node_bit_equality(monkeypatch):
     train = synthetic_timit(96, 4, dim=24, seed=0)
     test = synthetic_timit(24, 4, dim=24, seed=1)
     labels = ClassLabelIndicators(4).apply_batch(train.labels)
-    fitted = (
-        build_featurizer(conf)
-        .and_then(
+
+    def fit(*tail):
+        pipe = build_featurizer(conf).and_then(
             BlockLeastSquaresEstimator(
                 conf.cosine_features, conf.num_epochs, conf.lam
             ),
             train.data,
             labels,
         )
-        .and_then(MaxClassifier())
-        .fit()
-    )
-    y_seg = np.asarray(fitted.apply(test.data).to_array())
-    monkeypatch.setenv("KEYSTONE_SEGMENT_COMPILE", "0")
-    y_node = np.asarray(fitted.apply(test.data).to_array())
-    assert np.array_equal(y_seg, y_node)
+        for node in tail:
+            pipe = pipe.and_then(node)
+        return pipe.fit()
+
+    for fitted in (fit(), fit(MaxClassifier())):
+        y_seg = np.asarray(fitted.apply(test.data).to_array())
+        y_node = np.asarray(_apply_by_nodes(fitted, test.data).to_array())
+        assert np.array_equal(y_seg, y_node)
 
 
 # ---------------------------------------------------------------------------
@@ -450,8 +465,6 @@ def test_runtime_failure_demotes_and_next_plan_splits(tmp_path):
 def test_failed_dispatch_falls_back_to_exact_node_semantics():
     pipe = _Mul(2.0).and_then(_Mul(3.0))
     fitted = FittedPipeline(pipe.graph, pipe.source, pipe.sink)
-    from keystone_tpu.workflow.pipeline import attach_data
-
     g, data_id = attach_data(fitted.graph, Dataset.of(X10))
     g = g.replace_dependency(pipe.source, data_id)
     g = g.remove_source(pipe.source)

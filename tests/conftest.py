@@ -68,7 +68,5 @@ def bf16_products(monkeypatch):
     yield
     # programs traced under the patch must not serve another test
     from keystone_tpu.compile.segment import reset_dispatchers
-    from keystone_tpu.workflow import fusion
 
     reset_dispatchers()
-    fusion._FUSED_JIT_CACHE.clear()

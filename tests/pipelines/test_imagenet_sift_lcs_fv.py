@@ -101,13 +101,11 @@ def test_calibrated_gradient_signal_gates(monkeypatch):
         ).reshape(n, d, m_)
 
     monkeypatch.setattr(SIFTExtractor, "trace_batch", broken)
-    # the fused-executable and segment-dispatcher caches key on op
-    # type+params (not code), so a monkeypatched trace_batch would
-    # otherwise be served the healthy compiled program
+    # the segment dispatchers key on op type+params (not code), so a
+    # monkeypatched trace_batch would otherwise be served the healthy
+    # compiled program
     from keystone_tpu.compile.segment import reset_dispatchers
-    from keystone_tpu.workflow.fusion import _FUSED_JIT_CACHE
 
-    _FUSED_JIT_CACHE.clear()
     reset_dispatchers()
     broken_topk = np.asarray(
         build_predictor(tr_i, tr_l, conf)(te_i).get().to_array()

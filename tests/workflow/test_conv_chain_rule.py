@@ -20,7 +20,6 @@ from keystone_tpu.nodes.learning.linear import BlockLinearMapper
 from keystone_tpu.obs import tracer as obs_tracer
 from keystone_tpu.ops import conv_rectify_pool as crp
 from keystone_tpu.pipelines import cifar_extras, random_patch_cifar
-from keystone_tpu.workflow import fusion
 from keystone_tpu.workflow.env import PipelineEnv
 from keystone_tpu.workflow.optimizers import DefaultOptimizer, clear_memo
 from keystone_tpu.workflow.pipeline import Pipeline
@@ -30,17 +29,7 @@ CHAIN = (Convolver, SymmetricRectifier, Pooler)
 
 
 def _all_ops(graph):
-    """Every operator of ``graph``, a fused chain's members included."""
-    out = []
-
-    def visit(op):
-        out.append(op)
-        for inner, _ in getattr(op, "steps", ()):
-            visit(inner)
-
-    for node in graph.nodes:
-        visit(graph.get_operator(node))
-    return out
+    return [graph.get_operator(node) for node in graph.nodes]
 
 
 def _optimized(pipeline):
@@ -197,7 +186,6 @@ def test_pipelines_without_a_convolver_keep_their_graphs(which, monkeypatch):
 def _fit(n_train=96, n_test=40):
     PipelineEnv.get_or_create().reset()
     reset_dispatchers()
-    fusion._FUSED_JIT_CACHE.clear()
     clear_memo()
     train = synthetic_cifar(n_train, seed=1)
     test = synthetic_cifar(n_test, seed=2)

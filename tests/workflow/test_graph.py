@@ -164,32 +164,6 @@ def test_connect_graph_bad_splice_fails():
         g1.connect_graph(g2, {snk1: SourceId(99)})
 
 
-def test_replace_nodes():
-    # source -> a -> b -> sink; replace b with subgraph (x -> y)
-    g = Graph()
-    g, s = g.add_source()
-    a, b = Op("a"), Op("b")
-    g, na = g.add_node(a, [s])
-    g, nb = g.add_node(b, [na])
-    g, snk = g.add_sink(nb)
-
-    rep = Graph()
-    rep, rs = rep.add_source()
-    x, y = Op("x"), Op("y")
-    rep, nx = rep.add_node(x, [rs])
-    rep, ny = rep.add_node(y, [nx])
-    rep, rsnk = rep.add_sink(ny)
-
-    out = g.replace_nodes(frozenset([nb]), rep, {rs: na}, {nb: rsnk})
-    labels = sorted(out.get_operator(n).label for n in out.nodes)
-    assert labels == ["a", "x", "y"]
-    final = out.get_sink_dependency(snk)
-    assert out.get_operator(final) is y
-    (x_node,) = [n for n in out.nodes if out.get_operator(n) is x]
-    (a_node,) = [n for n in out.nodes if out.get_operator(n) is a]
-    assert out.get_dependencies(x_node) == (a_node,)
-
-
 def test_to_dot_contains_structure():
     g, s, na, nb, nc, snk = build_simple()
     dot = g.to_dot()
