@@ -12,7 +12,12 @@ everything that determines the traced program:
 * **operator identities** — fully-qualified class names;
 * **fitted parameters** — every attribute of every operator, canonicalized
   by content: scalars/strings verbatim, numpy and jax arrays as
-  shape+dtype+sha256-of-bytes, containers recursively, nested operators
+  shape+dtype+sha256-of-bytes (the digest is
+  ``utils/params.content_digest``'s, shared with the optimizer's
+  ``structural_key``: a read-only parameter array is hashed once, and every
+  later fingerprint that meets the same array object is answered from
+  memory; a writeable one is hashed each time), containers recursively,
+  nested operators
   (``ConvRectifyPool`` holds its three nodes as state) recursively, plain
   Python functions as code+constants+closure digests.
 
@@ -37,6 +42,8 @@ from __future__ import annotations
 import hashlib
 import types
 from typing import Any, Dict, Tuple
+
+from ..utils.params import content_digest
 
 FORMAT_VERSION = 1
 
@@ -91,10 +98,7 @@ def _feed(h, value: Any, path: str) -> None:
             # (raises FingerprintError if they have no stable form)
             _feed(h, value.tolist(), path)
         else:
-            _feed_bytes(
-                h, b"d",
-                hashlib.sha256(np.ascontiguousarray(value).tobytes()).digest(),
-            )
+            _feed_bytes(h, b"d", content_digest(value))
     elif isinstance(value, (list, tuple)):
         h.update(b"L(" if isinstance(value, list) else b"T(")
         for i, item in enumerate(value):

@@ -293,12 +293,14 @@ class BlockLinearMapper(Transformer):
             if feature_means is None
             else [as_param(m) for m in feature_means]
         )
+        # the program's literals, made here and shared with nobody:
+        # read-only in place, like every parameter (utils/params.py)
         self._W = np.concatenate(self.xs, axis=0)
-        self._mean = (
-            None
-            if self.feature_means is None
-            else np.concatenate(self.feature_means, axis=0)
-        )
+        self._W.flags.writeable = False
+        self._mean = None
+        if self.feature_means is not None:
+            self._mean = np.concatenate(self.feature_means, axis=0)
+            self._mean.flags.writeable = False
 
     def trace_batch(self, X):
         with jax.named_scope("ks.apply.scores"):

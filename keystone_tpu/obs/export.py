@@ -47,6 +47,8 @@ def _span_args(sp) -> dict:
             ("sync_ms", round(sp.sync_seconds * 1e3, 3) or None),
             ("output_bytes", sp.output_bytes),
             ("compiles", sp.compiles or None),
+            ("digest_bytes", sp.digest_bytes or None),
+            ("digest_hits", sp.digest_hits or None),
         )
         if v is not None
     }

@@ -45,6 +45,10 @@ class Span:
     output_bytes: Optional[int] = None
     #: XLA backend compiles that happened inside this span
     compiles: int = 0
+    #: bytes ``utils/params.content_digest`` hashed inside this span, and
+    #: the digests it answered from memory there
+    digest_bytes: int = 0
+    digest_hits: int = 0
     instant: bool = False
     #: free-form attributes; the concurrent executor adds
     #: ``queue_wait_seconds`` (ready-to-started scheduler latency) and

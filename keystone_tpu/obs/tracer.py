@@ -88,6 +88,23 @@ def _install_compile_listener() -> None:
         logger.debug("jax compile-event listener unavailable", exc_info=True)
 
 
+# -- content-digest counting ------------------------------------------------
+
+#: process-wide counts of ``utils/params.content_digest``'s work: the bytes
+#: it hashed and the digests it answered from memory. Like the compile
+#: count, every span carries the delta between its entry and its exit.
+_digest_lock = threading.Lock()
+_digest_bytes = 0
+_digest_hits = 0
+
+
+def count_digest(nbytes: int = 0, hits: int = 0) -> None:
+    global _digest_bytes, _digest_hits
+    with _digest_lock:
+        _digest_bytes += nbytes
+        _digest_hits += hits
+
+
 # -- the tracer -------------------------------------------------------------
 
 
@@ -187,6 +204,7 @@ class Tracer:
         # the count at entry, negated: _close adds the count at exit, which
         # leaves the compile REQUESTS inside the span (cache hits included)
         sp.compiles = -_compile_count()
+        sp.digest_bytes, sp.digest_hits = -_digest_bytes, -_digest_hits
         stack.append(sp)
         return sp
 
@@ -203,6 +221,8 @@ class Tracer:
                     sp.output_bytes = cheap_nbytes(target)
         sp.end = time.perf_counter()
         sp.compiles += _compile_count()
+        sp.digest_bytes += _digest_bytes
+        sp.digest_hits += _digest_hits
         self._keep(sp)
 
     @contextlib.contextmanager

@@ -11,6 +11,7 @@ from __future__ import annotations
 from typing import Any, Callable, List, Sequence
 
 from ..data.dataset import Dataset
+from ..utils.params import content_digest
 from .expressions import (
     DatasetExpression,
     DatumExpression,
@@ -45,12 +46,10 @@ def _canon(v):
     if isinstance(v, np.generic):
         return v.item()
     if isinstance(v, np.ndarray):
-        import hashlib
-
-        return (
-            "ndarray", v.shape, str(v.dtype),
-            hashlib.sha1(np.ascontiguousarray(v).tobytes()).hexdigest(),
-        )
+        if v.dtype.hasobject:
+            # the bytes of an object array are pointers, not content
+            return ("ndarray", v.shape, str(v.dtype), _canon(v.tolist()))
+        return ("ndarray", v.shape, str(v.dtype), content_digest(v))
     if isinstance(v, (list, tuple)):
         return (type(v).__name__, tuple(_canon(x) for x in v))
     if isinstance(v, dict):
@@ -69,8 +68,11 @@ def structural_key(op: "Operator"):
 
     Returns ``(type, canonical-params)`` when every attribute of the
     operator canonicalizes (scalars, strings, tuples, numpy arrays by
-    content digest — ``utils/params.py`` keeps fitted parameters as numpy,
-    so fitted transformers canonicalize too). Operators defining their own
+    shape, dtype and ``utils/params.content_digest`` — the sha256 that
+    ``compile/fingerprint`` feeds too, hashed once for a read-only array
+    and remembered with it; ``utils/params.py`` keeps fitted parameters as
+    read-only numpy, so fitted transformers canonicalize too, and a second
+    key of the same operator costs no hashing). Operators defining their own
     ``__eq__`` (Dataset/Datum leaves) and operators holding closures or
     arbitrary objects fall back to the operator instance itself, i.e.
     object identity — conservative, never merges wrongly."""

@@ -179,8 +179,9 @@ class EquivalentNodeMergeRule(Rule):
         from .operators import structural_key
 
         # Merging only rewires dependencies — operator keys never change
-        # within one apply(), so memoize the (sha1-of-params) key per
-        # operator instance across fixpoint passes.
+        # within one apply(), so memoize the key per operator instance
+        # across fixpoint passes (a parameter array that is still
+        # writeable is hashed by every structural_key call).
         key_cache: Dict[int, object] = {}
 
         def op_key(op):

@@ -125,3 +125,52 @@ def test_equal_estimators_fit_once_after_merge():
     np.testing.assert_allclose(got[1], X * 2.0)
     # exactly one of the two estimator objects fit, exactly once
     assert e1.num_fits + e2.num_fits == 1
+
+
+def _cosine_pair(flip=None):
+    """Two separately built ``CosineRandomFeatures`` over one input; with
+    ``flip``, the second's ``W`` differs in that one element."""
+    from keystone_tpu.nodes.stats import CosineRandomFeatures
+
+    rng = np.random.default_rng(3)
+    W = rng.standard_normal((32, 8)).astype(np.float32)
+    b = rng.uniform(0, 6.28, 32).astype(np.float32)
+    W2 = W.copy()
+    if flip is not None:
+        W2[flip] += 1e-3
+    return Pipeline.gather(
+        [CosineRandomFeatures(W, b), CosineRandomFeatures(W2, b.copy())]
+    )
+
+
+def test_equal_cosine_features_merge_and_the_second_rule_pass_hashes_nothing():
+    from keystone_tpu.obs.tracer import Tracer
+
+    pipe = _cosine_pair()
+    before = _n_nodes(pipe.graph)
+    tracer = Tracer(sync=False)
+    with tracer.span("first") as first:
+        graph, _ = EquivalentNodeMergeRule().apply(pipe.graph, {})
+    with tracer.span("second") as second:
+        EquivalentNodeMergeRule().apply(pipe.graph, {})
+    assert _n_nodes(graph) == before - 1
+    param_bytes = 2 * (32 * 8 + 32) * 4
+    assert (first.digest_bytes, first.digest_hits) == (param_bytes, 0)
+    # a fresh key_cache, the same (read-only) arrays: answered from memory
+    assert (second.digest_bytes, second.digest_hits) == (0, 4)
+
+
+def test_cosine_features_differing_in_one_element_do_not_merge():
+    pipe = _cosine_pair(flip=(31, 7))
+    before = _n_nodes(pipe.graph)
+    graph, _ = EquivalentNodeMergeRule().apply(pipe.graph, {})
+    assert _n_nodes(graph) == before
+
+
+def test_structural_key_takes_object_arrays_by_their_elements():
+    class _Meta(Transformer):
+        def __init__(self, meta):
+            self.meta = np.array(meta, dtype=object)
+
+    assert structural_key(_Meta(["a", 1.5])) == structural_key(_Meta(["a", 1.5]))
+    assert structural_key(_Meta(["a", 1.5])) != structural_key(_Meta(["b", 1.5]))

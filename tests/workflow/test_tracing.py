@@ -633,6 +633,51 @@ def test_the_kernels_lower_under_their_named_scopes(timit_job, text, scope):
     assert "_bcd_scan_impl" in timit_job["solver"]
 
 
+def test_a_timit_job_hashes_each_parameter_array_once():
+    """The counter that says the shared digest engaged: ``digest_bytes``
+    over a job's ``plan.build`` / ``plan.rule`` / ``plan.segments`` spans
+    is the job's distinct parameter bytes — each ``W`` and ``b`` hashed
+    once, where the three sites hashed each four times — and the other
+    three looks at each array are ``digest_hits``. The next job's arrays
+    are new objects: fresh content until hashed, so hashed once again."""
+    from keystone_tpu.pipelines.timit import TimitConfig, run, synthetic_timit
+    from keystone_tpu.workflow.env import PipelineEnv
+
+    branches, features, dim = 4, 64, 440
+    conf = TimitConfig(
+        num_cosines=branches, cosine_features=features, num_classes=5,
+        num_epochs=2,
+    )
+    train = synthetic_timit(256, 5, seed=1)
+    test = synthetic_timit(64, 5, seed=2)
+    w_bytes, b_bytes = features * dim * 4, features * 4
+    tracer = _installed()
+    for job in range(2):
+        PipelineEnv.get_or_create().reset()
+        seen = len(tracer.spans())
+        run(train, test, conf)
+        spans = tracer.spans()[seen:]
+        by_site = {
+            site: (
+                sum(sp.digest_bytes for sp in spans if sp.name == site),
+                sum(sp.digest_hits for sp in spans if sp.name == site),
+            )
+            for site in ("plan.build", "plan.rule", "plan.segments")
+        }
+        # built and first keyed in plan.build; the optimizer's rule and
+        # the two segments' fingerprints then meet the same array objects
+        assert by_site == {
+            "plan.build": (branches * (w_bytes + b_bytes), 0),
+            "plan.rule": (0, branches * 2),
+            "plan.segments": (0, 2 * branches * 2),
+        }, (job, by_site)
+        (whole,) = [sp for sp in spans if sp.name == "job"]
+        assert whole.digest_bytes == branches * (w_bytes + b_bytes)
+        # 12 of the hits are on the four W: 4 in the rule, 8 in the segments
+        assert whole.digest_hits == 3 * branches * 2
+    PipelineEnv.get_or_create().reset()
+
+
 @pytest.mark.parametrize("num_iter,keeps", [(3, True), (1, False)])
 def test_block_ls_solve_span_says_what_the_solver_program_keeps(
     num_iter, keeps

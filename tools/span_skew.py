@@ -8,7 +8,8 @@ It drives one traced window of a cell exactly as ``benchmark/run.py
 --trace 1`` does, but keeps the trace and loads the xplane itself (the
 harness keeps ``bench:`` annotations only). Printed, as one JSON line: the
 offset and its per-step scatter, the widest gap between an aligned span's
-edges and its annotation's, the idle split, and — for the readers that
+edges and its annotation's, the idle split, what the content digest hashed
+and answered from memory a unit by span name, and — for the readers that
 match device operations by name — whether the operations' names or stats
 carry the ``ks.*`` named scopes. ``--cpu`` rehearses the host side on the
 CPU with the tests' tiny benchmark (no device plane: no idle split).
@@ -272,6 +273,21 @@ def main(argv=None) -> int:
         "spans": len(spans), "ks_annotations": len(ks),
         "spans_per_unit": len(spans) / max(run.facts.get("units", 1), 1),
         "names": sorted({sp.name for sp in spans}),
+    }
+    # what utils/params.content_digest did inside each kind of span, a unit
+    # (a nested span counts its children's too: plan.optimize holds the
+    # plan.rule spans, job everything)
+    units = max(run.facts.get("units", 1), 1)
+    digests: dict = {}
+    for sp in spans:
+        if sp.digest_bytes or sp.digest_hits:
+            row = digests.setdefault(sp.name, [0, 0, 0.0])
+            row[0] += sp.digest_bytes
+            row[1] += sp.digest_hits
+            row[2] += sp.seconds
+    out["digests_per_unit_by_span"] = {
+        name: {"bytes": b / units, "hits": h / units, "seconds": s / units}
+        for name, (b, h, s) in digests.items()
     }
     roots = [(sp.name, sp.start, sp.end) for sp in spans if sp.name == "job"]
     offset = span_idle.offset_of(roots, anchors) if anchors else None
