@@ -3,7 +3,7 @@
 One process drives the main path — fit a pipeline, then serve it — once,
 through the entry points a user would call, at the full width of
 MnistRandomFFT (numFFTs=4, blockSize=2048, λ=1000; 60,000 train / 10,000
-test rows of the seeded synthetic task, generated in HBM). Four legs:
+test rows of the seeded synthetic task, generated in HBM). Five legs:
 
 * ``fit``    — CLI dispatch and backend selection through
   ``python -m keystone_tpu MnistRandomFFT --backend tpu``'s ``main``, then
@@ -17,6 +17,13 @@ test rows of the seeded synthetic task, generated in HBM). Four legs:
 * ``conv_chain`` — the fused conv → rectify → pool Pallas kernel at
   ``cifar_patch10k``'s 10,000 filters, compiled, against the XLA lowering
   of the three bodies it replaces, and that the front door picked it.
+
+* ``fisher`` — dense SIFT → PCA → Fisher vector at ``voc_fv256``'s 80
+  dimensions and 256 centres on a few 500 × 375 images, the PCA and the
+  codebook fitted from sampled descriptors as ``voc_sift_fisher.run`` fits
+  them, against the plain float32 ``highest`` reference of the benchmark
+  (``benchmark/configs/voc_fv256_reference.py``): descriptors, basis,
+  codebook and features each inside a stated gap.
 
 It refuses anything but a TPU, fails if any catch-and-degrade site fired
 on its path, and exits 0 only if every leg passed. Stdout is two lines of
@@ -456,6 +463,110 @@ def conv_chain_leg(*, filters, images, side=32, interpret=False):
     return report
 
 
+#: voc_fv256's widths (benchmark/configs/voc_fv256.json) on as many images
+#: as make 16,000 sampled descriptors for the PCA and for the codebook
+FISHER_SHAPE = dict(images=8, x=500, y=375, dims=80, centres=256)
+
+#: what ``fisher_leg`` allows, fixed BEFORE the chip run: descriptors are
+#: whole numbers after a floor (an off-by-one where two summation orders
+#: straddle one, on a thousandth of them at most); the basis is a float32
+#: eigh against a float64 one; the features carry the basis and the
+#: codebook through posteriors whose products run at three bf16 passes
+#: here and at six in the reference
+FISHER_GAPS = dict(descriptor_share=1e-3, basis=5e-3, codebook=5e-2, features=5e-2)
+
+
+def fisher_leg(*, images, x, y, dims, centres, per_image=2000):
+    """Dense SIFT → sampled columns → PCA → k-means++ / EM → Fisher vectors
+    → normalisations through the program's nodes, each as ``run`` uses it,
+    against the benchmark's plain reference on the same seeded images (R8:
+    an estimator a leg — ``pca.py``, ``kmeans.py``, ``gmm.py`` and
+    ``sift.py`` had only CPU tests, where a float32 product is float32)."""
+    import jax
+    import numpy as np
+
+    from benchmark import harness
+    from keystone_tpu.data.dataset import Dataset
+    from keystone_tpu.nodes.images import (
+        GMMFisherVectorEstimator,
+        GrayScaler,
+        PixelScaler,
+        SIFTExtractor,
+    )
+    from keystone_tpu.nodes.learning import ColumnPCAEstimator
+    from keystone_tpu.nodes.stats import (
+        ColumnSampler,
+        NormalizeRows,
+        SignedHellingerMapper,
+    )
+    from keystone_tpu.nodes.util import MatrixVectorizer
+
+    here = os.path.dirname(os.path.abspath(__file__))
+    configs = os.path.join(here, "benchmark", "configs")
+    ref = harness.load_module(os.path.join(configs, "voc_fv256_reference.py"))
+    cfg = harness.load_json(os.path.join(configs, "voc_fv256.json"))
+    cfg.update(
+        image_x=x, image_y=y, desc_dim=dims, vocab_size=centres,
+        n_train=images, num_pca_samples=images * per_image,
+        num_gmm_samples=images * per_image, reference_slice=min(4, images),
+        reference_rows=images,
+    )
+    X, _ = ref.make_rows(cfg, cfg["train_seed"], images)
+
+    def jit(node):
+        return jax.jit(node.trace_batch)
+
+    t0 = time.perf_counter()
+    gray = jit(GrayScaler())(jit(PixelScaler())(X))
+    D = jit(SIFTExtractor())(gray)
+    seed = cfg["sample_seed"]
+    pca = ColumnPCAEstimator(dims).fit(
+        ColumnSampler(per_image, seed=seed).apply_batch(Dataset.of(D))
+    )
+    P = jit(pca)(D)
+    fv = GMMFisherVectorEstimator(
+        centres, max_iterations=20, min_cluster_size=1
+    ).fit(ColumnSampler(per_image, seed=seed + 1).apply_batch(Dataset.of(P)))
+    F = jit(fv)(P)
+    for node in (MatrixVectorizer(), NormalizeRows(), SignedHellingerMapper(),
+                 NormalizeRows()):
+        F = jit(node)(F)
+    F = np.asarray(jax.block_until_ready(F))
+    seconds = time.perf_counter() - t0
+
+    want_D = ref.sift(cfg, X)
+    book = ref.learn_codebook(cfg, X)
+    want_F = np.asarray(ref.fisher_vectors(cfg, book, want_D, "highest"))
+    got_D = np.asarray(D).transpose(0, 2, 1)
+    want_D = np.asarray(want_D)
+
+    def rel(a, b):
+        a, b = np.asarray(a, np.float64), np.asarray(b, np.float64)
+        return float(np.linalg.norm(a - b) / np.linalg.norm(b))
+
+    report = {
+        "shape": {"images": images, "x": x, "y": y, "dims": dims,
+                  "centres": centres, "descriptors": int(got_D.shape[1])},
+        "finite": bool(np.isfinite(F).all()),
+        "descriptor_max_gap": float(np.abs(got_D - want_D).max()),
+        "descriptor_share": float(np.mean(got_D != want_D)),
+        "basis": rel(pca.pca_mat, book["basis"]),
+        "codebook": max(
+            rel(fv.gmm.means.T, book["means"]),
+            rel(fv.gmm.variances.T, book["variances"]),
+            rel(fv.gmm.weights, book["weights"]),
+        ),
+        "features": rel(F, want_F),
+        "seconds_program": round(seconds, 3),
+    }
+    report["ok"] = bool(
+        report["finite"] and F.shape == want_F.shape
+        and report["descriptor_max_gap"] <= 1.0
+        and all(report[k] <= v for k, v in FISHER_GAPS.items())
+    )
+    return report
+
+
 def _fetch_scalar(x) -> None:
     """Read one element back to the host: the device stream has really
     completed when it arrives."""
@@ -558,6 +669,7 @@ def main() -> int:
     ))
     leg("kernel", lambda: kernel_leg(**KERNEL_SHAPE))
     leg("conv_chain", lambda: conv_chain_leg(**CONV_CHAIN_SHAPE))
+    leg("fisher", lambda: fisher_leg(**FISHER_SHAPE))
     leg("sync", lambda: {"ok": True, **sync_check(size=8192, steps=24)})
 
     fallbacks = fallbacks_fired()

@@ -5,7 +5,10 @@ This is the compilation-unit plan the ROADMAP's whole-DAG native
 compilation item needs: each segment is a connected sub-DAG every node of
 which could lower into ONE fused XLA program, and each barrier is a point
 where data must materialize — a Cacher (the result must hit the state
-table / HBM pin), an out-of-core scan seam (chunked leaves produce data
+table / HBM pin; one whose value the device cannot hold is ``declined``
+and is a member, an identity inside the program: the value is computed
+again where it is read, as an RDD's dropped partition is), an out-of-core
+scan seam (chunked leaves produce data
 chunk-at-a-time), a host-side node (opaque / callback / stateful), an
 estimator boundary (fit-time solve), a saveable prefix (its result must
 hit the state table), a ``no_fuse`` node (dataset-sized operands must not
@@ -117,6 +120,7 @@ def plan_segments(
     cost_estimator: Any = None,
     materialized: Any = (),
     annotations: Any = (),
+    declined: Any = (),
 ) -> Tuple[List[Segment], Dict[Any, str]]:
     """Partition ``graph`` into maximal traceable segments.
 
@@ -128,6 +132,8 @@ def plan_segments(
     state — a segment through one would compute it again from its inputs.
     ``annotations`` are the optimizer's saveable prefixes: such a node's
     result must hit the state table, so it bounds segments too.
+    ``declined`` are Cachers whose value the caller cannot hold
+    (``compile/segment.py:unheld_caches``): no barrier, a member.
     """
     from ..workflow import analysis
     from ..workflow.graph import NodeId
@@ -161,6 +167,8 @@ def plan_segments(
                 op, verdicts.get(n, lattice.OPAQUE),
                 is_chunked_leaf=leaf_is_chunked(op),
             )
+            if reason == BARRIER_CACHER and n in declined:
+                reason = None
             readers = consumers.get(n)
             if (
                 reason == BARRIER_GATHER

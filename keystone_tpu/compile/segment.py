@@ -90,22 +90,34 @@ def lower_segment(graph: Any, segment: Any) -> Tuple[Callable, List[Step], Tuple
         for n in members
     ]
     out_slots = tuple(pos[o] for o in segment.outputs)
+    n_inputs = len(inputs)
 
     def fn(*xs):
-        values = _trace_steps(steps, list(xs))
+        # a segment with a ``row_keyed`` member is handed, after its
+        # inputs, the data-set index of its first row (SegmentBinding._run)
+        row0 = xs[n_inputs] if len(xs) > n_inputs else None
+        values = _trace_steps(steps, list(xs[:n_inputs]), row0=row0)
         return tuple(values[s] for s in out_slots)
 
     return fn, steps, out_slots
 
 
+def _row_keyed(steps: List[Step]) -> bool:
+    """Whether a member's ``trace_batch`` takes the rows' data-set indices
+    (``row_keyed``, e.g. a sampler whose draw is keyed on the row)."""
+    return any(getattr(op, "row_keyed", False) for op, _ in steps)
+
+
 def _trace_steps(
     steps: List[Step], values: List[Any],
     made: Optional[Callable[[Any, List[Any], Any], None]] = None,
+    row0: Any = None,
 ) -> List[Any]:
     """``values`` (the segment's inputs) with every step's traced value
     appended — the one composition of ``trace_batch`` bodies. A gather
     join is a tuple of its branches; ``made(op, args, out)`` sees every
-    other member's output."""
+    other member's output. A ``row_keyed`` member is told its rows'
+    indices: ``row0`` on, or from 0 where the rows are a whole data set."""
     from ..workflow.operators import GatherTransformerOperator
 
     for op, slots in steps:
@@ -113,7 +125,13 @@ def _trace_steps(
         if isinstance(op, GatherTransformerOperator):
             values.append(tuple(args))
         else:
-            values.append(op.trace_batch(*args))
+            if row0 is not None and getattr(op, "row_keyed", False):
+                import jax.numpy as jnp
+
+                rows = row0 + jnp.arange(args[0].shape[0], dtype=jnp.int32)
+                values.append(op.trace_batch(*args, rows=rows))
+            else:
+                values.append(op.trace_batch(*args))
             if made is not None:
                 made(op, args, values[-1])
     return values
@@ -411,6 +429,17 @@ def reset_dispatchers() -> None:
 # ---------------------------------------------------------------------------
 
 
+def _nbytes(tree: Any) -> int:
+    """Bytes of the arrays (or shape structs) of ``tree``."""
+    import jax
+    import numpy as np
+
+    return sum(
+        int(np.prod(v.shape)) * np.dtype(v.dtype).itemsize
+        for v in jax.tree_util.tree_leaves(tree)
+    )
+
+
 def _item_bytes(
     sigs: Tuple[Signature, ...], steps: List[Step],
     out_slots: Tuple[int, ...],
@@ -443,15 +472,11 @@ def _item_bytes(
     try:
         specs = [jax.ShapeDtypeStruct(s, np.dtype(d)) for s, d in sigs]
         made, outs = jax.eval_shape(members, *specs)
-        leaves = jax.tree_util.tree_leaves(made)
         row_wise = all(
             o.shape and o.shape[0] == rows
             for o in jax.tree_util.tree_leaves(outs)
         )
-        total = sum(
-            int(np.prod(v.shape)) * np.dtype(v.dtype).itemsize for v in leaves
-        )
-        return -(-total // rows) + sum(scratch) if row_wise else 0
+        return -(-_nbytes(made) // rows) + sum(scratch) if row_wise else 0
     except Exception:
         logger.debug("segment: no per-row size estimate", exc_info=True)
         return 0
@@ -488,6 +513,85 @@ def _device_memory() -> Optional[Tuple[int, int]]:
     return worst
 
 
+def unheld_caches(
+    graph: Any, verdicts: Dict[Any, str], held: Dict[Any, Any]
+) -> Dict[Any, int]:
+    """The ``Cacher`` nodes of ``graph`` whose value the device cannot
+    hold, each with the bytes it was asked to keep. Upstream a ``Cacher`` is
+    ``RDD.cache()``, which drops what does not fit and computes it again;
+    here such a request is declined when the pull is planned, from what the
+    plan can observe: the value's shape (``jax.eval_shape`` through the
+    traceable members above it, from the arrays the executor holds — no
+    operation runs) against what the device has free. A cache is kept where
+    it takes at most half of that — the other half is for the programs that
+    read it, as a row slice's is (:meth:`SegmentBinding.row_plan`). Empty
+    where the backend reports no memory (the CPU), where the graph has no
+    Cacher, and for every cache whose size cannot be told."""
+    from ..check.segments import BARRIER_CACHER, barrier_reason
+    from ..workflow.graph import NodeId
+    from ..workflow.operators import DatasetOperator
+
+    def reason(n: Any):
+        return barrier_reason(
+            graph.get_operator(n), verdicts.get(n, "opaque")
+        )
+
+    cachers = [
+        n for n in graph.nodes
+        if n not in held and reason(n) == BARRIER_CACHER
+    ]
+    memory = _device_memory() if cachers else None
+    if memory is None:
+        return {}
+    import jax
+
+    def struct_of(dataset: Any):
+        payload = getattr(dataset, "payload", None)
+        if not getattr(dataset, "is_batched", False) or not hasattr(
+            payload, "shape"
+        ):
+            return None
+        return jax.ShapeDtypeStruct(payload.shape, payload.dtype)
+
+    memo: Dict[Any, Any] = {}
+
+    def abstract(n: Any):
+        if n not in memo:
+            memo[n] = None  # a cycle-free graph; unknown until told
+            memo[n] = _abstract(n)
+        return memo[n]
+
+    def _abstract(n: Any):
+        if not isinstance(n, NodeId) or n not in graph.operators:
+            return None
+        if n in held:
+            return struct_of(held[n].get())
+        op = graph.get_operator(n)
+        if isinstance(op, DatasetOperator):
+            return struct_of(op.dataset)
+        deps = [abstract(d) for d in graph.get_dependencies(n)]
+        if not deps or any(d is None for d in deps):
+            return None
+        why = reason(n)
+        if why == BARRIER_CACHER:
+            return deps[0]
+        if why is not None:
+            return None
+        try:
+            return jax.eval_shape(op.trace_batch, *deps)
+        except Exception:
+            logger.debug("segment: no size for %s", op.label, exc_info=True)
+            return None
+
+    free, _limit = memory
+    declined: Dict[Any, int] = {}
+    for n in cachers:
+        value = abstract(n)
+        if value is not None and _nbytes(value) > free // 2:
+            declined[n] = _nbytes(value)
+    return declined
+
+
 @functools.lru_cache(maxsize=None)
 def _row_slice_jit() -> Callable:
     import jax
@@ -513,6 +617,15 @@ def _row_put_jit() -> Callable:
         ),
         donate_argnums=() if jax.default_backend() == "cpu" else (0,),
     )
+
+
+def _row0(start: int):
+    """The data-set index of a dispatch's first row, as the program of a
+    segment with a ``row_keyed`` member takes it: an int32 scalar argument,
+    so that every slice runs the one program."""
+    import numpy as np
+
+    return np.asarray(start, np.int32)
 
 
 def _row_slice(a: Any, start: int, rows: int):
@@ -573,6 +686,9 @@ class SegmentBinding:
             SegmentDispatcher(fn, "", None, label=label, n_nodes=len(steps))
         )
         self._demoted = False
+        #: bytes of the caches among the members that the plan declined
+        #: (:func:`unheld_caches`); :func:`bind_segment` says
+        self.cache_declined_bytes = 0
 
     def __len__(self) -> int:
         return len(self.steps)
@@ -620,9 +736,10 @@ class SegmentBinding:
         from ..data.pipeline_scan import ChunkPadder
 
         if chunked:
-            if len(self.out_slots) != 1:
+            if len(self.out_slots) != 1 or _row_keyed(self.steps):
                 # a multi-output chunked segment would rescan the source
-                # once per output — node semantics handle it exactly
+                # once per output, and a row-keyed member counts its rows
+                # along its own scan — node semantics handle both exactly
                 return self._fallback(datasets), "fallback"
             disp = self._dispatcher()
             if len(datasets) == 1:
@@ -641,8 +758,13 @@ class SegmentBinding:
             t0 = time.perf_counter()
             arrays = [ds.to_array() for ds in datasets]
             rows, slice_rows = self.row_plan(disp, arrays)
+            keyed = _row_keyed(self.steps)
             if slice_rows < rows:
-                raw = self._dispatch_row_slices(disp, arrays, rows, slice_rows)
+                raw = self._dispatch_row_slices(
+                    disp, arrays, rows, slice_rows, keyed
+                )
+            elif keyed:
+                raw = disp(*arrays, _row0(0))
             else:
                 raw = disp(*arrays)
             if rows:
@@ -650,6 +772,8 @@ class SegmentBinding:
                     rows=rows, row_slices=-(-rows // slice_rows),
                     slice_rows=slice_rows,
                 )
+                if self.cache_declined_bytes:
+                    facts["cache_declined_bytes"] = self.cache_declined_bytes
                 shapes = [(slice_rows,) + a.shape[1:] for a in arrays]
                 if _runs_conv_kernel(self.steps, shapes):
                     facts["conv_fused_rows"] = rows
@@ -713,13 +837,14 @@ class SegmentBinding:
     @staticmethod
     def _dispatch_row_slices(
         disp: "SegmentDispatcher", arrays: List[Any], rows: int,
-        slice_rows: int,
+        slice_rows: int, keyed: bool = False,
     ) -> Tuple[Any, ...]:
         """The segment over ``rows`` rows, ``slice_rows`` at a time through
         the one program of that shape, each slice's outputs written into
         their place in one buffer an output; the last slice is padded with
         its first row (as ``ChunkPadder`` pads) and the padding cut from
-        its outputs. Every row goes through once."""
+        its outputs. Every row goes through once. ``keyed``: the program
+        takes the index of the slice's first row after its inputs."""
         import jax.numpy as jnp
 
         from ..data.pipeline_scan import _pad_rows
@@ -731,7 +856,7 @@ class SegmentBinding:
             xs = [_row_slice(a, start, take) for a in arrays]
             if take < slice_rows:
                 xs = [_pad_rows(x, take, slice_rows) for x in xs]
-            part = disp(*xs)
+            part = disp(*xs, _row0(start)) if keyed else disp(*xs)
             if outs is None:
                 # one buffer an output, written slice by slice: joining the
                 # slices at the end holds them twice, and XLA's many-operand
@@ -775,8 +900,12 @@ class SegmentBinding:
             logger.debug("segment: could not record demotion", exc_info=True)
 
 
-def bind_segment(graph: Any, segment: Any) -> Optional[SegmentBinding]:
-    """Lower ``segment`` into a dispatchable binding, or None when it is
+def bind_segment(
+    graph: Any, segment: Any, declined: Optional[Dict[Any, int]] = None
+) -> Optional[SegmentBinding]:
+    """Lower ``segment`` into a dispatchable binding (``declined``: the
+    caches the plan could not hold, :func:`unheld_caches` — the binding
+    reports the bytes of those among its members), or None when it is
     not worth (or not safe to) segment-dispatch:
 
     * empty, or a singleton — a single node gains nothing over its node
@@ -851,7 +980,7 @@ def bind_segment(graph: Any, segment: Any) -> Optional[SegmentBinding]:
     label = "+".join(op.label for op in ops)
     if len(label) > 96:
         label = label[:93] + "..."
-    return SegmentBinding(
+    binding = SegmentBinding(
         index=segment.index,
         inputs=list(segment.inputs),
         outputs=list(segment.outputs),
@@ -865,6 +994,10 @@ def bind_segment(graph: Any, segment: Any) -> Optional[SegmentBinding]:
             op.label for op in ops if getattr(op, "batch_coupled", False)
         ],
     )
+    binding.cache_declined_bytes = sum(
+        (declined or {}).get(n, 0) for n in members
+    )
+    return binding
 
 
 # ---------------------------------------------------------------------------

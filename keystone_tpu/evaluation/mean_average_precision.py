@@ -12,6 +12,7 @@ from typing import Any, Sequence
 
 import numpy as np
 
+from ..obs.tracer import span
 from .base import Evaluator, resolve
 
 
@@ -22,6 +23,10 @@ class MeanAveragePrecisionEvaluator(Evaluator):
     def evaluate(self, predictions: Any, actuals: Any) -> np.ndarray:
         """predictions: (n, num_classes) scores; actuals: per-item label sets.
         Returns per-class AP vector (mean of it = MAP)."""
+        with span("eval.map", classes=self.num_classes):
+            return self._evaluate(predictions, actuals)
+
+    def _evaluate(self, predictions: Any, actuals: Any) -> np.ndarray:
         scores = np.asarray(resolve(predictions), dtype=np.float64)
         actual_sets = [np.atleast_1d(np.asarray(a)) for a in actuals]
         n = scores.shape[0]

@@ -64,14 +64,28 @@ class FisherVector(Transformer):
     def __init__(self, gmm: GaussianMixtureModel):
         self.gmm = gmm
 
+    def row_scratch_bytes(self, shape) -> int:
+        """What an image holds besides the (d, 2k) output while it is
+        encoded — segment dispatch prices a row by it
+        (``compile/segment.py:_item_bytes``): the posteriors ``q`` (m, k),
+        and ``X*X`` with the transposed descriptors (m, d). At 73,505
+        descriptors, 80 dimensions and 256 centres: 75 + 2 × 23.5 MB. (With
+        the members' outputs a row of the descriptor → Fisher-vector chain
+        is then priced at 258 MB; the TPU compiler's own figure is 175–179 MB
+        of temporaries, ``memory_analysis()`` of the program for a described
+        v5e at slices of 4 and 8.)"""
+        _, d, m = shape
+        return 4 * m * (self.gmm.k + 2 * d)
+
     def trace_batch(self, X):
-        return _fisher_vector(
-            X.astype(jnp.float32),
-            self.gmm.means.astype(jnp.float32),
-            self.gmm.variances.astype(jnp.float32),
-            self.gmm.weights.astype(jnp.float32),
-            self.gmm.weight_threshold,
-        )
+        with jax.named_scope("ks.featurize.fisher"):
+            return _fisher_vector(
+                X.astype(jnp.float32),
+                self.gmm.means.astype(jnp.float32),
+                self.gmm.variances.astype(jnp.float32),
+                self.gmm.weights.astype(jnp.float32),
+                self.gmm.weight_threshold,
+            )
 
     def apply(self, x):
         return self.trace_batch(jnp.asarray(x)[None])[0]
@@ -98,9 +112,11 @@ class GMMFisherVectorEstimator(Estimator):
             cols = jnp.asarray(
                 np.concatenate([np.asarray(i).T for i in data], axis=0)
             )
-        with span("gmm_fv.em_fit") as sp:
-            gmm = GaussianMixtureModelEstimator(
-                self.k, **self.gmm_kwargs
-            ).fit_matrix(cols)
-            sp.sync_on(gmm.means)
+        with span(
+            "gmm_fv.em_fit", samples=int(cols.shape[0]), centres=self.k
+        ) as sp:
+            est = GaussianMixtureModelEstimator(self.k, **self.gmm_kwargs)
+            gmm = est.fit_matrix(cols)
+            # the parameters are host arrays by now: the loop has ended
+            sp.attrs["iterations"] = int(est.iterations_run)
         return FisherVector(gmm)

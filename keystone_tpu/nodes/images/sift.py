@@ -46,23 +46,25 @@ def _gaussian_kernel1d(sigma: float) -> np.ndarray:
 
 
 def _smooth(X, sigma: float):
-    """Separable Gaussian blur of (n, X, Y) maps via two 1-D convs
-    (σ=0 → identity); edge-replicated padding like vl_imsmooth."""
+    """Separable Gaussian blur of (n, X, Y) maps (σ=0 → identity);
+    edge-replicated padding like vl_imsmooth. The taps are summed as
+    shifted slices in float32, NOT a convolution: a TPU runs a float32
+    convolution as one bf16 pass by default, which rounds the pixels by
+    4e-3 of their value while the gradients below are differences of
+    neighbouring smoothed pixels, a few hundredths of them — on the chip
+    24% of the quantized descriptor elements then differ from a float32
+    run's, by up to 3 (PERF.md §6, PR 33). At ``Precision.HIGHEST`` the
+    one-channel convolution is exact and costs 1.5 ms an image of 500 ×
+    375 on the MXU's six passes; the shifted sums are exact at 0.3."""
     if sigma <= 0:
         return X
-    k = jnp.asarray(_gaussian_kernel1d(sigma))
+    k = _gaussian_kernel1d(sigma)
     r = k.shape[0] // 2
-    Xp = jnp.pad(X, [(0, 0), (r, r), (r, r)], mode="edge")[..., None]
-    kx = k.reshape(-1, 1, 1, 1)  # (H, W, I, O)
-    ky = k.reshape(1, -1, 1, 1)
-    dn = ("NHWC", "HWIO", "NHWC")
-    out = jax.lax.conv_general_dilated(
-        Xp, kx, (1, 1), "VALID", dimension_numbers=dn
-    )
-    out = jax.lax.conv_general_dilated(
-        out, ky, (1, 1), "VALID", dimension_numbers=dn
-    )
-    return out[..., 0]
+    xd, yd = X.shape[1], X.shape[2]
+    P = jnp.pad(X, [(0, 0), (r, r), (0, 0)], mode="edge")
+    X = sum(float(w) * P[:, i : i + xd, :] for i, w in enumerate(k))
+    P = jnp.pad(X, [(0, 0), (0, 0), (r, r)], mode="edge")
+    return sum(float(w) * P[:, :, i : i + yd] for i, w in enumerate(k))
 
 
 def _orientation_maps(X):
@@ -94,14 +96,13 @@ def _orientation_maps(X):
 
 def _box_pool(maps, width: int):
     """Box-sum each orientation map over width×width windows ('flat window')
-    → (n, X-w+1, Y-w+1, 8). Separable: two 1-D passes cost 2·W adds per
-    output instead of the 2-D window's W²."""
-    out = jax.lax.reduce_window(
-        maps, 0.0, jax.lax.add, (1, width, 1, 1), (1, 1, 1, 1), "valid"
-    )
-    return jax.lax.reduce_window(
-        out, 0.0, jax.lax.add, (1, 1, width, 1), (1, 1, 1, 1), "valid"
-    )
+    → (n, X-w+1, Y-w+1, 8). Separable: two 1-D passes of ``width`` shifted
+    slices added up. (``lax.reduce_window`` computes the same sums and took
+    1.4 ms an image of 500 × 375 over the four scales on a TPU v5e where
+    the shifted additions take 0.4: PERF.md §6, PR 33.)"""
+    nx, ny = maps.shape[1] - width + 1, maps.shape[2] - width + 1
+    maps = sum(maps[:, i : i + nx] for i in range(width))
+    return sum(maps[:, :, i : i + ny] for i in range(width))
 
 
 @partial(jax.jit, static_argnames=("bin_size", "step"))
@@ -192,10 +193,31 @@ class SIFTExtractor(Transformer):
             all_desc.append(desc)
         return jnp.concatenate(all_desc, axis=1)
 
+    def num_descriptors(self, xd: int, yd: int) -> int:
+        """N: grid points over the scales of an ``xd`` × ``yd`` image."""
+        total = 0
+        for scale in range(self.num_scales):
+            extent = _NBP * (self.bin_size + 2 * scale)
+            step = self.step + scale * self.scale_step
+            if xd >= extent and yd >= extent:
+                total += ((xd - extent) // step + 1) * ((yd - extent) // step + 1)
+        return total
+
+    def row_scratch_bytes(self, shape: Tuple[int, ...]) -> int:
+        """What an image holds besides the (128, N) output while its
+        descriptors are made — segment dispatch prices a row by it
+        (``compile/segment.py:_item_bytes``): the (N, 128) stack the scales
+        are joined into, and a scale's eight orientation maps before and
+        after the box sums. At 500 × 375 that is 37.6 + 12 MB."""
+        _, xd, yd = shape[:3]
+        stack = self.num_descriptors(xd, yd) * _NBP * _NBP * _NBO * 4
+        return stack + 2 * xd * yd * _NBO * 4
+
     def trace_batch(self, X):
         # (n, N, 128) → (n, 128, N): the reference's column-major descriptor
         # matrix shape (external/SIFTExtractor.scala:27-33)
-        return jnp.swapaxes(self.descriptors_batch(X), 1, 2)
+        with jax.named_scope("ks.featurize.sift"):
+            return jnp.swapaxes(self.descriptors_batch(X), 1, 2)
 
     def apply(self, x):
         return self.trace_batch(jnp.asarray(x)[None])[0]

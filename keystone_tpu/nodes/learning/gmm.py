@@ -40,6 +40,16 @@ RANDOM_INITIALIZATION = "random"
 # noise, making the encoding fusion-invariant.
 _PREC = "high"
 
+# The EM's own products at float32 on any backend. The loop stops when the
+# mean log likelihood gains less than 1e-4 of itself: at three passes the
+# likelihood carries 1e-5 of noise, so a run on a TPU can stop a round
+# before or after a float32 run does, and a round moves the weights by
+# percents. Ten or twenty rounds over the sample, once a fit: 0.3 s more
+# at 10⁶ × 80 × 256 on a v5e (PERF.md §6, PR 33) beside a featurizer of
+# seconds. The posteriors of the ENCODING (``_posteriors``, the Fisher
+# vector's path over every descriptor of every image) stay at ``_PREC``.
+_EM_PREC = jax.lax.Precision.HIGHEST
+
 
 @jax.jit
 def _posteriors(X, means, variances, weights, weight_threshold):
@@ -74,8 +84,8 @@ def _e_step(X, means, variances, weights, weight_threshold):
     llh for both too (GaussianMixtureModelEstimator.scala:118-165)."""
     Xsq = X * X
     sq_mahal = (
-        jnp.matmul(Xsq, (0.5 / variances).T, precision=_PREC)
-        - jnp.matmul(X, (means / variances).T, precision=_PREC)
+        jnp.matmul(Xsq, (0.5 / variances).T, precision=_EM_PREC)
+        - jnp.matmul(X, (means / variances).T, precision=_EM_PREC)
         + 0.5 * jnp.sum(means * means / variances, axis=1)
     )
     d = X.shape[1]
@@ -97,9 +107,9 @@ def _e_step(X, means, variances, weights, weight_threshold):
 def _m_step(X, q, var_floor):
     q_sum = jnp.sum(q, axis=0)
     weights = q_sum / X.shape[0]
-    means = jnp.matmul(q.T, X, precision=_PREC) / q_sum[:, None]
+    means = jnp.matmul(q.T, X, precision=_EM_PREC) / q_sum[:, None]
     variances = (
-        jnp.matmul(q.T, X * X, precision=_PREC) / q_sum[:, None]
+        jnp.matmul(q.T, X * X, precision=_EM_PREC) / q_sum[:, None]
         - means * means
     )
     variances = jnp.maximum(variances, var_floor)
@@ -121,7 +131,8 @@ def _em_loop(X, means, variances, weights, var_floor, *,
     check), each of which drains the device queue. Break semantics match
     the reference loop exactly (GaussianMixtureModelEstimator.scala:
     118-165): stop on non-improving cost or an unbalanced cluster, in both
-    cases KEEPING the previous iteration's parameters."""
+    cases KEEPING the previous iteration's parameters. Returns the
+    parameters and the iterations run."""
 
     def cond(carry):
         i, done, *_ = carry
@@ -150,8 +161,8 @@ def _em_loop(X, means, variances, weights, var_floor, *,
         variances,
         weights,
     )
-    _, _, _, _, m, v, w = jax.lax.while_loop(cond, body, init)
-    return m, v, w
+    i, _, _, _, m, v, w = jax.lax.while_loop(cond, body, init)
+    return m, v, w, i
 
 
 class GaussianMixtureModel(Transformer):
@@ -228,8 +239,14 @@ class GaussianMixtureModelEstimator(Estimator):
             assign = km.trace_batch(X)
             mass = jnp.sum(assign, axis=0)
             weights = mass / n
-            means = (assign.T @ X) / mass[:, None]
-            variances = (assign.T @ (X * X)) / mass[:, None] - means * means
+            # the clusters' moments at float32 on any backend: a mean of
+            # descriptors rounded to bfloat16 starts the EM from another
+            # point (kmeans.py says what that costs)
+            means = jnp.matmul(assign.T, X, precision=_EM_PREC) / mass[:, None]
+            variances = (
+                jnp.matmul(assign.T, X * X, precision=_EM_PREC) / mass[:, None]
+                - means * means
+            )
         else:
             rng = np.random.default_rng(self.seed)
             col_min = jnp.min(X, axis=0)
@@ -247,7 +264,7 @@ class GaussianMixtureModelEstimator(Estimator):
         )
         variances = jnp.maximum(variances, var_floor)
 
-        means, variances, weights = _em_loop(
+        means, variances, weights, self.iterations_run = _em_loop(
             X, means, variances, weights, var_floor,
             max_iterations=self.max_iterations,
             weight_threshold=self.weight_threshold,

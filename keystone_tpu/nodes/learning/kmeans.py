@@ -19,8 +19,19 @@ import jax
 import jax.numpy as jnp
 
 from ...data.dataset import Dataset
+from ...obs.tracer import span
 from ...workflow.transformer import Estimator, Transformer
 from ...utils.params import as_param
+
+# Distances and the centres' moments at float32 whatever the backend's
+# default (one bf16 pass on a TPU). The seeding draws each centre by an
+# arg-max over the log of these distances and the assignments by an
+# arg-min: an error of 4e-3 picks other points than a float32 run does,
+# and from another seeding comes another codebook. A centre is a mean of
+# descriptors of 0..255: rounded to bfloat16 it is off by a whole unit.
+# The products are n × d × k with k a few hundred, once a fit: nothing
+# beside the featurizer they are learnt for.
+_PREC = jax.lax.Precision.HIGHEST
 
 
 @jax.jit
@@ -29,7 +40,7 @@ def _sq_dists(X, means):
     distance trick (KMeansPlusPlus.scala:34-39)."""
     xsq = 0.5 * jnp.sum(X * X, axis=1, keepdims=True)
     msq = 0.5 * jnp.sum(means * means, axis=1)
-    return xsq - X @ means.T + msq
+    return xsq - jnp.matmul(X, means.T, precision=_PREC) + msq
 
 
 @jax.jit
@@ -54,7 +65,10 @@ def _seed_plus_plus(X, key, k: int):
 
     def step(carry, _):
         cur_sq, last_c, key = carry
-        sq_new = xsq_half - X @ last_c + 0.5 * jnp.dot(last_c, last_c)
+        sq_new = (
+            xsq_half - jnp.matmul(X, last_c, precision=_PREC)
+            + 0.5 * jnp.dot(last_c, last_c)
+        )
         cur_sq = jnp.minimum(cur_sq, sq_new)
         probs = jnp.maximum(cur_sq, 0.0)
         key, kw, ku = jax.random.split(key, 3)
@@ -93,7 +107,10 @@ def _lloyd_loop(X, means, *, max_iterations: int, stop_tolerance: float):
         )
         assign = jax.nn.one_hot(jnp.argmin(dists, axis=1), k, dtype=X.dtype)
         counts = assign.sum(axis=0)
-        new_means = (assign.T @ X) / jnp.maximum(counts, 1.0)[:, None]
+        new_means = (
+            jnp.matmul(assign.T, X, precision=_PREC)
+            / jnp.maximum(counts, 1.0)[:, None]
+        )
         new_means = jnp.where((counts > 0)[:, None], new_means, means)
         m2 = jnp.where(stop, means, new_means)
         return (i + 1, stop, cost, True, m2)
@@ -132,9 +149,11 @@ class KMeansPlusPlusEstimator(Estimator):
 
     def fit_matrix(self, X) -> KMeansModel:
         X = jnp.asarray(X, dtype=jnp.float32)
-        means = _seed_plus_plus(
-            X, jax.random.PRNGKey(self.seed), self.num_means
-        )
+        with span("kmeans.seed", centres=self.num_means) as sp:
+            means = _seed_plus_plus(
+                X, jax.random.PRNGKey(self.seed), self.num_means
+            )
+            sp.sync_on(means)
         means = _lloyd_loop(
             X, means,
             max_iterations=self.max_iterations,

@@ -21,6 +21,7 @@ import numpy as np
 
 from ...data.dataset import Dataset
 from ...linalg.tsqr import tsqr_r
+from ...obs.tracer import span
 from ...parallel.mesh import default_mesh
 from ...workflow.node_optimization import Optimizable
 from ...workflow.transformer import Estimator, Transformer
@@ -61,8 +62,14 @@ class BatchPCATransformer(Transformer):
         self.pca_mat = as_param(pca_mat)
 
     def trace_batch(self, X):
-        # X: (n, d, n_desc) → (n, dims, n_desc)
-        return jnp.einsum("dk,ndm->nkm", self.pca_mat, X)
+        # X: (n, d, n_desc) → (n, dims, n_desc). precision=high, as the
+        # Fisher vector's products downstream: the projected descriptors go
+        # into an expanded Mahalanobis form that cancels, and one bf16 pass
+        # over descriptors of 0..255 lands in the posteriors' exponent
+        with jax.named_scope("ks.featurize.pca"):
+            return jnp.einsum(
+                "dk,ndm->nkm", self.pca_mat, X, precision="high"
+            )
 
     def apply(self, x):
         return self.pca_mat.T @ jnp.asarray(x)
@@ -89,7 +96,11 @@ def _pca_gram_eigh(X):
     tests."""
     means = jnp.mean(X, axis=0)
     Xc = X - means
-    G = jnp.matmul(Xc.T, Xc, precision="high")
+    # float32 on any backend: the directions of close eigenvalues turn
+    # with an error in G, and everything fitted after the projection (a
+    # codebook, a model) is fitted in these coordinates. One n × d × d
+    # product a fit.
+    G = jnp.matmul(Xc.T, Xc, precision=jax.lax.Precision.HIGHEST)
     _, vecs = jnp.linalg.eigh(G)  # ascending eigenvalues
     v = vecs[:, ::-1]  # descending, like svd's vt ordering
     return enforce_matlab_sign_convention(v)
@@ -120,8 +131,11 @@ class PCAEstimator(Estimator, CostModel):
     def cost(self, n, d, k, sparsity, num_machines,
              cpu_weight, mem_weight, network_weight):
         flops = n * d * d
-        return max(cpu_weight * flops, mem_weight * n * d) \
-            + network_weight * n * d
+        # the sample is collected to one machine: nothing to collect where
+        # there is one (a single chip then takes the local path, whose
+        # covariance and eigenvectors are float32 on any backend)
+        collect = network_weight * n * d if num_machines > 1 else 0.0
+        return max(cpu_weight * flops, mem_weight * n * d) + collect
 
 
 class DistributedPCAEstimator(Estimator, CostModel):
@@ -194,6 +208,17 @@ class _ColumnFit:
         cols = [np.asarray(item).T for item in data]
         return jnp.asarray(np.concatenate(cols, axis=0), dtype=jnp.float32)
 
+    def _fit_columns(self, data: Dataset, directions) -> "BatchPCATransformer":
+        """The transformer of ``directions(rows)`` over the columns of
+        ``data``, under a ``pca.fit`` span."""
+        rows = self._collect_columns(data)
+        with span(
+            "pca.fit", samples=int(rows.shape[0]), dims=self.dims
+        ) as sp:
+            pca_mat = directions(rows)
+            sp.sync_on(pca_mat)
+        return BatchPCATransformer(pca_mat)
+
 
 class LocalColumnPCAEstimator(Estimator, CostModel, _ColumnFit):
     """(parity: LocalColumnPCAEstimator, PCA.scala:52-73)."""
@@ -203,8 +228,7 @@ class LocalColumnPCAEstimator(Estimator, CostModel, _ColumnFit):
         self._est = PCAEstimator(dims)
 
     def fit(self, data: Dataset) -> BatchPCATransformer:
-        rows = self._collect_columns(data)
-        return BatchPCATransformer(self._est.compute_pca(rows))
+        return self._fit_columns(data, self._est.compute_pca)
 
     def cost(self, *a):
         return self._est.cost(*a)
@@ -218,9 +242,9 @@ class DistributedColumnPCAEstimator(Estimator, CostModel, _ColumnFit):
         self._est = DistributedPCAEstimator(dims)
 
     def fit(self, data: Dataset) -> BatchPCATransformer:
-        rows = self._collect_columns(data)
-        t = self._est.fit(Dataset.of(rows))
-        return BatchPCATransformer(t.pca_mat)
+        return self._fit_columns(
+            data, lambda rows: self._est.fit(Dataset.of(rows)).pca_mat
+        )
 
     def cost(self, *a):
         return self._est.cost(*a)

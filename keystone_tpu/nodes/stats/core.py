@@ -282,30 +282,56 @@ class Sampler(Transformer):
 
 
 class ColumnSampler(Transformer):
-    """Sample ``num_samples`` random columns of each (d, m) matrix item
-    (parity: Sampling.scala:12-20). Used to subsample descriptor matrices
-    before PCA/GMM estimation.
+    """Sample ``num_samples`` random columns (with replacement) of each
+    (d, m) matrix item (parity: Sampling.scala:12-20). Used to subsample
+    descriptor matrices before PCA/GMM estimation.
 
-    A batched (n, d, m) descriptor stack samples in ONE device gather
-    (take_along_axis with per-item column draws) instead of n per-item
-    dispatches, whose host overhead dwarfs the gather work itself."""
+    The draw of a row is keyed on (seed, the row's index in the data set)
+    and on nothing else — ``jax.random.fold_in(PRNGKey(seed), row)`` — so
+    the same columns come out whole, in row slices of any size, chunk by
+    chunk and item by item, and another program can draw them too.
+    ``row_keyed``: segment dispatch hands ``trace_batch`` the rows' indices
+    (``compile/segment.py``), so the sampler is a member of the row-sliced
+    segment that makes the descriptors and the descriptor stack of a whole
+    data set never exists."""
+
+    #: ``trace_batch`` takes ``rows``: the data-set index of each row
+    row_keyed = True
 
     def __init__(self, num_samples_per_matrix: int, seed: int = 0):
         self.num_samples = num_samples_per_matrix
         self.seed = seed
-        self._rng = np.random.default_rng(seed)
 
-    def apply(self, x):
+    def columns(self, rows, m: int):
+        """(len(rows), num_samples) int32 column draws for the rows whose
+        data-set indices are ``rows``, each in [0, m)."""
+        key = jax.random.PRNGKey(self.seed)
+        return jax.vmap(
+            lambda r: jax.random.randint(
+                jax.random.fold_in(key, r), (self.num_samples,), 0, m
+            )
+        )(jnp.asarray(rows, jnp.uint32))
+
+    def trace_batch(self, X, rows=None):
+        n, _, m = X.shape
+        if rows is None:
+            rows = jnp.arange(n)
+        return jnp.take_along_axis(
+            X, self.columns(rows, m)[:, None, :], axis=2
+        )
+
+    def apply(self, x, row: int = 0):
         x = jnp.asarray(x)
-        cols = self._rng.integers(0, x.shape[1], size=self.num_samples)
-        return x[:, jnp.asarray(cols)]
+        return x[:, self.columns(jnp.asarray([row]), x.shape[1])[0]]
 
     def apply_batch(self, data):
         from ...data.chunked import ChunkedDataset
 
         data = Dataset.of(data)
         if not data.is_batched:
-            return data.map(self.apply)
+            return Dataset.from_items(
+                [self.apply(x, i) for i, x in enumerate(data)]
+            )
         if isinstance(data, ChunkedDataset):
             # per-chunk device gather, lazily — the sampled set is small and
             # materializes at the consumer; the descriptor stack never does.
@@ -314,27 +340,20 @@ class ColumnSampler(Transformer):
             parent = data.raw_chunks
 
             def factory():
-                for i, chunk in enumerate(parent()):
-                    yield self.sample_chunk(chunk, i)
+                at = 0
+                for chunk in parent():
+                    yield self.sample_chunk(chunk, at)
+                    at += chunk.shape[0]
 
             return ChunkedDataset(factory, len(data), label="col_sample")
-        return Dataset(self._sample_batch(data.to_array()), batched=True)
+        return data.map_batch(self.trace_batch)
 
-    def sample_chunk(self, X, chunk_index: int):
-        """Sample one chunk of a chunked scan. Column draws key on
-        (seed, chunk index), NOT the stateful rng: a lazy chunked chain
-        re-runs on every scan, and the lineage contract requires identical
-        chunks each time. Shared by the chunked ``apply_batch`` path and
-        callers that drive one combined scan themselves (the ImageNet FV
-        branch builder draws PCA + GMM samples in a single featurize pass)."""
-        return self._sample_batch(
-            X, np.random.default_rng((self.seed, chunk_index))
-        )
-
-    def _sample_batch(self, X, rng=None):
-        rng = self._rng if rng is None else rng
-        n, _, m = X.shape
-        cols = rng.integers(0, m, size=(n, self.num_samples))
-        return jnp.take_along_axis(
-            X, jnp.asarray(cols)[:, None, :], axis=2
-        )
+    def sample_chunk(self, X, row_start: int):
+        """Sample one chunk of a chunked scan whose first row is row
+        ``row_start`` of the data set: the columns the whole data set would
+        give these rows. A lazy chunked chain re-runs on every scan and the
+        lineage contract requires identical chunks each time. Shared by the
+        chunked ``apply_batch`` path and callers that drive one combined
+        scan themselves (the ImageNet FV branch builder draws PCA + GMM
+        samples in a single featurize pass)."""
+        return self.trace_batch(X, row_start + jnp.arange(X.shape[0]))

@@ -128,15 +128,17 @@ def compute_pca_fisher_branch(
             prefix_out = prefix(train_images).get()
             if isinstance(prefix_out, ChunkedDataset):
                 # both samplers share ONE featurize scan, each drawing via
-                # its (seed, chunk-index)-keyed sample_chunk contract
+                # its (seed, row-index)-keyed sample_chunk contract
                 s_pca = ColumnSampler(num_col_samples_per_image, seed=seed)
                 s_gmm = ColumnSampler(gmm_per_img, seed=seed + 1)
                 pca_parts, gmm_parts = [], []
-                for i, chunk in enumerate(prefix_out.chunks()):
+                at = 0
+                for chunk in prefix_out.chunks():
                     if need_pca_sample:
-                        pca_parts.append(s_pca.sample_chunk(chunk, i))
+                        pca_parts.append(s_pca.sample_chunk(chunk, at))
                     if need_gmm_sample:
-                        gmm_parts.append(s_gmm.sample_chunk(chunk, i))
+                        gmm_parts.append(s_gmm.sample_chunk(chunk, at))
+                    at += chunk.shape[0]
                 if need_pca_sample:
                     pca_sample = Dataset(
                         jnp.concatenate(pca_parts, axis=0), batched=True

@@ -268,7 +268,7 @@ class GraphExecutor:
         try:
             from ..check import lattice
             from ..check.segments import plan_segments
-            from ..compile.segment import bind_segment
+            from ..compile.segment import bind_segment, unheld_caches
 
             graph = self.graph
             with _span("plan.segments", nodes=len(graph.nodes)) as sp:
@@ -279,14 +279,18 @@ class GraphExecutor:
                 # what this executor already holds (a fit hands each
                 # estimator's executor the upstream results of the last)
                 # is data: a segment through it would featurize again
-                held = {n for n, e in self._state.items() if e.computed}
+                held = {n: e for n, e in self._state.items() if e.computed}
+                # a cache request the device cannot honour is no barrier:
+                # the Cacher joins the segment that feeds it and its value
+                # is computed again where it is read
+                declined = unheld_caches(graph, verdicts, held)
                 planned, _barriers = plan_segments(
                     graph, verdicts, {}, materialized=held,
-                    annotations=self._annotations,
+                    annotations=self._annotations, declined=declined,
                 )
                 table: Dict[NodeId, Any] = {}
                 for seg in planned:
-                    binding = bind_segment(graph, seg)
+                    binding = bind_segment(graph, seg, declined)
                     if binding is None:
                         continue
                     for out in binding.outputs:

@@ -37,7 +37,7 @@ def test_voc_sift_fisher_end_to_end():
         desc_dim=16,
         lam=10.0,
     )
-    aps, _ = run(tr_i, tr_l, te_i, te_l, conf)
+    _, aps, _ = run(tr_i, tr_l, te_i, te_l, conf)
     assert aps.shape == (20,)
     # random scoring gives MAP ≈ mean positive rate ≈ 0.1; textured classes
     # must do meaningfully better
@@ -68,6 +68,6 @@ def test_voc_pca_gmm_checkpoint_load(tmp_path):
         gmm_var_file=str(tmp_path / "v.csv"),
         gmm_wts_file=str(tmp_path / "w.csv"),
     )
-    aps, _ = run(tr_i, tr_l, te_i, te_l, conf)
+    _, aps, _ = run(tr_i, tr_l, te_i, te_l, conf)
     assert aps.shape == (20,)
     assert np.isfinite(aps).all()

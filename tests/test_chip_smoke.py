@@ -102,6 +102,22 @@ def test_conv_chain_leg_interpreted():
     assert "front_door_chose_kernel" not in report
 
 
+def test_fisher_leg_at_a_tiny_size():
+    """The leg's control flow on the CPU, where every product is float32:
+    the program's descriptors, basis, codebook and features are the
+    reference's well inside the gaps the chip is held to."""
+    report = chip_smoke.fisher_leg(
+        images=6, x=64, y=48, dims=16, centres=8, per_image=300
+    )
+    assert report["ok"] and report["finite"], report
+    assert report["shape"]["descriptors"] == 406
+    assert report["descriptor_max_gap"] <= 1.0
+    assert report["basis"] < 5e-3 and report["features"] < 5e-3, report
+    assert chip_smoke.FISHER_SHAPE == dict(
+        images=8, x=500, y=375, dims=80, centres=256
+    )
+
+
 def test_a_forced_segment_demotion_is_seen():
     """A segment whose compiled program raises at run time is served node
     by node with a warning (tests/compile/test_segment.py pins that the
