@@ -23,7 +23,10 @@ test rows of the seeded synthetic task, generated in HBM). Five legs:
   codebook fitted from sampled descriptors as ``voc_sift_fisher.run`` fits
   them, against the plain float32 ``highest`` reference of the benchmark
   (``benchmark/configs/voc_fv256_reference.py``): descriptors, basis,
-  codebook and features each inside a stated gap.
+  codebook and features each inside a stated gap; and the sampled SIFT
+  body (``SampledSIFTExtractor``: the descriptors at the sampler's columns
+  alone, gathered from the pooled maps) against the same columns of the
+  full body's descriptors.
 
 It refuses anything but a TPU, fails if any catch-and-degrade site fired
 on its path, and exits 0 only if every leg passed. Stdout is two lines of
@@ -472,8 +475,12 @@ FISHER_SHAPE = dict(images=8, x=500, y=375, dims=80, centres=256)
 #: straddle one, on a thousandth of them at most); the basis is a float32
 #: eigh against a float64 one; the features carry the basis and the
 #: codebook through posteriors whose products run at three bf16 passes
-#: here and at six in the reference
-FISHER_GAPS = dict(descriptor_share=1e-3, basis=5e-3, codebook=5e-2, features=5e-2)
+#: here and at six in the reference; the sampled body's descriptors are
+#: the full body's at the same columns but for the same floor
+FISHER_GAPS = dict(
+    descriptor_share=1e-3, basis=5e-3, codebook=5e-2, features=5e-2,
+    sampled_share=1e-3,
+)
 
 
 def fisher_leg(*, images, x, y, dims, centres, per_image=2000):
@@ -493,6 +500,7 @@ def fisher_leg(*, images, x, y, dims, centres, per_image=2000):
         PixelScaler,
         SIFTExtractor,
     )
+    from keystone_tpu.nodes.images.chain import SampledSIFTExtractor
     from keystone_tpu.nodes.learning import ColumnPCAEstimator
     from keystone_tpu.nodes.stats import (
         ColumnSampler,
@@ -534,6 +542,14 @@ def fisher_leg(*, images, x, y, dims, centres, per_image=2000):
     F = np.asarray(jax.block_until_ready(F))
     seconds = time.perf_counter() - t0
 
+    # the sampling pass's own body: the gather's index arithmetic where the
+    # numerics are the chip's
+    sampler = ColumnSampler(per_image, seed=seed)
+    sampled = np.asarray(
+        jit(SampledSIFTExtractor(SIFTExtractor(), (), sampler))(gray)
+    )
+    want_sampled = np.asarray(jit(sampler)(D))
+
     want_D = ref.sift(cfg, X)
     book = ref.learn_codebook(cfg, X)
     want_F = np.asarray(ref.fisher_vectors(cfg, book, want_D, "highest"))
@@ -557,11 +573,14 @@ def fisher_leg(*, images, x, y, dims, centres, per_image=2000):
             rel(fv.gmm.weights, book["weights"]),
         ),
         "features": rel(F, want_F),
+        "sampled_max_gap": float(np.abs(sampled - want_sampled).max()),
+        "sampled_share": float(np.mean(sampled != want_sampled)),
         "seconds_program": round(seconds, 3),
     }
     report["ok"] = bool(
         report["finite"] and F.shape == want_F.shape
         and report["descriptor_max_gap"] <= 1.0
+        and report["sampled_max_gap"] <= 1.0
         and all(report[k] <= v for k, v in FISHER_GAPS.items())
     )
     return report

@@ -777,6 +777,11 @@ class SegmentBinding:
                 shapes = [(slice_rows,) + a.shape[1:] for a in arrays]
                 if _runs_conv_kernel(self.steps, shapes):
                     facts["conv_fused_rows"] = rows
+                for op, _ in self.steps:
+                    # a member that counts its rows under a name of its own
+                    # (the sampled SIFT body: ``sift_sampled_rows``)
+                    if getattr(op, "rows_fact", None):
+                        facts[op.rows_fact] = rows
             if self.digest is not None:
                 seg_cost.record_run(
                     self.digest, time.perf_counter() - t0,
@@ -909,7 +914,8 @@ def bind_segment(
     not worth (or not safe to) segment-dispatch:
 
     * empty, or a singleton — a single node gains nothing over its node
-      thunk;
+      thunk — unless the node stands for a chain and says so
+      (``binds_alone``: its body wants one program and row slices);
     * any member without a traceable ``trace_batch`` (defense in depth —
       the planner's lattice should have barriered these already);
     * the cost model demoted this digest (compile cost exceeded observed
@@ -925,7 +931,10 @@ def bind_segment(
     )
 
     members = list(segment.nodes)
-    if len(members) < 2:
+    alone = len(members) == 1 and getattr(
+        graph.get_operator(members[0]), "binds_alone", False
+    )
+    if len(members) < 2 and not alone:
         return None
     ops = []
     for n in members:
@@ -968,7 +977,9 @@ def bind_segment(
         digest = None
     from ..cost import segments as seg_cost
 
-    if digest is not None and not seg_cost.should_compile(
+    # (the cost model weighs a compile against the node dispatches it
+    # saves: a node that binds alone saves none and is not bound for them)
+    if digest is not None and not alone and not seg_cost.should_compile(
         digest, len(members)
     ):
         logger.info(

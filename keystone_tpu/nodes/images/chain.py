@@ -1,28 +1,42 @@
-"""The convolution chain as one node: ``Convolver`` → ``SymmetricRectifier``
-→ sum-``Pooler`` with the convolution's output kept on the chip.
+"""Chains the optimizer puts ONE node in place of, ahead of segment
+planning. Each rule matches operator types and scalars and reads no array;
+a graph without the chain's head comes back as it was given.
 
+**The convolution chain**: ``Convolver`` → ``SymmetricRectifier`` →
+sum-``Pooler`` with the convolution's output kept on the chip.
 Pipelines write the chain as the reference does (``Convolver.and_then(
 SymmetricRectifier).and_then(Pooler)``, RandomPatchCifar.scala:72-83);
-:class:`ConvChainRule` recognises it in the optimized graph, ahead of
-segment planning, and puts :class:`ConvRectifyPool` in its place:
+:class:`ConvChainRule` recognises it in the optimized graph
+and puts :class:`ConvRectifyPool` in its place:
 one member whose output is the pooled features, so segment dispatch prices a
 row at 0.3 MB and not at the 88 MB the three outputs take at 10,000 filters.
 The node runs ``ops/conv_rectify_pool.py`` where that kernel can run — the
 TPU backend, shapes its tiling admits — and the three bodies anywhere else:
 the same values, chosen from what the code can observe, with no option.
+
+**The sampling pass**: ``SIFTExtractor`` → nodes that act column by column
+(``column_wise``: the projection) → ``ColumnSampler``. Sampling columns
+commutes with everything that reads no other column, so
+:class:`SampledSIFTRule` puts :class:`SampledSIFTExtractor` at the sampler's
+place: it draws the sampler's columns first and makes only those descriptors
+(``SIFTExtractor.sampled_batch``) — 651 of an image's 73,505 in
+``voc_fv256`` — then applies the column-wise nodes to the sample.
 """
 
 from __future__ import annotations
 
-from typing import Optional, Tuple
+from typing import Callable, List, Optional, Sequence, Tuple
 
 import jax
+import jax.numpy as jnp
 
 from ...ops import conv_rectify_pool as _kernel
 from ...workflow.graph import Graph, NodeId
 from ...workflow.rules import Annotations, Rule
 from ...workflow.transformer import Transformer
+from ..stats.core import ColumnSampler, RowKeyedTransformer
 from .core import Convolver, Pooler, SymmetricRectifier
+from .sift import SIFTExtractor
 
 
 class ConvRectifyPool(Transformer):
@@ -93,6 +107,49 @@ class ConvRectifyPool(Transformer):
             )
 
 
+def _heads(graph: Graph, kind) -> List[NodeId]:
+    return [
+        n for n in sorted(graph.nodes)
+        if isinstance(graph.get_operator(n), kind)
+    ]
+
+
+def _only_reader_of(
+    graph: Graph, annotations: Annotations
+) -> Callable[[NodeId], Optional[NodeId]]:
+    """``only_reader(node)``: the one node that reads ``node``, or None
+    where a second node or a sink reads it too, or its result is saved
+    (annotated: it has to reach the state table)."""
+    readers: dict = {}
+    for node in graph.nodes:
+        for d in graph.get_dependencies(node):
+            readers.setdefault(d, []).append(node)
+    for d in graph.sink_dependencies.values():
+        readers.setdefault(d, []).append(None)
+
+    def only_reader(node: NodeId) -> Optional[NodeId]:
+        found = readers.get(node, [])
+        if len(found) != 1 or node in annotations:
+            return None
+        return found[0]
+
+    return only_reader
+
+
+def _replace_chain(
+    graph: Graph, chain: Sequence[NodeId], fused: Transformer
+) -> Graph:
+    """``fused`` at the last node of ``chain``, reading what its first node
+    read; the nodes before the last go."""
+    graph = graph.set_operator(chain[-1], fused)
+    graph = graph.set_dependencies(
+        chain[-1], graph.get_dependencies(chain[0])
+    )
+    for node in reversed(chain[:-1]):
+        graph = graph.remove_node(node)
+    return graph
+
+
 class ConvChainRule(Rule):
     """Replace every ``Convolver`` whose only reader is a
     ``SymmetricRectifier`` whose only reader is a ``Pooler`` with
@@ -106,29 +163,20 @@ class ConvChainRule(Rule):
     def apply(
         self, graph: Graph, annotations: Annotations
     ) -> Tuple[Graph, Annotations]:
-        heads = [
-            n for n in sorted(graph.nodes)
-            if isinstance(graph.get_operator(n), Convolver)
-        ]
+        heads = _heads(graph, Convolver)
         if not heads:
             return graph, annotations
-        readers = {}
-        for node in graph.nodes:
-            for d in graph.get_dependencies(node):
-                readers.setdefault(d, []).append(node)
-        for d in graph.sink_dependencies.values():
-            readers.setdefault(d, []).append(None)
+        only_reader = _only_reader_of(graph, annotations)
 
-        def only_reader(node: NodeId, kind) -> Optional[NodeId]:
-            found = readers.get(node, [])
-            if len(found) != 1 or found[0] is None or node in annotations:
+        def reader(node: Optional[NodeId], kind) -> Optional[NodeId]:
+            found = None if node is None else only_reader(node)
+            if found is None:
                 return None
-            op = graph.get_operator(found[0])
-            return found[0] if isinstance(op, kind) else None
+            return found if isinstance(graph.get_operator(found), kind) else None
 
         for head in heads:
-            mid = only_reader(head, SymmetricRectifier)
-            tail = only_reader(mid, Pooler) if mid is not None else None
+            mid = reader(head, SymmetricRectifier)
+            tail = reader(mid, Pooler)
             if tail is None:
                 continue
             pooler = graph.get_operator(tail)
@@ -137,9 +185,82 @@ class ConvChainRule(Rule):
             fused = ConvRectifyPool(
                 graph.get_operator(head), graph.get_operator(mid), pooler
             )
-            graph = graph.set_operator(tail, fused)
-            graph = graph.set_dependencies(
-                tail, graph.get_dependencies(head)
-            )
-            graph = graph.remove_node(mid).remove_node(head)
+            graph = _replace_chain(graph, (head, mid, tail), fused)
+        return graph, annotations
+
+
+class SampledSIFTExtractor(RowKeyedTransformer):
+    """``sampler(then[-1](… then[0](sift(X))))`` for nodes ``then`` that
+    act column by column: the sampler's draw first, the descriptors at the
+    drawn columns alone, then the column-wise nodes on the sample. The same
+    columns of the same descriptors as the chain written out, whose (N,
+    128) stack of an image — 37.6 MB at 500 × 375 — is never built."""
+
+    #: ``exec.segment`` counts the rows through this node under this name
+    rows_fact = "sift_sampled_rows"
+    #: dispatched as the chain it stands for was, as a compiled segment in
+    #: row slices, even where it is the segment's only member
+    #: (``compile/segment.py:bind_segment``): node dispatch would run the
+    #: body operation by operation over a whole data set
+    binds_alone = True
+
+    def __init__(
+        self, sift: SIFTExtractor, then: Sequence[Transformer],
+        sampler: ColumnSampler,
+    ):
+        self.sift = sift
+        self.then = tuple(then)
+        self.sampler = sampler
+
+    def row_scratch_bytes(self, shape: Tuple[int, ...]) -> int:
+        """What an image holds besides the sample while it is made
+        (``compile/segment.py:_item_bytes``): 34.9 MB at 500 × 375."""
+        return self.sift.sampled_scratch_bytes(shape)
+
+    def trace_batch(self, X, rows=None):
+        n, xd, yd = X.shape[:3]
+        if rows is None:
+            rows = jnp.arange(n)
+        columns = self.sampler.columns(
+            rows, self.sift.num_descriptors(xd, yd)
+        )
+        D = self.sift.sampled_batch(X, columns)
+        for node in self.then:
+            D = node.trace_batch(D)
+        return D
+
+
+class SampledSIFTRule(Rule):
+    """Replace every ``SIFTExtractor`` whose only reader leads, through
+    ``column_wise`` nodes each with one reader, to a ``ColumnSampler`` by
+    one :class:`SampledSIFTExtractor` at the sampler's node. Matches
+    operator types and one class attribute; reads no array. A second
+    reader anywhere before the sampler, a saved (annotated) interior result
+    or any other node in between (a ``Cacher``) leaves the chain as
+    written."""
+
+    name = "SampledSIFTRule"
+
+    def apply(
+        self, graph: Graph, annotations: Annotations
+    ) -> Tuple[Graph, Annotations]:
+        heads = _heads(graph, SIFTExtractor)
+        if not heads:
+            return graph, annotations
+        only_reader = _only_reader_of(graph, annotations)
+        for head in heads:
+            chain = [head]
+            while True:
+                node = only_reader(chain[-1])
+                if node is None:
+                    break
+                chain.append(node)
+                op = graph.get_operator(node)
+                if not getattr(op, "column_wise", False):
+                    break
+            ops = [graph.get_operator(n) for n in chain]
+            if len(chain) < 2 or not isinstance(ops[-1], ColumnSampler):
+                continue
+            fused = SampledSIFTExtractor(ops[0], ops[1:-1], ops[-1])
+            graph = _replace_chain(graph, chain, fused)
         return graph, annotations

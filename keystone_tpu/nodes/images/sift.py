@@ -105,6 +105,49 @@ def _box_pool(maps, width: int):
     return sum(maps[:, :, i : i + ny] for i in range(width))
 
 
+def _bin_window(bin_size: int) -> Tuple[int, int]:
+    """``(window, off)``: the side of the flat window a bin is pooled over,
+    and how far its anchor lies before the bin's (a centered window)."""
+    window = max(1, int(round(bin_size * _WINDOW_SIZE)))
+    return window, (window - bin_size) // 2
+
+
+def _grid(xd: int, yd: int, bin_size: int, step: int) -> Tuple[int, int]:
+    """``(nkx, nky)``: the keypoints of one scale over an ``xd`` × ``yd``
+    image — descriptors of 4×4 bins of side ``bin_size``, anchored at their
+    top-left corners ``(ix·step, iy·step)`` — or (0, 0) where none fits."""
+    extent = _NBP * bin_size
+    if xd < extent or yd < extent:
+        return 0, 0
+    return (xd - extent) // step + 1, (yd - extent) // step + 1
+
+
+def _pooled_maps(gray, bin_size: int):
+    """(n, X, Y) smoothed → (n, X-w+1, Y-w+1, 8): the orientation maps
+    box-summed over a bin's flat window; the value at p is the sum over the
+    box anchored at p. Everything a scale computes whatever descriptors are
+    then read from it."""
+    return _box_pool(_orientation_maps(gray), _bin_window(bin_size)[0])
+
+
+def _normalize(desc):
+    """(…, 128) binned descriptors → (L2-normalized, clamped at 0.2 and
+    normalized again; the norms before the clamp, which the contrast test
+    reads: vl_dsift's semantics)."""
+    norms = jnp.linalg.norm(desc, axis=-1)
+    normed = desc / jnp.maximum(norms[..., None], 1e-12)
+    normed = jnp.minimum(normed, 0.2)
+    n2 = jnp.linalg.norm(normed, axis=-1, keepdims=True)
+    return normed / jnp.maximum(n2, 1e-12), norms
+
+
+def _quantize(desc, norms):
+    """Zero the low-contrast descriptors (VLFeat.cxx:62,146), then the
+    short quantization: ×512, clamp 255 (VLFeat.cxx:237-249)."""
+    desc = jnp.where((norms > _CONTRAST_THRESHOLD)[..., None], desc, 0.0)
+    return jnp.minimum(jnp.floor(desc * 512.0), 255.0)
+
+
 @partial(jax.jit, static_argnames=("bin_size", "step"))
 def _sift_one_scale(gray, bin_size: int, step: int):
     """Descriptors for one scale over the keypoint grid.
@@ -113,19 +156,12 @@ def _sift_one_scale(gray, bin_size: int, step: int):
     descriptors (un-normalized binning already weighted), plus norms.
     """
     n, xd, yd = gray.shape
-    maps = _orientation_maps(gray)
-    window = max(1, int(round(bin_size * _WINDOW_SIZE)))
-    pooled = _box_pool(maps, window)  # value at p = sum over box anchored at p
-
-    # Descriptor geometry: 4×4 bins of side bin_size; descriptor extent
-    # 4·bin_size. Anchor descriptors at top-left corner positions.
-    extent = _NBP * bin_size
-    max_x = xd - extent
-    max_y = yd - extent
-    if max_x < 0 or max_y < 0:
+    pooled = _pooled_maps(gray, bin_size)
+    nkx, nky = _grid(xd, yd, bin_size, step)
+    if not nkx:
         return jnp.zeros((n, 0, _NBP * _NBP * _NBO)), jnp.zeros((n, 0))
-    kx = list(range(0, max_x + 1, step))
-    ky = list(range(0, max_y + 1, step))
+    kx = np.arange(nkx) * step
+    ky = np.arange(nky) * step
 
     # bin (i, j) of descriptor at (x, y) pools the box anchored at
     # (x + i·bin − (window−bin)//2, …) — centered flat window per bin.
@@ -134,28 +170,34 @@ def _sift_one_scale(gray, bin_size: int, step: int):
     # and ran 1.5× SLOWER: stride-3 slices on the second-minor dim defeat
     # the TPU's vectorized loads worse than the gathers do. Measured,
     # reverted; don't repeat.
-    off = (window - bin_size) // 2
+    _, off = _bin_window(bin_size)
     px_max = pooled.shape[1] - 1
     py_max = pooled.shape[2] - 1
 
     feats = []
     for j in range(_NBP):        # y bins slow
         for i in range(_NBP):    # x bins
-            xs = np.clip(np.asarray(kx) + i * bin_size - off, 0, px_max)
-            ys = np.clip(np.asarray(ky) + j * bin_size - off, 0, py_max)
+            xs = np.clip(kx + i * bin_size - off, 0, px_max)
+            ys = np.clip(ky + j * bin_size - off, 0, py_max)
             block = pooled[:, jnp.asarray(xs), :, :][:, :, jnp.asarray(ys), :]
             feats.append(block)  # (n, nkx, nky, 8)
     # layout: t + 8·i + 32·j  → stack bins in (j, i) order then interleave o
     desc = jnp.stack(feats, axis=3)  # (n, nkx, nky, 16, 8)
-    desc = desc.reshape(n, len(kx) * len(ky), _NBP * _NBP * _NBO)
+    desc = desc.reshape(n, nkx * nky, _NBP * _NBP * _NBO)
+    return _normalize(desc)
 
-    norms = jnp.linalg.norm(desc, axis=-1)
-    # vl_dsift norm semantics: norm before clamping used for the contrast test
-    normed = desc / jnp.maximum(norms[..., None], 1e-12)
-    normed = jnp.minimum(normed, 0.2)
-    n2 = jnp.linalg.norm(normed, axis=-1, keepdims=True)
-    normed = normed / jnp.maximum(n2, 1e-12)
-    return normed, norms
+
+def _bin_addresses(local, grid, step: int, bin_size: int, pooled_shape):
+    """Where the 16 bins of the keypoints ``local`` — (n, s) indices ``ix·nky
+    + iy`` into one scale's ``grid`` — lie in that scale's pooled maps,
+    flattened over the image: (n, s, 16) positions ``x·py + y``, bins in
+    ``_sift_one_scale``'s (j, i) order, clipped as it clips them."""
+    nky = grid[1]
+    px, py = pooled_shape
+    bins = np.arange(_NBP) * bin_size - _bin_window(bin_size)[1]
+    x = (local // nky * step)[..., None] + np.tile(bins, _NBP)    # i fast
+    y = (local % nky * step)[..., None] + np.repeat(bins, _NBP)   # j slow
+    return jnp.clip(x, 0, px - 1) * py + jnp.clip(y, 0, py - 1)
 
 
 class SIFTExtractor(Transformer):
@@ -174,34 +216,70 @@ class SIFTExtractor(Transformer):
         self.num_scales = num_scales
         self.scale_step = scale_step
 
+    def _scales(self):
+        """``(bin_size, sigma, step)`` of each scale."""
+        for scale in range(self.num_scales):
+            bin_size = self.bin_size + 2 * scale  # VLFeat.cxx:71
+            yield (
+                bin_size, bin_size / _MAGNIF,     # VLFeat.cxx:85
+                self.step + scale * self.scale_step,
+            )
+
     def descriptors_batch(self, X) -> jnp.ndarray:
         """(n, X, Y, 1) → (n, N, 128) quantized descriptors."""
         gray = jnp.asarray(X)[..., 0].astype(jnp.float32)
         all_desc = []
-        for scale in range(self.num_scales):
-            bin_size = self.bin_size + 2 * scale  # VLFeat.cxx:71
-            sigma = bin_size / _MAGNIF            # VLFeat.cxx:85
-            smoothed = _smooth(gray, sigma)
-            step = self.step + scale * self.scale_step
-            desc, norms = _sift_one_scale(smoothed, bin_size, step)
-            # zero low-contrast descriptors (VLFeat.cxx:62,146)
-            desc = jnp.where(
-                (norms > _CONTRAST_THRESHOLD)[..., None], desc, 0.0
-            )
-            # short quantization: ×512, clamp 255 (VLFeat.cxx:237-249)
-            desc = jnp.minimum(jnp.floor(desc * 512.0), 255.0)
-            all_desc.append(desc)
+        for bin_size, sigma, step in self._scales():
+            desc, norms = _sift_one_scale(_smooth(gray, sigma), bin_size, step)
+            all_desc.append(_quantize(desc, norms))
         return jnp.concatenate(all_desc, axis=1)
+
+    def sampled_batch(self, X, columns) -> jnp.ndarray:
+        """(n, X, Y, 1) and (n, s) column indices in [0, N) → (n, 128, s):
+        ``trace_batch(X)`` at those columns of each image, made without the
+        rest. A column decodes to (scale, ix, iy) in the order
+        ``descriptors_batch`` joins and reshapes — scales outermost, ``ix·nky
+        + iy`` within one; every scale's pooled maps are made as the full
+        body makes them, and a column reads its 16 bins × 8 orientations
+        from its own scale's. Normalization, the contrast test and the
+        quantization read no other column. The (N, 128) stack, its
+        transpose and the join over scales are never built."""
+        gray = jnp.asarray(X)[..., 0].astype(jnp.float32)
+        n, xd, yd = gray.shape
+        with jax.named_scope("ks.featurize.sift_sampled"):
+            # one gather an image for all its columns: the scales' pooled
+            # maps side by side, each column's bins addressed in its own
+            # scale's (a gather a scale for every column costs four times
+            # the lanes read: 0.13 ms an image each, PERF.md §6, PR 34)
+            maps, at, first, base = [], None, 0, 0
+            for bin_size, sigma, step in self._scales():
+                grid = _grid(xd, yd, bin_size, step)
+                if not grid[0]:
+                    continue
+                pooled = _pooled_maps(_smooth(gray, sigma), bin_size)
+                # columns of other scales address a keypoint of this one
+                # here, and are not kept
+                local = jnp.clip(columns - first, 0, grid[0] * grid[1] - 1)
+                here = base + _bin_addresses(
+                    local, grid, step, bin_size, pooled.shape[1:3]
+                )
+                at = here if at is None else jnp.where(
+                    (columns >= first)[..., None], here, at
+                )
+                maps.append(pooled.reshape(n, -1, _NBO))
+                first += grid[0] * grid[1]
+                base += maps[-1].shape[1]
+            desc = jnp.take_along_axis(
+                jnp.concatenate(maps, axis=1), at.reshape(n, -1, 1), axis=1
+            ).reshape(n, -1, _NBP * _NBP * _NBO)
+            return jnp.swapaxes(_quantize(*_normalize(desc)), 1, 2)
 
     def num_descriptors(self, xd: int, yd: int) -> int:
         """N: grid points over the scales of an ``xd`` × ``yd`` image."""
-        total = 0
-        for scale in range(self.num_scales):
-            extent = _NBP * (self.bin_size + 2 * scale)
-            step = self.step + scale * self.scale_step
-            if xd >= extent and yd >= extent:
-                total += ((xd - extent) // step + 1) * ((yd - extent) // step + 1)
-        return total
+        return sum(
+            int(np.prod(_grid(xd, yd, bin_size, step)))
+            for bin_size, _, step in self._scales()
+        )
 
     def row_scratch_bytes(self, shape: Tuple[int, ...]) -> int:
         """What an image holds besides the (128, N) output while its
@@ -212,6 +290,20 @@ class SIFTExtractor(Transformer):
         _, xd, yd = shape[:3]
         stack = self.num_descriptors(xd, yd) * _NBP * _NBP * _NBO * 4
         return stack + 2 * xd * yd * _NBO * 4
+
+    def sampled_scratch_bytes(self, shape: Tuple[int, ...]) -> int:
+        """What an image holds besides its sample while ``sampled_batch``
+        makes it: every scale's pooled maps side by side for the one
+        gather, and a scale's eight orientation maps before and after the
+        box sums. At 500 × 375 that is 22.9 + 12 MB, not the 37.6 MB stack
+        besides."""
+        _, xd, yd = shape[:3]
+        positions = 2 * xd * yd
+        for bin_size, _, step in self._scales():
+            if _grid(xd, yd, bin_size, step)[0]:
+                window = _bin_window(bin_size)[0]
+                positions += (xd - window + 1) * (yd - window + 1)
+        return positions * _NBO * 4
 
     def trace_batch(self, X):
         # (n, N, 128) → (n, 128, N): the reference's column-major descriptor

@@ -58,6 +58,9 @@ class BatchPCATransformer(Transformer):
     """Per-item descriptor matrices (d, n_desc) → (dims, n_desc)
     (parity: BatchPCATransformer, PCA.scala:38-44)."""
 
+    #: a column's projection reads that column alone
+    column_wise = True
+
     def __init__(self, pca_mat):
         self.pca_mat = as_param(pca_mat)
 

@@ -281,48 +281,18 @@ class Sampler(Transformer):
         return x
 
 
-class ColumnSampler(Transformer):
-    """Sample ``num_samples`` random columns (with replacement) of each
-    (d, m) matrix item (parity: Sampling.scala:12-20). Used to subsample
-    descriptor matrices before PCA/GMM estimation.
-
-    The draw of a row is keyed on (seed, the row's index in the data set)
-    and on nothing else — ``jax.random.fold_in(PRNGKey(seed), row)`` — so
-    the same columns come out whole, in row slices of any size, chunk by
-    chunk and item by item, and another program can draw them too.
-    ``row_keyed``: segment dispatch hands ``trace_batch`` the rows' indices
-    (``compile/segment.py``), so the sampler is a member of the row-sliced
-    segment that makes the descriptors and the descriptor stack of a whole
-    data set never exists."""
+class RowKeyedTransformer(Transformer):
+    """A transformer whose output for a row depends on the row's index in
+    the data set (a draw keyed on it): ``trace_batch(X, rows=None)`` takes
+    the indices, from 0 where ``X`` is a whole data set. ``row_keyed``:
+    segment dispatch hands them over (``compile/segment.py``); here the
+    same rows get the same indices item by item and chunk by chunk."""
 
     #: ``trace_batch`` takes ``rows``: the data-set index of each row
     row_keyed = True
 
-    def __init__(self, num_samples_per_matrix: int, seed: int = 0):
-        self.num_samples = num_samples_per_matrix
-        self.seed = seed
-
-    def columns(self, rows, m: int):
-        """(len(rows), num_samples) int32 column draws for the rows whose
-        data-set indices are ``rows``, each in [0, m)."""
-        key = jax.random.PRNGKey(self.seed)
-        return jax.vmap(
-            lambda r: jax.random.randint(
-                jax.random.fold_in(key, r), (self.num_samples,), 0, m
-            )
-        )(jnp.asarray(rows, jnp.uint32))
-
-    def trace_batch(self, X, rows=None):
-        n, _, m = X.shape
-        if rows is None:
-            rows = jnp.arange(n)
-        return jnp.take_along_axis(
-            X, self.columns(rows, m)[:, None, :], axis=2
-        )
-
     def apply(self, x, row: int = 0):
-        x = jnp.asarray(x)
-        return x[:, self.columns(jnp.asarray([row]), x.shape[1])[0]]
+        return self.trace_batch(jnp.asarray(x)[None], jnp.asarray([row]))[0]
 
     def apply_batch(self, data):
         from ...data.chunked import ChunkedDataset
@@ -349,11 +319,46 @@ class ColumnSampler(Transformer):
         return data.map_batch(self.trace_batch)
 
     def sample_chunk(self, X, row_start: int):
-        """Sample one chunk of a chunked scan whose first row is row
-        ``row_start`` of the data set: the columns the whole data set would
-        give these rows. A lazy chunked chain re-runs on every scan and the
-        lineage contract requires identical chunks each time. Shared by the
-        chunked ``apply_batch`` path and callers that drive one combined
-        scan themselves (the ImageNet FV branch builder draws PCA + GMM
-        samples in a single featurize pass)."""
+        """One chunk of a chunked scan whose first row is row ``row_start``
+        of the data set: what the whole data set would give these rows. A
+        lazy chunked chain re-runs on every scan and the lineage contract
+        requires identical chunks each time. Shared by the chunked
+        ``apply_batch`` path and callers that drive one combined scan
+        themselves (the ImageNet FV branch builder draws PCA + GMM samples
+        in a single featurize pass)."""
         return self.trace_batch(X, row_start + jnp.arange(X.shape[0]))
+
+
+class ColumnSampler(RowKeyedTransformer):
+    """Sample ``num_samples`` random columns (with replacement) of each
+    (d, m) matrix item (parity: Sampling.scala:12-20). Used to subsample
+    descriptor matrices before PCA/GMM estimation.
+
+    The draw of a row is keyed on (seed, the row's index in the data set)
+    and on nothing else — ``jax.random.fold_in(PRNGKey(seed), row)`` — so
+    the same columns come out whole, in row slices of any size, chunk by
+    chunk and item by item, and another program can draw them too. As a
+    ``row_keyed`` member of the row-sliced segment that makes the
+    descriptors, the descriptor stack of a whole data set never exists."""
+
+    def __init__(self, num_samples_per_matrix: int, seed: int = 0):
+        self.num_samples = num_samples_per_matrix
+        self.seed = seed
+
+    def columns(self, rows, m: int):
+        """(len(rows), num_samples) int32 column draws for the rows whose
+        data-set indices are ``rows``, each in [0, m)."""
+        key = jax.random.PRNGKey(self.seed)
+        return jax.vmap(
+            lambda r: jax.random.randint(
+                jax.random.fold_in(key, r), (self.num_samples,), 0, m
+            )
+        )(jnp.asarray(rows, jnp.uint32))
+
+    def trace_batch(self, X, rows=None):
+        n, _, m = X.shape
+        if rows is None:
+            rows = jnp.arange(n)
+        return jnp.take_along_axis(
+            X, self.columns(rows, m)[:, None, :], axis=2
+        )

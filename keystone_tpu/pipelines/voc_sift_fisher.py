@@ -62,10 +62,12 @@ class SIFTFisherConfig:
 
 def _sample_descriptors(featurizer, train_images, per_img: int, seed: int):
     """One sampling pass: ``per_img`` columns of every training image's
-    descriptor matrix, ``featurizer`` and the sampler composed lazily so
-    that the sampler is a member of the row-sliced segment that makes the
-    descriptors (``compile/segment.py``) — the descriptor stack of the
-    whole training set is 37.6 MB an image at 500 × 375 and never exists."""
+    descriptor matrix, ``featurizer`` and the sampler composed lazily, so
+    that the optimizer sees SIFT → column-wise nodes → sampler and puts the
+    one node in their place that makes only the sampled descriptors
+    (``nodes/images/chain.py:SampledSIFTRule``) — an image's descriptor
+    stack, 37.6 MB at 500 × 375, is not built in a sampling pass, and that
+    of the whole training set never exists."""
     n = len(Dataset.of(train_images))
     with span(
         "voc.sample_descriptors", images=n, columns=n * per_img
@@ -90,7 +92,9 @@ def run(train_images, train_label_sets, test_images, test_label_sets,
     ``Cacher`` after the projection asks (the executor declines a cache
     the device cannot hold and computes the value again where it is read).
     The PCA and the codebook are fitted as soon as their sample is drawn:
-    the codebook's sample is drawn through the fitted projection."""
+    the codebook's sample is drawn through the fitted projection, ahead of
+    that ``Cacher`` — a cache is not column-wise, and a sampling pass
+    projects the sampled descriptors alone."""
     start = time.perf_counter()
     with span("job", pipeline="VOCSIFTFisher"):
         with span("plan.build"):
@@ -119,7 +123,8 @@ def run(train_images, train_label_sets, test_images, test_label_sets,
                 pca = ColumnPCAEstimator(conf.desc_dim).fit(
                     _sample_descriptors(sift, train_images, per_img, conf.seed)
                 )
-            pca_featurizer = sift.and_then(pca).and_then(Cacher())
+            projected = sift.and_then(pca)
+            pca_featurizer = projected.and_then(Cacher())
 
             if conf.gmm_mean_file:
                 gmm = GaussianMixtureModel.load(
@@ -134,7 +139,7 @@ def run(train_images, train_label_sets, test_images, test_label_sets,
                 fv = GMMFisherVectorEstimator(
                     conf.vocab_size, max_iterations=20, min_cluster_size=1
                 ).fit(_sample_descriptors(
-                    pca_featurizer, train_images, per_img, conf.seed + 1
+                    projected, train_images, per_img, conf.seed + 1
                 ))
                 vocab_size = conf.vocab_size
 

@@ -165,7 +165,7 @@ class DefaultOptimizer(Optimizer):
 
         return self._head_batches() + [
             Batch("Node Level Optimization", Strategy.ONCE, [NodeOptimizationRule()]),
-            self._conv_chain_batch(),
+            self._chains_batch(),
         ]
 
     def _head_batches(self) -> List[Batch]:
@@ -182,15 +182,20 @@ class DefaultOptimizer(Optimizer):
             ),
         ]
 
-    def _conv_chain_batch(self) -> Batch:
-        """Last batch always: a convolution chain becomes the one node that
-        keeps the convolution's output on the chip
-        (``nodes/images/chain.py``). Which nodes make one XLA program is
-        not the optimizer's decision: the executor's segment planner groups
-        what is left (``check/segments.py``)."""
-        from ..nodes.images.chain import ConvChainRule
+    def _chains_batch(self) -> Batch:
+        """Last batch always: a chain that one node computes with less
+        becomes that node (``nodes/images/chain.py``) — a convolution chain
+        the one that keeps the convolution's output on the chip, a sampling
+        pass over SIFT descriptors the one that makes only the sampled
+        ones. Which nodes make one XLA program is not the optimizer's
+        decision: the executor's segment planner groups what is left
+        (``check/segments.py``)."""
+        from ..nodes.images.chain import ConvChainRule, SampledSIFTRule
 
-        return Batch("Convolution Chain", Strategy.ONCE, [ConvChainRule()])
+        return Batch(
+            "Chains As One Node", Strategy.ONCE,
+            [ConvChainRule(), SampledSIFTRule()],
+        )
 
 
 class AutoCachingOptimizer(DefaultOptimizer):
@@ -215,5 +220,5 @@ class AutoCachingOptimizer(DefaultOptimizer):
                 Strategy.ONCE,
                 [AutoCacheRule(self.strategy, self.mem_budget_bytes)],
             ),
-            self._conv_chain_batch(),
+            self._chains_batch(),
         ]

@@ -54,6 +54,13 @@ class Transformer(Chainable, TransformerOperator):
     #: their output — and routes callers to ``apply`` instead.
     batch_coupled: bool = False
 
+    #: set True on transformers over per-item (d, m) matrices whose
+    #: trace_batch acts column by column: column c of an item's output
+    #: reads column c of its input and no other, so sampling columns
+    #: before it or after it gives the same sample
+    #: (``nodes/images/chain.py:SampledSIFTRule``).
+    column_wise: bool = False
+
     def apply(self, x: Any) -> Any:
         if self.trace_batch is not None:
             import jax.numpy as jnp

@@ -5,6 +5,12 @@ interpret mode cannot: that Mosaic accepts the kernel's tiling, that the
 convolution's (n, 27, 27, 10,000) output is nowhere an HBM buffer, and that
 what an image holds ahead of the kernel is what segment dispatch is told.
 
+The sampled SIFT body (``SampledSIFTExtractor``) at ``voc_fv256``'s widths
+is compiled here too, in this file because one file's tests go to one xdist
+worker and only one process may hold the TPU's library: that an image's
+(73,505, 128) descriptor stack is nowhere a buffer, and that what an image
+holds while its sample is made is what segment dispatch is told.
+
 The topology is described inside a fixture, never at import: one process at
 a time may load the TPU's library, and every xdist worker imports this file.
 """
@@ -17,14 +23,20 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from keystone_tpu.nodes.images.chain import ConvRectifyPool
+from keystone_tpu.nodes.images.chain import (
+    ConvRectifyPool,
+    SampledSIFTExtractor,
+)
 from keystone_tpu.nodes.images.core import (
     Convolver,
     ImageVectorizer,
     Pooler,
     SymmetricRectifier,
 )
+from keystone_tpu.nodes.images.sift import SIFTExtractor
+from keystone_tpu.nodes.learning.pca import BatchPCATransformer
 from keystone_tpu.nodes.learning.zca import ZCAWhitener
+from keystone_tpu.nodes.stats import ColumnSampler
 from keystone_tpu.ops import conv_rectify_pool as crp
 
 FILTERS, IMAGES, SIDE = 10000, 1024, 32
@@ -106,3 +118,64 @@ def test_an_images_scratch_is_what_dispatch_is_told(compiled):
     told = crp.scratch_bytes(27, 27, 13, 14)
     held = compiled.memory_analysis().temp_size_in_bytes / IMAGES
     assert 0.8 * told <= held <= 1.1 * told, (told, held)
+
+
+# -- the sampled SIFT body at voc_fv256's widths ------------------------------
+
+VOC_SLICE, VOC_X, VOC_Y, VOC_COLUMNS = 64, 500, 375, 651
+
+
+@pytest.fixture(scope="module")
+def sampled(one_chip):
+    """SIFT → projection → sampler as the codebook's sampling pass runs it:
+    the one node over one row slice, its first row's index an argument."""
+    from jax.experimental.compilation_cache import compilation_cache
+
+    rng = np.random.default_rng(0)
+    basis = np.linalg.qr(rng.standard_normal((128, 80)))[0].astype(np.float32)
+    node = SampledSIFTExtractor(
+        SIFTExtractor(), (BatchPCATransformer(basis),),
+        ColumnSampler(VOC_COLUMNS, seed=1),
+    )
+    images = jax.ShapeDtypeStruct(
+        (VOC_SLICE, VOC_X, VOC_Y, 1), jnp.float32, sharding=one_chip
+    )
+    row0 = jax.ShapeDtypeStruct((), jnp.int32, sharding=one_chip)
+    cache_was_on = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    try:
+        return node, jax.jit(
+            lambda X, r: node.trace_batch(
+                X, r + jnp.arange(VOC_SLICE, dtype=jnp.int32)
+            )
+        ).lower(images, row0).compile()
+    finally:
+        jax.config.update("jax_enable_compilation_cache", cache_was_on)
+        compilation_cache.reset_cache()
+
+
+def test_the_descriptor_stack_is_no_buffer_of_a_sampling_pass(sampled):
+    node, compiled = sampled
+    assert node.sift.num_descriptors(VOC_X, VOC_Y) == 73505
+    text = compiled.as_text()
+    shapes = [
+        [int(d) for d in s.split(",")]
+        for s in re.findall(r"(?:f32|s32)\[([\d,]+)\]", text)
+    ]
+    # nothing holds a scale's keypoint grid, let alone all four
+    grids = {162 * 120, 159 * 118, 157 * 115, 154 * 112, 73505}
+    assert not [s for s in shapes if grids & set(s)]
+    # the widest an image has is the four scales' pooled maps side by side
+    # for the one gather (5,737,248 values), never 73,505 × 128 (9,408,640)
+    widest = max(int(np.prod(s)) for s in shapes) / VOC_SLICE
+    assert widest == (495 * 370 + 492 * 367 + 489 * 364 + 486 * 361) * 8
+    assert len(re.findall(r" gather\(", text)) == 1
+    assert re.search(r"f32\[64,80,651\]", text)
+
+
+def test_a_sampled_images_scratch_is_what_dispatch_is_told(sampled):
+    node, compiled = sampled
+    told = node.row_scratch_bytes((VOC_SLICE, VOC_X, VOC_Y, 1))
+    held = compiled.memory_analysis().temp_size_in_bytes / VOC_SLICE
+    assert 0.9 * told <= held <= 1.1 * told, (told, held)

@@ -1,0 +1,160 @@
+"""``SIFTExtractor.sampled_batch`` and ``SampledSIFTExtractor``
+(``nodes/images/sift.py``, ``chain.py``): the descriptors at the sampler's
+columns, made without the rest, against the chain as written — all the
+descriptors, then ``ColumnSampler``. The same columns of the same
+descriptors: whole numbers after a floor, equal but for an off-by-one where
+two summation orders straddle one (none on the CPU at these sizes; the
+tolerance is the benchmark's, ``test_the_sampled_columns_are_the_references``).
+"""
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+from keystone_tpu.data.chunked import ChunkedDataset
+from keystone_tpu.data.dataset import Dataset
+from keystone_tpu.nodes.images.chain import SampledSIFTExtractor
+from keystone_tpu.nodes.images.sift import SIFTExtractor
+from keystone_tpu.nodes.learning.pca import BatchPCATransformer
+from keystone_tpu.nodes.stats import ColumnSampler
+
+X_DIM, Y_DIM = 64, 48  # 406 descriptors over four scales
+
+
+def _images(n, seed=0, x=X_DIM, y=Y_DIM):
+    """Textured grayscale images in [0, 1]: gradients everywhere, so
+    descriptors pass the contrast test."""
+    rng = np.random.default_rng(seed)
+    xx, yy = np.meshgrid(np.arange(x), np.arange(y), indexing="ij")
+    out = []
+    for _ in range(n):
+        f, t = rng.uniform(0.1, 0.4), rng.uniform(0, np.pi)
+        wave = np.sin(2 * np.pi * f * (np.cos(t) * xx + np.sin(t) * yy))
+        out.append(0.5 + 0.3 * wave + 0.1 * rng.standard_normal((x, y)))
+    return jnp.asarray(np.clip(out, 0, 1)[..., None], jnp.float32)
+
+
+def _assert_same_sample(got, want):
+    got, want = np.asarray(got), np.asarray(want)
+    assert got.shape == want.shape
+    assert np.abs(got - want).max() <= 1.0
+    assert np.mean(got != want) < 1e-3
+
+
+def _written(sift, sampler, X, rows, then=()):
+    D = sift.trace_batch(X)
+    for node in then:
+        D = node.trace_batch(D)
+    return sampler.trace_batch(D, rows)
+
+
+def test_the_sampled_body_makes_the_samplers_columns():
+    sift, sampler = SIFTExtractor(), ColumnSampler(60, seed=3)
+    X, rows = _images(6), jnp.arange(6)
+    assert sift.num_descriptors(X_DIM, Y_DIM) == 406
+    got = jax.jit(SampledSIFTExtractor(sift, (), sampler).trace_batch)(X, rows)
+    want = _written(sift, sampler, X, rows)
+    assert got.shape == (6, 128, 60)
+    _assert_same_sample(got, want)
+    assert 5.0 < np.asarray(want).mean() < 100.0  # not the zeros of a flat image
+
+
+def test_every_column_decodes_to_its_scale_and_keypoint():
+    """All 406 columns in order, and each scale's first and last twice
+    over: the sampled body is the full body where nothing is left out."""
+    sift = SIFTExtractor()
+    X = _images(2, seed=5)
+    full = np.asarray(sift.trace_batch(X))
+    columns = jnp.tile(jnp.arange(406, dtype=jnp.int32), (2, 1))
+    _assert_same_sample(sift.sampled_batch(X, columns), full)
+    # 17·11 + 14·9 + 11·6 + 9·3 keypoints: each scale's first and last
+    edges = np.asarray([0, 186, 187, 312, 313, 378, 379, 405], np.int32)
+    got = sift.sampled_batch(X, jnp.tile(jnp.asarray(edges), (2, 1)))
+    _assert_same_sample(got, full[:, :, edges])
+
+
+@pytest.mark.parametrize("slice_rows", [4, 32])
+def test_row_slices_draw_what_the_whole_data_set_draws(slice_rows):
+    """The draw is keyed on the data-set row: 32 images in slices of 4, or
+    as one batch whose first row is row 100 of its data set."""
+    sift, sampler = SIFTExtractor(), ColumnSampler(25, seed=11)
+    node = SampledSIFTExtractor(sift, (), sampler)
+    X = _images(32, seed=2)
+    rows = 100 + jnp.arange(32)
+    want = np.asarray(_written(sift, sampler, X, rows))
+    fn = jax.jit(node.trace_batch)
+    got = np.concatenate([
+        np.asarray(fn(X[a : a + slice_rows], rows[a : a + slice_rows]))
+        for a in range(0, 32, slice_rows)
+    ])
+    _assert_same_sample(got, want)
+    # and not the columns rows 0..31 would draw
+    assert not np.array_equal(got, np.asarray(fn(X, jnp.arange(32))))
+
+
+def test_a_flat_image_samples_zeros():
+    node = SampledSIFTExtractor(SIFTExtractor(), (), ColumnSampler(40, seed=1))
+    flat = jnp.full((2, X_DIM, Y_DIM, 1), 0.5, jnp.float32)
+    assert not np.asarray(node.trace_batch(flat)).any()
+
+
+def test_the_projection_of_the_sample_is_the_sample_of_the_projection():
+    rng = np.random.default_rng(4)
+    basis = np.linalg.qr(rng.standard_normal((128, 16)))[0].astype(np.float32)
+    sift, pca, sampler = (
+        SIFTExtractor(), BatchPCATransformer(basis), ColumnSampler(30, seed=8)
+    )
+    assert pca.column_wise and not sift.column_wise
+    X, rows = _images(5, seed=9), 7 + jnp.arange(5)
+    got = SampledSIFTExtractor(sift, (pca,), sampler).trace_batch(X, rows)
+    want = _written(sift, sampler, X, rows, then=(pca,))
+    assert got.shape == (5, 16, 30)
+    np.testing.assert_allclose(
+        np.asarray(got), np.asarray(want), rtol=1e-5, atol=1e-3
+    )
+
+
+def test_a_scale_that_does_not_fit_has_no_columns():
+    """A 36 × 30 image holds bins of 4 and 6 only (extents 16, 24, 32, 40
+    against 30): two scales' keypoints are all the columns there are."""
+    sift, sampler = SIFTExtractor(scale_step=1), ColumnSampler(50, seed=2)
+    X = _images(3, seed=6, x=36, y=30)
+    n = sift.num_descriptors(36, 30)
+    assert n == 7 * 5 + 4 * 2
+    got = SampledSIFTExtractor(sift, (), sampler).trace_batch(X)
+    _assert_same_sample(got, _written(sift, sampler, X, jnp.arange(3)))
+
+
+def test_items_and_chunks_draw_by_their_place_in_the_data_set():
+    """Node dispatch of the one node: an item list and a chunked scan give
+    each row the columns of its data-set index, as ``ColumnSampler`` does."""
+    sift, sampler = SIFTExtractor(num_scales=2), ColumnSampler(9, seed=5)
+    node = SampledSIFTExtractor(sift, (), sampler)
+    X = _images(6, seed=7)
+    want = np.asarray(node.trace_batch(X, jnp.arange(6)))
+    whole = node.apply_batch(Dataset.of(X)).to_array()
+    np.testing.assert_array_equal(np.asarray(whole), want)
+    items = node.apply_batch(Dataset.from_items([x for x in X])).collect()
+    np.testing.assert_array_equal(np.stack([np.asarray(i) for i in items]), want)
+    chunked = ChunkedDataset(lambda: iter([X[:4], X[4:]]), 6)
+    got = np.concatenate(
+        [np.asarray(c) for c in node.apply_batch(chunked).raw_chunks()]
+    )
+    np.testing.assert_array_equal(got, want)
+
+
+def test_a_row_is_priced_by_the_maps_and_not_by_the_stack():
+    """What segment dispatch is told an image holds (the compiled program's
+    own figure: tests/nodes/test_conv_rectify_pool_tpu_compile.py)."""
+    shape = (16, 500, 375, 1)
+    sift = SIFTExtractor()
+    node = SampledSIFTExtractor(sift, (), ColumnSampler(651))
+    maps = 2 * 500 * 375 * 8 * 4
+    # windows of 6, 9, 12 and 15: the four scales' pooled maps side by side
+    joined = (495 * 370 + 492 * 367 + 489 * 364 + 486 * 361) * 8 * 4
+    assert node.row_scratch_bytes(shape) == joined + maps == 34_948_992
+    assert sift.row_scratch_bytes(shape) == maps + 73505 * 128 * 4
+    assert node.row_keyed and node.binds_alone
+    assert node.rows_fact == "sift_sampled_rows"
+    assert "SIFTExtractor" in node.label

@@ -112,6 +112,8 @@ def test_fisher_leg_at_a_tiny_size():
     assert report["ok"] and report["finite"], report
     assert report["shape"]["descriptors"] == 406
     assert report["descriptor_max_gap"] <= 1.0
+    # the sampled body draws the sampler's columns of the same descriptors
+    assert report["sampled_max_gap"] <= 1.0 and report["sampled_share"] < 1e-3
     assert report["basis"] < 5e-3 and report["features"] < 5e-3, report
     assert chip_smoke.FISHER_SHAPE == dict(
         images=8, x=500, y=375, dims=80, centres=256

@@ -9,7 +9,9 @@ It drives one traced window of a cell exactly as ``benchmark/run.py
 harness keeps ``bench:`` annotations only). Printed, as one JSON line: the
 offset and its per-step scatter, the widest gap between an aligned span's
 edges and its annotation's, the idle split, what the content digest hashed
-and answered from memory a unit by span name, and — for the readers that
+and answered from memory a unit by span name, what segment dispatch did
+with the window's first segments (``rows``, the slices, ``conv_fused_rows``,
+``sift_sampled_rows``), and — for the readers that
 match device operations by name — whether the operations' names or stats
 carry the ``ks.*`` named scopes. ``--cpu`` rehearses the host side on the
 CPU with the tests' tiny benchmark (no device plane: no idle split).
@@ -289,6 +291,14 @@ def main(argv=None) -> int:
         name: {"bytes": b / units, "hits": h / units, "seconds": s / units}
         for name, (b, h, s) in digests.items()
     }
+    # what segment dispatch did with the first segments of the window: the
+    # slices, and the counts that say a fused body engaged
+    facts = ("label", "path", "rows", "row_slices", "slice_rows",
+             "conv_fused_rows", "sift_sampled_rows", "cache_declined_bytes")
+    out["segments"] = [
+        {k: sp.attrs[k] for k in facts if k in sp.attrs}
+        for sp in spans if sp.name == "exec.segment"
+    ][:16]
     roots = [(sp.name, sp.start, sp.end) for sp in spans if sp.name == "job"]
     offset = span_idle.offset_of(roots, anchors) if anchors else None
     if offset is not None:
