@@ -42,9 +42,10 @@ def _batched_solve(jointXTX, rhs, lam):
     d=4096, tens of images per class), and f32 Cholesky NaNs on the
     resulting near-semidefinite jointXTX. The reference survives because
     Breeze's ``\\`` is f64 LU (BlockWeightedLeastSquares.scala:294)."""
-    d = jointXTX.shape[-1]
-    G = jointXTX + lam * jnp.eye(d, dtype=jointXTX.dtype)
-    return jnp.linalg.solve(G, rhs[..., None])[..., 0]
+    with jax.named_scope("ks.solver.wls.solve"):
+        d = jointXTX.shape[-1]
+        G = jointXTX + lam * jnp.eye(d, dtype=jointXTX.dtype)
+        return jnp.linalg.solve(G, rhs[..., None])[..., 0]
 
 
 def _wls_stream_scan1_impl(

@@ -10,7 +10,21 @@ Mesh-native mapping of the reference's choreography (SURVEY §2.7): the
 "one class per partition" HashPartitioner trick becomes segment reductions
 over the class-index vector — per-class means via one segment_sum, per-class
 Grams via a chunked masked einsum — and the per-class executor-local solves
-become a vmapped batched Cholesky. No resharding of the data ever happens.
+become ONE batched solve a class chunk (``linalg/weighted.py:_batched_solve``:
+LU with partial pivoting, not Cholesky — a class covariance has rank at most
+the class's row count, far under d at the published widths, and a float32
+Cholesky of the near-semidefinite jointXTX gives NaNs; upstream's Breeze
+``\\`` is a float64 LU). No resharding of the data ever happens.
+
+Spans of the dense block step (``obs/tracer.py``): ``wls.block`` a feature
+block and pass, with ``path`` (``primal``: one d × d system a class; ``dual``:
+one (n + 3)² system a class in the span of the rows, taken where n + 3 < d),
+``class_systems`` (d × d class systems solved: k, and 0 on the dual path),
+``class_chunk`` (classes a dispatch) and ``gram_products`` (masked per-class
+Gram products formed, as ``block_ls.solve`` counts its own: k, 0 on the dual
+path); under it ``wls.stats`` (population and class moments, the cross
+terms), ``wls.class_grams`` and ``wls.class_solve`` a class chunk
+(``classes``, ``rows``, ``dims``) and ``wls.residual``.
 """
 
 from __future__ import annotations
@@ -66,7 +80,58 @@ def _class_stats(A, y_idx, k):
 @partial(jax.jit, static_argnames=())
 def _chunk_grams(A, mask_chunk):
     """Masked Grams for a chunk of classes: (C, d, d)."""
-    return jnp.einsum("nd,nc,ne->cde", A, mask_chunk, A)
+    with jax.named_scope("ks.solver.wls.gram"):
+        return jnp.einsum("nd,nc,ne->cde", A, mask_chunk, A)
+
+
+@jax.jit
+def _joint_xtx(grams, counts, mu_c, pop_mean, pop_cov, w):
+    """jointXTX of a class chunk (C, d, d) from its masked Grams:
+    ``(1−w)·popCov + w·classCov_c + w(1−w)·(μ_c − μ)(μ_c − μ)ᵀ``
+    (BlockWeightedLeastSquares.scala:236-262), one program, so that no
+    (C, d, d) term of the sum is a buffer of its own."""
+    with jax.named_scope("ks.solver.wls.gram"):
+        cnt = jnp.maximum(counts, 1.0)[:, None, None]
+        class_cov = grams / cnt - jnp.einsum("cd,ce->cde", mu_c, mu_c)
+        mean_diff = mu_c - pop_mean
+        return (
+            (1 - w) * pop_cov
+            + w * class_cov
+            + w * (1 - w) * jnp.einsum("cd,ce->cde", mean_diff, mean_diff)
+        )
+
+
+@partial(jax.jit, static_argnames=("dual",))
+def _block_gram(A, *, dual):
+    """What a block step keeps of all the rows across passes: the
+    population mean with the population covariance (primal) or the reduced
+    QR of Aᵀ (dual) — never both."""
+    pop_mean = jnp.mean(A, axis=0)
+    if dual:
+        return tuple(jnp.linalg.qr(A.T)), pop_mean  # (Q (d, n), R (n, n))
+    with jax.named_scope("ks.solver.wls.gram"):
+        n = A.shape[0]
+        return jnp.matmul(A.T, A) / n - jnp.outer(pop_mean, pop_mean), pop_mean
+
+
+@jax.jit
+def _cross_terms(A, R, onehot, counts):
+    """``(AᵀR / n, A_cᵀr_c / n_c)``, both (d, k), and the residual's
+    population and class means (k,)."""
+    n = A.shape[0]
+    cnt = jnp.maximum(counts, 1.0)
+    pop_xtr = jnp.matmul(A.T, R) / n
+    class_xtr = jnp.matmul(A.T, onehot * R) / cnt
+    return (
+        pop_xtr, class_xtr, jnp.mean(R, axis=0),
+        jnp.sum(onehot * R, axis=0) / cnt,
+    )
+
+
+@jax.jit
+def _residual_update(R, A, delta):
+    with jax.named_scope("ks.solver.wls.residual"):
+        return R - jnp.matmul(A, delta)
 
 
 # batched per-class ridge solve — shared with the streaming solver body,
@@ -215,7 +280,14 @@ class BlockWeightedLeastSquaresEstimator(LabelEstimator, CostModel):
         w = self.mixture_weight
         lam = self.lam
         n, k = Y.shape
-        y_idx = jnp.argmax(Y, axis=1)
+        # a row with no positive indicator belongs to none of these k
+        # classes (index k: an all-zero one-hot row) and enters the
+        # population statistics alone — so a solve over a share of the
+        # classes, Y cut to its columns, gives those classes' columns of
+        # the uncut solve: the class systems are independent
+        y_idx = jnp.where(
+            jnp.max(Y, axis=1) > 0, jnp.argmax(Y, axis=1), k
+        )
 
         counts = jnp.sum(
             jax.nn.one_hot(y_idx, k, dtype=jnp.float32), axis=0
@@ -240,34 +312,7 @@ class BlockWeightedLeastSquaresEstimator(LabelEstimator, CostModel):
                 # The cached per-block Gram is pop_cov (d×d) for the
                 # dense path, AAᵀ (n×n) for the dual path — never both.
                 use_dual = lam > 0 and (n + 3) < d
-                if stats[j] is None:
-                    pop_mean = jnp.mean(A, axis=0)
-                    _, class_means = _class_stats(A, y_idx, k)
-                    joint_means = w * class_means + (1 - w) * pop_mean
-                    if use_dual:
-                        gram = tuple(jnp.linalg.qr(A.T))  # (Q (d,n), R (n,n))
-                    else:
-                        gram = (A.T @ A) / n - jnp.outer(pop_mean, pop_mean)
-                    stats[j] = (gram, pop_mean, joint_means)
-                gram_j, pop_mean, joint_means = stats[j]
-                pop_cov = gram_j  # dense path; dual path unpacks (Q, R)
-                pop_xtr = (A.T @ R) / n  # (d, k)
-                residual_mean = jnp.mean(R, axis=0)  # (k,)
-
-                _, class_means = _class_stats(A, y_idx, k)
-                # per-class residual-column stats: r_c over class-c rows
-                class_r_sum = jnp.sum(onehot * R, axis=0)  # Σ_{i∈c} R[i, c]
-                class_r_mean = class_r_sum / jnp.maximum(counts, 1.0)
-                class_xtr = (A.T @ (onehot * R)) / jnp.maximum(
-                    counts, 1.0
-                )  # (d, k): A_cᵀ r_c / n_c per class
-
                 if use_dual:
-                    s3 = jnp.asarray(
-                        [-(1 - w), -w, w * (1 - w)], dtype=jnp.float32
-                    )
-                    # constant per block — projected once, not per chunk
-                    pm_proj = jnp.matmul(pop_mean, stats[j][0][0])
                     # dual systems are (n+3)² per class — far smaller than
                     # d² — so batch many more classes per dispatch (bound:
                     # ~256 MB of batched inner systems)
@@ -278,63 +323,96 @@ class BlockWeightedLeastSquaresEstimator(LabelEstimator, CostModel):
                     )
                 else:
                     C = max(1, self.class_chunk)
-                delta_cols = []
-                for c0 in range(0, k, C):
-                    cs = slice(c0, min(c0 + C, k))
-                    mu_c = class_means[cs]  # (C, d)
-                    mean_diff = mu_c - pop_mean  # (C, d)
-                    mean_mixture = (
-                        (1 - w) * residual_mean[cs] + w * class_r_mean[cs]
-                    )  # (C,)
-                    jointXTR = (
-                        (1 - w) * pop_xtr[:, cs].T
-                        + w * class_xtr[:, cs].T
-                        - joint_means[cs] * mean_mixture[:, None]
-                    )  # (C, d)
-                    rhs = jointXTR - lam * Ws[j][:, cs].T
-                    if use_dual:
-                        dvec = (1 - w) / n + w * onehot[:, cs].T \
-                            / jnp.maximum(counts[cs], 1.0)[:, None]  # (C, n)
-                        Qb, Rb = gram_j
-                        mu_proj = jnp.matmul(mu_c, Qb)  # (C, n)
-                        delta_cols.append(
-                            _dual_solve_chunk(
-                                Qb, Rb, shard_classes(dvec),
-                                pm_proj, shard_classes(mu_proj), s3,
-                                shard_classes(rhs), lam,
-                            )
-                        )
-                        continue
-                    # model-axis parallelism: the class dim of the masked
-                    # Grams and the batched per-class solves shards over
-                    # MODEL_AXIS (each model-device owns a slice of
-                    # classes); a 1-wide model axis makes this a no-op
-                    mask = shard_classes(onehot[:, cs], axis=1)  # (n, C)
-                    grams = _chunk_grams(A, mask)  # (C, d, d)
-                    cnt = counts[cs][:, None, None]
-                    class_cov = grams / jnp.maximum(cnt, 1.0) - jnp.einsum(
-                        "cd,ce->cde", mu_c, mu_c
-                    )
-                    jointXTX = (
-                        (1 - w) * pop_cov
-                        + w * class_cov
-                        + w * (1 - w) * jnp.einsum(
-                            "cd,ce->cde", mean_diff, mean_diff
-                        )
-                    )
-                    delta_cols.append(
-                        _batched_solve(
-                            shard_classes(jointXTX), shard_classes(rhs), lam
-                        )
-                    )
-                delta = jnp.concatenate(delta_cols, axis=0).T  # (d, k)
-                Ws[j] = Ws[j] + delta
                 # per-block span (parity: the reference's per-block solve
                 # timing logs, BlockWeightedLeastSquares.scala:177-313);
                 # syncs only under an installed tracer
-                with span("wls.block") as sp:
-                    R = R - A @ delta
-                    sp.sync_on(R)
+                with span(
+                    "wls.block", path="dual" if use_dual else "primal",
+                    class_systems=0 if use_dual else k, class_chunk=C,
+                    gram_products=0 if use_dual else k,
+                    rows=n, dims=d,
+                ) as block_span:
+                    with span("wls.stats", rows=n, dims=d) as sp:
+                        if stats[j] is None:
+                            gram, pop_mean = _block_gram(A, dual=use_dual)
+                            _, class_means = _class_stats(A, y_idx, k)
+                            joint_means = (
+                                w * class_means + (1 - w) * pop_mean
+                            )
+                            stats[j] = (
+                                gram, pop_mean, joint_means, class_means
+                            )
+                        gram_j, pop_mean, joint_means, class_means = stats[j]
+                        # per-class residual-column stats: r_c over the
+                        # rows of class c
+                        pop_xtr, class_xtr, residual_mean, class_r_mean = (
+                            _cross_terms(A, R, onehot, counts)
+                        )
+                        sp.sync_on(class_xtr)
+                    if use_dual:
+                        s3 = jnp.asarray(
+                            [-(1 - w), -w, w * (1 - w)], dtype=jnp.float32
+                        )
+                        # constant per block — projected once, not per chunk
+                        Qb, Rb = gram_j
+                        pm_proj = jnp.matmul(pop_mean, Qb)
+                    delta_cols = []
+                    for c0 in range(0, k, C):
+                        cs = slice(c0, min(c0 + C, k))
+                        mu_c = class_means[cs]  # (C, d)
+                        mean_mixture = (
+                            (1 - w) * residual_mean[cs]
+                            + w * class_r_mean[cs]
+                        )  # (C,)
+                        jointXTR = (
+                            (1 - w) * pop_xtr[:, cs].T
+                            + w * class_xtr[:, cs].T
+                            - joint_means[cs] * mean_mixture[:, None]
+                        )  # (C, d)
+                        rhs = jointXTR - lam * Ws[j][:, cs].T
+                        sized = dict(
+                            classes=int(mu_c.shape[0]), rows=n, dims=d
+                        )
+                        if use_dual:
+                            dvec = (1 - w) / n + w * onehot[:, cs].T \
+                                / jnp.maximum(counts[cs], 1.0)[:, None]
+                            mu_proj = jnp.matmul(mu_c, Qb)  # (C, n)
+                            with span("wls.class_solve", **sized) as sp:
+                                delta_cols.append(
+                                    _dual_solve_chunk(
+                                        Qb, Rb, shard_classes(dvec),
+                                        pm_proj, shard_classes(mu_proj), s3,
+                                        shard_classes(rhs), lam,
+                                    )
+                                )
+                                sp.sync_on(delta_cols[-1])
+                            continue
+                        # model-axis parallelism: the class dim of the
+                        # masked Grams and the batched per-class solves
+                        # shards over MODEL_AXIS (each model-device owns a
+                        # slice of classes); a 1-wide model axis makes
+                        # this a no-op
+                        with span("wls.class_grams", **sized) as sp:
+                            mask = shard_classes(onehot[:, cs], axis=1)
+                            jointXTX = _joint_xtx(
+                                _chunk_grams(A, mask), counts[cs], mu_c,
+                                pop_mean, gram_j, w,
+                            )
+                            sp.sync_on(jointXTX)
+                        with span("wls.class_solve", **sized) as sp:
+                            delta_cols.append(
+                                _batched_solve(
+                                    shard_classes(jointXTX),
+                                    shard_classes(rhs), lam,
+                                )
+                            )
+                            sp.sync_on(delta_cols[-1])
+                        del jointXTX  # 0.5 GB a chunk: not beside the next one's
+                    delta = jnp.concatenate(delta_cols, axis=0).T  # (d, k)
+                    Ws[j] = Ws[j] + delta
+                    with span("wls.residual", rows=n, dims=d):
+                        R = _residual_update(R, A, delta)
+                    block_span.sync_on(R)
 
         # final intercept (ref :310-315)
         b = joint_label_mean - sum(

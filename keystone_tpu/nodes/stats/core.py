@@ -77,10 +77,16 @@ class NormalizeRows(Transformer):
 
 
 class SignedHellingerMapper(Transformer):
-    """x → sign(x)·√|x| (parity: SignedHellingerMapper.scala:12-16)."""
+    """x → sign(x)·√|x| (parity: SignedHellingerMapper.scala:12-16; on a
+    descriptor matrix, BatchSignedHellingerMapper)."""
+
+    #: element by element: a column's root reads that column alone, so a
+    #: sampler may be drawn ahead of it (``nodes/images/chain.py``)
+    column_wise = True
 
     def trace_batch(self, X):
-        return jnp.sign(X) * jnp.sqrt(jnp.abs(X))
+        with jax.named_scope("ks.featurize.hellinger"):
+            return jnp.sign(X) * jnp.sqrt(jnp.abs(X))
 
 
 class TermFrequency(Transformer):

@@ -20,9 +20,22 @@ CSV checkpoints exactly like the reference (--siftPcaFile / --lcsGmmMeanFile
 …, ImageNetSiftLcsFV.scala:40-66).
 
 TPU-first notes: both featurizer branches are batched XLA programs over the
-canonical (n, X, Y, C) image batch; the per-class solve inside the weighted
-solver is a batched Cholesky on the MXU rather than the reference's per-class
-Spark partitions (BlockWeightedLeastSquares.scala:111-131).
+canonical (n, X, Y, C) image batch, members of ONE row-sliced segment (the
+gather joins them inside the program); the per-class solve inside the
+weighted solver is one batched pivoted LU a class chunk
+(``linalg/weighted.py:_batched_solve`` — not a Cholesky: a class covariance
+has rank at most the class's row count, and float32 Cholesky gives NaNs on
+the near-semidefinite jointXTX) rather than the reference's per-class Spark
+partitions (BlockWeightedLeastSquares.scala:111-131).
+
+A job featurizes the training images five times — each branch's PCA sample,
+each branch's codebook sample, the fit — and the held-out images once. A
+sampling pass is ONE lazily composed pull (descriptors → sampler), so the
+descriptors of a data set (6.9 MB an image of 256 × 256 for SIFT, 1.2 MB for
+LCS) never exist; each branch's PCA and codebook are fitted as soon as their
+sample is drawn and the sample is dropped; the ``Cacher`` after each
+branch's descriptors is kept where the device can hold its value and
+declined where it cannot (``compile/segment.py:unheld_caches``).
 """
 
 from __future__ import annotations
@@ -30,7 +43,7 @@ from __future__ import annotations
 import argparse
 import time
 from dataclasses import dataclass
-from typing import List, Optional
+from typing import NamedTuple, Optional
 
 import jax.numpy as jnp
 import numpy as np
@@ -90,6 +103,70 @@ class ImageNetSiftLcsFVConfig:
     seed: int = 0
 
 
+class TopKErrors(NamedTuple):
+    """Held-out error in percent (Stats.getErrPercent): the true label not
+    among the five best scores, and not the best."""
+
+    top5: float
+    top1: float
+
+
+def _sample_descriptors(
+    featurizer, train_images, per_img: int, seed: int, *, branch: str,
+    stage: str,
+):
+    """One sampling pass: ``per_img`` columns of every training image's
+    descriptor matrix, ``featurizer`` and the sampler composed lazily, so
+    that the pull is ONE row-sliced segment — and, where it is SIFT →
+    column-wise nodes → sampler, the one node that makes only the sampled
+    descriptors (``nodes/images/chain.py:SampledSIFTRule``)."""
+    n = len(Dataset.of(train_images))
+    with span(
+        "imagenet.sample_descriptors", branch=branch, stage=stage, images=n,
+        columns=n * per_img,
+    ) as sp:
+        sampler = ColumnSampler(per_img, seed=seed).to_pipeline()
+        sample = sampler(featurizer(train_images)).get()
+        sp.attrs["bytes"] = int(sample.to_array().nbytes)
+        sp.sync_on(sample.to_array())
+    return sample
+
+
+def _chunked_samples(
+    prefix, train_images, *, per_img: int, gmm_per_img: int, seed: int,
+    need_pca: bool, need_gmm: bool, branch: str,
+):
+    """Both samples of an out-of-core training set in ONE chunk-by-chunk
+    featurize scan, each drawn via its (seed, row index)-keyed
+    ``sample_chunk`` contract — the descriptor stacks of the full training
+    set never coexist in device memory (parity: ImageNetSiftLcsFV.scala:
+    98-135 never collects the descriptor RDD)."""
+    n = len(train_images)
+    with span(
+        "imagenet.sample_descriptors", branch=branch, stage="pca+gmm",
+        images=n, columns=n * (per_img * need_pca + gmm_per_img * need_gmm),
+    ) as sp:
+        s_pca = ColumnSampler(per_img, seed=seed)
+        s_gmm = ColumnSampler(gmm_per_img, seed=seed + 1)
+        pca_parts, gmm_parts = [], []
+        at = 0
+        for chunk in prefix(train_images).get().chunks():
+            if need_pca:
+                pca_parts.append(s_pca.sample_chunk(chunk, at))
+            if need_gmm:
+                gmm_parts.append(s_gmm.sample_chunk(chunk, at))
+            at += chunk.shape[0]
+        samples = [
+            Dataset(jnp.concatenate(parts, axis=0), batched=True)
+            if parts else None
+            for parts in (pca_parts, gmm_parts)
+        ]
+        kept = [s.to_array() for s in samples if s is not None]
+        sp.attrs["bytes"] = int(sum(a.nbytes for a in kept))
+        sp.sync_on(kept[0])
+    return samples
+
+
 def compute_pca_fisher_branch(
     prefix: Pipeline,
     train_images,
@@ -103,9 +180,13 @@ def compute_pca_fisher_branch(
     gmm_var_file: Optional[str] = None,
     gmm_wts_file: Optional[str] = None,
     seed: int = 0,
+    branch: str = "descriptors",
 ) -> Pipeline:
     """PCA + FV tail over a descriptor-extracting prefix
     (parity: computePCAandFisherBranch, ImageNetSiftLcsFV.scala:22-74).
+    ``prefix`` ends in the descriptors; the ``Cacher`` the reference puts
+    after them goes in here, behind the samplers: a cache is not
+    column-wise, and a sampling pass makes the sampled descriptors alone.
 
     The reference derives BOTH samplers from numPcaSamples and leaves
     numGmmSamples unused (ImageNetSiftLcsFV.scala:108,146-167); here the GMM
@@ -113,92 +194,79 @@ def compute_pca_fisher_branch(
     samples AFTER projecting the full descriptor set
     (sampler(pcaFeaturizer(data))); the PCA projection is per-column, so
     sampling first is distributionally identical and skips ~15× of
-    projection work (only sampled columns project). Out-of-core inputs
-    (``ChunkedDataset``) draw both samples in ONE chunk-by-chunk featurize
-    scan — the descriptor stacks for the full training set never coexist in
-    device memory (parity: ImageNetSiftLcsFV.scala:98-135 never collects
-    the descriptor RDD)."""
+    projection work (only sampled columns project). The PCA and the
+    codebook are fitted as soon as their sample is drawn — the codebook's
+    through the fitted projection — and the sample is dropped. Out-of-core
+    inputs (``ChunkedDataset``) draw both samples in one scan
+    (:func:`_chunked_samples`)."""
     from ..data.chunked import ChunkedDataset
-    need_pca_sample = not pca_file
-    need_gmm_sample = not gmm_mean_file
+
+    gmm_per_img = gmm_samples_per_image or num_col_samples_per_image
+    need_pca, need_gmm = not pca_file, not gmm_mean_file
+    chunked = isinstance(train_images, ChunkedDataset)
     pca_sample = desc_sample = None
-    if need_pca_sample or need_gmm_sample:
-        gmm_per_img = gmm_samples_per_image or num_col_samples_per_image
-        with span("imagenet.descriptors+samples") as sp:
-            prefix_out = prefix(train_images).get()
-            if isinstance(prefix_out, ChunkedDataset):
-                # both samplers share ONE featurize scan, each drawing via
-                # its (seed, row-index)-keyed sample_chunk contract
-                s_pca = ColumnSampler(num_col_samples_per_image, seed=seed)
-                s_gmm = ColumnSampler(gmm_per_img, seed=seed + 1)
-                pca_parts, gmm_parts = [], []
-                at = 0
-                for chunk in prefix_out.chunks():
-                    if need_pca_sample:
-                        pca_parts.append(s_pca.sample_chunk(chunk, at))
-                    if need_gmm_sample:
-                        gmm_parts.append(s_gmm.sample_chunk(chunk, at))
-                    at += chunk.shape[0]
-                if need_pca_sample:
-                    pca_sample = Dataset(
-                        jnp.concatenate(pca_parts, axis=0), batched=True
-                    )
-                if need_gmm_sample:
-                    desc_sample = Dataset(
-                        jnp.concatenate(gmm_parts, axis=0), batched=True
-                    )
+    with span("imagenet.codebook", branch=branch):
+        if chunked and (need_pca or need_gmm):
+            pca_sample, desc_sample = _chunked_samples(
+                prefix, train_images, per_img=num_col_samples_per_image,
+                gmm_per_img=gmm_per_img, seed=seed, need_pca=need_pca,
+                need_gmm=need_gmm, branch=branch,
+            )
+
+        if pca_file:
+            pca_mat = np.loadtxt(pca_file, delimiter=",", ndmin=2).T
+            # a loaded PCA matrix sets this branch's descriptor dim
+            desc_dim = int(pca_mat.shape[1])
+            pca = BatchPCATransformer(jnp.asarray(pca_mat, dtype=jnp.float32))
+        else:
+            if pca_sample is None:
+                pca_sample = _sample_descriptors(
+                    prefix, train_images, num_col_samples_per_image, seed,
+                    branch=branch, stage="pca",
+                )
+            pca = ColumnPCAEstimator(desc_dim).fit(pca_sample)
+            del pca_sample
+        projected = prefix.and_then(pca)
+
+        if gmm_mean_file:
+            gmm = GaussianMixtureModel.load(
+                gmm_mean_file, gmm_var_file, gmm_wts_file
+            )
+            fv = FisherVector(gmm)
+            # a loaded codebook sets this branch's FV width (see
+            # voc_sift_fisher)
+            vocab_size = int(gmm.k)
+        else:
+            if desc_sample is None:
+                gmm_sample = _sample_descriptors(
+                    projected, train_images, gmm_per_img, seed + 1,
+                    branch=branch, stage="gmm",
+                )
             else:
-                if need_pca_sample:
-                    pca_sample = ColumnSampler(
-                        num_col_samples_per_image, seed=seed
-                    ).apply_batch(prefix_out)
-                if need_gmm_sample:
-                    desc_sample = ColumnSampler(
-                        gmm_per_img, seed=seed + 1
-                    ).apply_batch(prefix_out)
-            sp.sync_on((pca_sample or desc_sample).to_array())
-
-    if pca_file:
-        pca_mat = np.loadtxt(pca_file, delimiter=",", ndmin=2).T
-        # a loaded PCA matrix sets this branch's descriptor dim
-        desc_dim = int(pca_mat.shape[1])
-        # to_pipeline() so both PCA sources expose the same Pipeline
-        # interface to the GMM-sample site below
-        pca_apply = BatchPCATransformer(
-            jnp.asarray(pca_mat, dtype=jnp.float32)
-        ).to_pipeline()
-        pca_featurizer = prefix.and_then(pca_apply)
-    else:
-        pca_apply = ColumnPCAEstimator(desc_dim).with_data(pca_sample)
-        pca_featurizer = prefix.and_then(pca_apply)
-
-    if gmm_mean_file:
-        gmm = GaussianMixtureModel.load(gmm_mean_file, gmm_var_file, gmm_wts_file)
-        fisher = pca_featurizer.and_then(FisherVector(gmm))
-        # a loaded codebook sets this branch's FV width (see voc_sift_fisher)
-        vocab_size = int(gmm.k)
-    else:
-        with span("imagenet.pca_fit+gmm_project") as sp:
-            gmm_sample = pca_apply(desc_sample).get()
-            sp.sync_on(gmm_sample.to_array())
-        fv = GMMFisherVectorEstimator(
-            vocab_size, max_iterations=20, min_cluster_size=1
-        ).with_data(gmm_sample)
-        fisher = pca_featurizer.and_then(fv)
+                gmm_sample = pca.apply_batch(desc_sample)
+                del desc_sample
+            fv = GMMFisherVectorEstimator(
+                vocab_size, max_iterations=20, min_cluster_size=1
+            ).fit(gmm_sample)
+            del gmm_sample
 
     # FloatToDouble is identity here: the FV tail stays f32 on TPU (the
     # reference widens for its f64 Breeze solver, ImageNetSiftLcsFV.scala:69).
-    branch = (
-        fisher.and_then(MatrixVectorizer())
+    branch_pipeline = (
+        prefix.and_then(Cacher())
+        .and_then(pca)
+        .and_then(fv)
+        .and_then(MatrixVectorizer())
         .and_then(NormalizeRows())
         .and_then(SignedHellingerMapper())
         .and_then(NormalizeRows())
     )
-    return branch, 2 * desc_dim * vocab_size
+    return branch_pipeline, 2 * desc_dim * vocab_size
 
 
 def build_predictor(train_images, train_int_labels, conf: ImageNetSiftLcsFVConfig):
-    """The full two-branch predictor pipeline (unfit estimator form)."""
+    """The full two-branch predictor pipeline (unfit estimator form; both
+    branches' PCA and codebook are fitted on the way)."""
     n_train = len(Dataset.of(train_images))
     per_img = max(1, conf.num_pca_samples // max(n_train, 1))
     per_img_gmm = max(1, conf.num_gmm_samples // max(n_train, 1))
@@ -206,15 +274,14 @@ def build_predictor(train_images, train_int_labels, conf: ImageNetSiftLcsFVConfi
         Dataset.of(train_int_labels)
     )
 
-    sift_prefix = (
+    sift_descriptors = (
         PixelScaler()
         .and_then(GrayScaler())
         .and_then(SIFTExtractor(scale_step=conf.sift_scale_step))
         .and_then(SignedHellingerMapper())  # BatchSignedHellingerMapper
-        .and_then(Cacher())
     )
     sift_branch, sift_width = compute_pca_fisher_branch(
-        sift_prefix,
+        sift_descriptors,
         train_images,
         num_col_samples_per_image=per_img,
         gmm_samples_per_image=per_img_gmm,
@@ -225,13 +292,14 @@ def build_predictor(train_images, train_int_labels, conf: ImageNetSiftLcsFVConfi
         gmm_var_file=conf.sift_gmm_var_file,
         gmm_wts_file=conf.sift_gmm_wts_file,
         seed=conf.seed,
+        branch="sift",
     )
 
-    lcs_prefix = LCSExtractor(
+    lcs_descriptors = LCSExtractor(
         conf.lcs_stride, conf.lcs_border, conf.lcs_patch
-    ).to_pipeline().and_then(Cacher())
+    ).to_pipeline()
     lcs_branch, lcs_width = compute_pca_fisher_branch(
-        lcs_prefix,
+        lcs_descriptors,
         train_images,
         num_col_samples_per_image=per_img,
         gmm_samples_per_image=per_img_gmm,
@@ -242,6 +310,7 @@ def build_predictor(train_images, train_int_labels, conf: ImageNetSiftLcsFVConfi
         gmm_var_file=conf.lcs_gmm_var_file,
         gmm_wts_file=conf.lcs_gmm_wts_file,
         seed=conf.seed + 17,
+        branch="lcs",
     )
 
     # parity: Pipeline.gather { sift :: lcs :: Nil } andThen VectorCombiner
@@ -280,12 +349,25 @@ def top_k_err_percent(predicted_topk, actual) -> float:
 
 def run(train_images, train_labels, test_images, test_labels,
         conf: ImageNetSiftLcsFVConfig):
-    """Returns (predictor pipeline, top-5 test error %, seconds)."""
+    """Returns (the fitted predictor pipeline, the held-out
+    :class:`TopKErrors` in percent, seconds). The predictor is fitted, then
+    the estimator-free pipeline applied: its chain is one segment the
+    executor can cut by rows; pulled unfitted, every fitted stage is
+    applied node by node to a whole data set."""
     start = time.perf_counter()
-    predictor = build_predictor(train_images, train_labels, conf)
-    test_predicted = predictor(test_images).get().to_array()
-    err = top_k_err_percent(test_predicted, test_labels)
-    return predictor, err, time.perf_counter() - start
+    with span("job", pipeline="ImageNetSiftLcsFV"):
+        with span("plan.build"):
+            predictor = build_predictor(train_images, train_labels, conf)
+        fitted = predictor.fit()
+        test_predicted = fitted.apply(test_images).to_array()
+        with span("eval.top_k", k=5) as sp:
+            topk = np.asarray(test_predicted)
+            errors = TopKErrors(
+                top5=top_k_err_percent(topk, test_labels),
+                top1=top_k_err_percent(topk[:, :1], test_labels),
+            )
+            sp.attrs.update(top5_error=errors.top5, top1_error=errors.top1)
+    return fitted, errors, time.perf_counter() - start
 
 
 def synthetic_gradient_imagenet(
@@ -497,10 +579,13 @@ def main(argv=None) -> int:
     p.add_argument("--lcsStride", type=int, default=4)
     p.add_argument("--lcsBorder", type=int, default=16)
     p.add_argument("--lcsPatch", type=int, default=6)
-    p.add_argument("--numPcaSamples", type=int, default=100_000)
-    p.add_argument("--numGmmSamples", type=int, default=100_000)
-    p.add_argument("--numClasses", type=int, default=16)
-    p.add_argument("--nTrain", type=int, default=256)
+    # the published widths (ImageNetSiftLcsFV.scala:146-167): 1e7 sampled
+    # descriptors for each PCA and each codebook, 1,000 classes
+    p.add_argument("--numPcaSamples", type=int, default=10_000_000)
+    p.add_argument("--numGmmSamples", type=int, default=10_000_000)
+    p.add_argument("--numClasses", type=int, default=NUM_CLASSES)
+    p.add_argument("--nTrain", type=int, default=256,
+                   help="synthetic images where no --trainLocation is given")
     p.add_argument("--nTest", type=int, default=64)
     for f in ("siftPcaFile", "siftGmmMeanFile", "siftGmmVarFile",
               "siftGmmWtsFile", "lcsPcaFile", "lcsGmmMeanFile",
@@ -547,10 +632,15 @@ def main(argv=None) -> int:
         te_i = np.asarray(test.data.to_array())
         te_l = test.labels
     else:
-        tr_i, tr_l = synthetic_imagenet(args.nTrain, conf.num_classes, seed=1)
-        te_i, te_l = synthetic_imagenet(args.nTest, conf.num_classes, seed=2)
-    _, err, seconds = run(tr_i, tr_l, te_i, te_l, conf)
-    print(f"TEST Error is {err}%")
+        tr_i, tr_l = synthetic_imagenet(
+            args.nTrain, conf.num_classes, size=args.imageSize, seed=1
+        )
+        te_i, te_l = synthetic_imagenet(
+            args.nTest, conf.num_classes, size=args.imageSize, seed=2
+        )
+    _, errors, seconds = run(tr_i, tr_l, te_i, te_l, conf)
+    print(f"TEST Error is {errors.top5}%")
+    print(f"TEST top-1 Error is {errors.top1}%")
     print(f"Pipeline took {seconds} s")
     return 0
 

@@ -158,3 +158,23 @@ def test_a_row_is_priced_by_the_maps_and_not_by_the_stack():
     assert node.row_keyed and node.binds_alone
     assert node.rows_fact == "sift_sampled_rows"
     assert "SIFTExtractor" in node.label
+
+
+def test_the_signed_root_is_column_wise_and_samples_through():
+    """``SignedHellingerMapper`` acts element by element: the root of the
+    sample is the sample of the roots (the ImageNet pipeline's SIFT branch
+    puts it between the extractor and the sampler)."""
+    from keystone_tpu.nodes.stats import SignedHellingerMapper
+
+    root = SignedHellingerMapper()
+    assert root.column_wise is True
+    sift, sampler = SIFTExtractor(scale_step=1), ColumnSampler(40, seed=5)
+    X, rows = _images(5, seed=2), jnp.arange(5)
+    got = jax.jit(
+        SampledSIFTExtractor(sift, (root,), sampler).trace_batch
+    )(X, rows)
+    want = _written(sift, sampler, X, rows, then=(root,))
+    assert got.shape == (5, 128, 40)
+    # roots of whole numbers 0..255, an off-by-one floor on a few of them
+    assert np.abs(np.asarray(got) ** 2 - np.asarray(want) ** 2).max() <= 1.001
+    assert np.mean(np.asarray(got) != np.asarray(want)) < 1e-3

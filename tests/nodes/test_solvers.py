@@ -413,3 +413,74 @@ def test_minimize_lbfgs_handles_flat_objective():
     w = minimize_lbfgs(vag, w0, max_iterations=30)
     np.testing.assert_allclose(np.asarray(w), w0)
     assert np.all(np.isfinite(np.asarray(w)))
+
+
+def _wls_block_spans(fit):
+    """The ``wls.block`` spans of ``fit()`` and what it returned."""
+    from keystone_tpu.obs import tracer as tracer_mod
+
+    tracer = tracer_mod.start()
+    try:
+        out = fit()
+    finally:
+        tracer_mod.stop()
+    return [sp for sp in tracer.spans() if sp.name == "wls.block"], out
+
+
+@pytest.mark.parametrize("rows_less_d", [-4, -3, -2], ids=["dual", "meet", "primal"])
+def test_block_weighted_paths_agree_where_they_meet(rows_less_d):
+    """``use_dual = lam > 0 and n + 3 < d``: at n + 3 = d − 1 the dual
+    path runs, at d and d + 1 the primal. With one block and one pass both
+    ARE the exact per-class system, so on either side of the line the
+    held-out predictions are the independent per-class oracle's — float32
+    ``highest``, within 2% of the scores' scale — and the span's ``path``
+    names the one that ran."""
+    rng = np.random.default_rng(13)
+    d, k = 64, 4
+    n = d + rows_less_d
+    y = np.arange(n) % k
+    rng.shuffle(y)
+    W = rng.standard_normal((d, k))
+    X = (rng.standard_normal((n, d)) + 0.5 * W.T[y]).astype(np.float32)
+    Y = -np.ones((n, k), dtype=np.float32)
+    Y[np.arange(n), y] = 1.0
+    X_test = rng.standard_normal((32, d)).astype(np.float32)
+    args = dict(lam=1e-3, mixture_weight=0.25)
+    spans, block = _wls_block_spans(
+        lambda: BlockWeightedLeastSquaresEstimator(d, 1, **args).fit(
+            Dataset.of(X), Dataset.of(Y)
+        )
+    )
+    exact = PerClassWeightedLeastSquaresEstimator(d, 1, **args).fit(
+        Dataset.of(X), Dataset.of(Y)
+    )
+    dual = n + 3 < d
+    (span,) = spans
+    assert span.attrs["path"] == ("dual" if dual else "primal")
+    assert span.attrs["class_systems"] == (0 if dual else k)
+    assert span.attrs["gram_products"] == (0 if dual else k)
+    assert (span.attrs["rows"], span.attrs["dims"]) == (n, d)
+    pb = np.asarray(block.apply_batch(Dataset.of(X_test)).to_array())
+    pe = np.asarray(exact.apply_batch(Dataset.of(X_test)).to_array())
+    np.testing.assert_allclose(pb, pe, atol=2e-2 * np.abs(pe).max())
+
+
+def test_block_weighted_rows_of_no_class_enter_the_population_alone():
+    """A row whose indicators are all −1 belongs to none of the k classes:
+    it enters the population statistics and no class's — so a solve over a
+    share of the classes (``Y`` cut to its columns, every row kept) gives
+    those classes' columns and intercepts of the uncut solve."""
+    rng = np.random.default_rng(5)
+    X, Y, _ = _class_data(rng, n=90, d=12, k=6)
+
+    def solve(Y):
+        m = BlockWeightedLeastSquaresEstimator(
+            12, 1, lam=1e-2, mixture_weight=0.25
+        ).fit(Dataset.of(X), Dataset.of(Y))
+        return np.asarray(m.xs[0]), np.asarray(m.b)
+
+    W, b = solve(Y)
+    for half in ([0, 1, 2], [3, 4, 5]):
+        W_half, b_half = solve(Y[:, half])
+        np.testing.assert_allclose(W_half, W[:, half], atol=1e-4)
+        np.testing.assert_allclose(b_half, b[half], atol=1e-4)

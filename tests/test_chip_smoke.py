@@ -120,6 +120,22 @@ def test_fisher_leg_at_a_tiny_size():
     )
 
 
+def test_weighted_leg_at_a_tiny_size():
+    """The leg's control flow on the CPU: the primal path (n + 3 >= d), the
+    program's float32 solve against the reference's float64 class systems
+    by held-out scores, and LCS against the reference's."""
+    report = chip_smoke.weighted_leg(
+        rows=96, dims=64, classes=4, held_out=32, images=3, size=64
+    )
+    assert report["ok"] and report["finite"], report
+    assert report["paths"] == ["primal"]
+    assert report["scores"] < 1e-3 and report["labels_agree"] == 1.0, report
+    assert report["shape"]["lcs_descriptors"] == 64
+    assert report["lcs"] < 1e-2 < report["lcs_scale"]
+    assert chip_smoke.WEIGHTED_SHAPE["dims"] == 4096
+    assert chip_smoke.WEIGHTED_SHAPE["rows"] >= 4096 + 256
+
+
 def test_a_forced_segment_demotion_is_seen():
     """A segment whose compiled program raises at run time is served node
     by node with a warning (tests/compile/test_segment.py pins that the

@@ -245,3 +245,32 @@ def test_a_small_job_samples_through_the_node_twice():
     assert [a["images"] for a in passes] == [n_train, n_train]
     assert [a["columns"] for a in passes] == [3200, 3200]
     assert [a["bytes"] for a in passes] == [3200 * 128 * 4, 3200 * 8 * 4]
+
+
+def test_the_rule_fires_through_the_signed_root_and_not_through_a_cache():
+    """The ImageNet pipeline's SIFT branch: ``SIFTExtractor →
+    SignedHellingerMapper → ColumnSampler`` becomes one node whose ``then``
+    is the root; with the reference's ``Cacher`` between the root and the
+    sampler the chain is left as written (a cache is not column-wise) —
+    which is why ``imagenet_sift_lcs_fv`` draws its samples ahead of it."""
+    from keystone_tpu.nodes.stats import SignedHellingerMapper
+
+    sift, root = SIFTExtractor(scale_step=1), SignedHellingerMapper()
+    sampler = ColumnSampler(9, seed=2)
+    pipeline = GrayScaler().and_then(sift).and_then(root).and_then(sampler)
+    graph, _ = SampledSIFTRule().apply(pipeline.graph, {})
+    ops = _ops(graph)
+    assert [type(op) for op in ops] == [GrayScaler, SampledSIFTExtractor]
+    assert ops[1].then == (root,) and ops[1].sift is sift
+    cached = (
+        GrayScaler().and_then(sift).and_then(root).and_then(Cacher())
+        .and_then(sampler)
+    )
+    graph, _ = SampledSIFTRule().apply(cached.graph, {})
+    assert graph is cached.graph
+    # through the projection too: root, then PCA, then the sampler
+    pca = _pca()
+    both = sift.and_then(root).and_then(pca).and_then(sampler)
+    graph, _ = SampledSIFTRule().apply(both.graph, {})
+    (fused,) = _ops(graph)
+    assert fused.then == (root, pca)
