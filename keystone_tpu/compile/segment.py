@@ -482,17 +482,40 @@ def _item_bytes(
         return 0
 
 
-def _runs_conv_kernel(steps: List[Step], shapes: List[Any]) -> bool:
-    """Whether a member of ``steps`` sends its rows through the fused
-    convolution kernel (``nodes/images/chain.py:ConvRectifyPool.kernel_mode``),
-    judged where it reads one of the inputs, whose ``shapes`` are known
-    without a trace."""
-    for op, slots in steps:
-        mode = getattr(op, "kernel_mode", None)
-        if mode is not None and slots and slots[0] < len(shapes):
-            if mode(shapes[slots[0]]) is not None:
-                return True
-    return False
+def _member_facts(
+    steps: List[Step], arrays: List[Any], slice_rows: int, rows: int
+) -> Dict[str, Any]:
+    """What the members that speak of their rows under names of their own
+    say of ``rows`` rows — ``segment_facts(shape, rows)``: the fused
+    convolution's ``conv_fused_rows`` where its kernel engages, the sampled
+    SIFT body's ``sift_sampled_rows`` and ``sift_sampled_path`` — each asked
+    at the shape its first input has where ``arrays`` go through
+    ``slice_rows`` at a time. The members ahead of the last such one are
+    evaluated abstractly (``jax.eval_shape``: no operation runs), none
+    where none speaks."""
+    import jax
+
+    from ..workflow.operators import GatherTransformerOperator
+
+    speaking = {
+        i for i, (op, _) in enumerate(steps) if hasattr(op, "segment_facts")
+    }
+    facts: Dict[str, Any] = {}
+    values: List[Any] = [
+        jax.ShapeDtypeStruct((slice_rows,) + a.shape[1:], a.dtype)
+        for a in arrays
+    ]
+    for i, (op, slots) in enumerate(steps[: max(speaking, default=-1) + 1]):
+        args = [values[s] for s in slots]
+        if i in speaking:
+            facts.update(op.segment_facts(args[0].shape, rows))
+        if i == max(speaking):
+            break
+        if isinstance(op, GatherTransformerOperator):
+            values.append(tuple(args))
+        else:
+            values.append(jax.eval_shape(op.trace_batch, *args))
+    return facts
 
 
 def _device_memory() -> Optional[Tuple[int, int]]:
@@ -774,14 +797,9 @@ class SegmentBinding:
                 )
                 if self.cache_declined_bytes:
                     facts["cache_declined_bytes"] = self.cache_declined_bytes
-                shapes = [(slice_rows,) + a.shape[1:] for a in arrays]
-                if _runs_conv_kernel(self.steps, shapes):
-                    facts["conv_fused_rows"] = rows
-                for op, _ in self.steps:
-                    # a member that counts its rows under a name of its own
-                    # (the sampled SIFT body: ``sift_sampled_rows``)
-                    if getattr(op, "rows_fact", None):
-                        facts[op.rows_fact] = rows
+                facts.update(
+                    _member_facts(self.steps, arrays, slice_rows, rows)
+                )
             if self.digest is not None:
                 seg_cost.record_run(
                     self.digest, time.perf_counter() - t0,

@@ -19,8 +19,13 @@ the same values, chosen from what the code can observe, with no option.
 commutes with everything that reads no other column, so
 :class:`SampledSIFTRule` puts :class:`SampledSIFTExtractor` at the sampler's
 place: it draws the sampler's columns first and makes only those descriptors
-(``SIFTExtractor.sampled_batch``) — 651 of an image's 73,505 in
-``voc_fv256`` — then applies the column-wise nodes to the sample.
+(``SIFTExtractor.sampled_batch``), then applies the column-wise nodes to the
+sample. How the columns are read off the pooled maps follows from the
+sample's share of the keypoint grid, from shapes alone
+(``SIFTExtractor.sampled_path``): 651 of an image's 73,505 in ``voc_fv256``
+(0.9%) as one gather of 16 eight-float bins a column, 1,220 of 13,436 in
+``imagenet_fv16`` (9.1%) as one take of 128-float rows from the grid's stack
+of raw bins.
 """
 
 from __future__ import annotations
@@ -81,6 +86,12 @@ class ConvRectifyPool(Transformer):
             shape[1] - S + 1, shape[2] - S + 1,
             self.pooler.stride, self.pooler.pool_size,
         )
+
+    def segment_facts(self, shape: Tuple[int, ...], rows: int) -> dict:
+        """What ``exec.segment`` says of ``rows`` images of ``shape``
+        through this node: their count where the fused kernel runs them."""
+        fused = self.kernel_mode(shape) is not None
+        return {"conv_fused_rows": rows} if fused else {}
 
     def trace_batch(self, X):
         mode = self.kernel_mode(X.shape)
@@ -193,11 +204,16 @@ class SampledSIFTExtractor(RowKeyedTransformer):
     """``sampler(then[-1](… then[0](sift(X))))`` for nodes ``then`` that
     act column by column: the sampler's draw first, the descriptors at the
     drawn columns alone, then the column-wise nodes on the sample. The same
-    columns of the same descriptors as the chain written out, whose (N,
-    128) stack of an image — 37.6 MB at 500 × 375 — is never built."""
+    columns of the same descriptors as the chain written out, whose
+    normalized, quantized and transposed (128, N) matrix of an image is
+    never built. Which of ``SIFTExtractor.sampled_batch``'s two bodies
+    reads the columns off the pooled maps follows from the sample's share
+    of the keypoint grid (``SIFTExtractor.sampled_path``, shapes alone):
+    651 of 73,505 (0.9%, ``voc_fv256``) are gathered bin by bin, and the
+    (N, 128) stack — 37.6 MB at 500 × 375 — is never built either; 1,220
+    of 13,436 (9.1%, ``imagenet_fv16``) are taken from the grid's raw
+    stack."""
 
-    #: ``exec.segment`` counts the rows through this node under this name
-    rows_fact = "sift_sampled_rows"
     #: dispatched as the chain it stands for was, as a compiled segment in
     #: row slices, even where it is the segment's only member
     #: (``compile/segment.py:bind_segment``): node dispatch would run the
@@ -215,7 +231,15 @@ class SampledSIFTExtractor(RowKeyedTransformer):
     def row_scratch_bytes(self, shape: Tuple[int, ...]) -> int:
         """What an image holds besides the sample while it is made
         (``compile/segment.py:_item_bytes``): 34.9 MB at 500 × 375."""
-        return self.sift.sampled_scratch_bytes(shape)
+        return self.sift.sampled_scratch_bytes(shape, self.sampler.num_samples)
+
+    def segment_facts(self, shape: Tuple[int, ...], rows: int) -> dict:
+        """What ``exec.segment`` says of ``rows`` images of ``shape``
+        through this node: their count, and the body that made the sample."""
+        path = self.sift.sampled_path(
+            shape[1], shape[2], self.sampler.num_samples
+        )
+        return {"sift_sampled_rows": rows, "sift_sampled_path": path}
 
     def trace_batch(self, X, rows=None):
         n, xd, yd = X.shape[:3]

@@ -37,6 +37,14 @@ _MAGNIF = 6.0
 _CONTRAST_THRESHOLD = 0.005
 _WINDOW_SIZE = 1.5
 
+# What the two bodies of ``SIFTExtractor.sampled_batch`` pay a row they move,
+# on a TPU v5e (``sampled_path`` has the readings; PERF.md §6, PR 36):
+# "bins" gathers rows of 8 floats — its one gather over the rows gathered —,
+# "grid" rows of 128 through the keypoint grid — what that body costs over
+# "bins" without its gather, a row of the grid
+_BIN_ROW_NS = 13.5
+_GRID_ROW_NS = 9.5
+
 
 def _gaussian_kernel1d(sigma: float) -> np.ndarray:
     radius = max(1, int(math.ceil(4.0 * sigma)))
@@ -148,18 +156,12 @@ def _quantize(desc, norms):
     return jnp.minimum(jnp.floor(desc * 512.0), 255.0)
 
 
-@partial(jax.jit, static_argnames=("bin_size", "step"))
-def _sift_one_scale(gray, bin_size: int, step: int):
-    """Descriptors for one scale over the keypoint grid.
-
-    gray: (n, X, Y) already smoothed. Returns (n, nkx·nky, 128) float
-    descriptors (un-normalized binning already weighted), plus norms.
-    """
-    n, xd, yd = gray.shape
-    pooled = _pooled_maps(gray, bin_size)
-    nkx, nky = _grid(xd, yd, bin_size, step)
-    if not nkx:
-        return jnp.zeros((n, 0, _NBP * _NBP * _NBO)), jnp.zeros((n, 0))
+def _binned(pooled, grid: Tuple[int, int], step: int, bin_size: int):
+    """One scale's (n, px, py, 8) pooled maps → (n, nkx·nky, 128): the raw
+    sums of the 4×4 bins of every keypoint of ``grid``, keypoints ``ix·nky +
+    iy``, elements in vl_dsift's layout t + 8·i + 32·j; not normalized."""
+    n = pooled.shape[0]
+    nkx, nky = grid
     kx = np.arange(nkx) * step
     ky = np.arange(nky) * step
 
@@ -169,7 +171,9 @@ def _sift_one_scale(gray, bin_size: int, step: int):
     # + stride-`step` slices (27% less HBM traffic by XLA's own count) —
     # and ran 1.5× SLOWER: stride-3 slices on the second-minor dim defeat
     # the TPU's vectorized loads worse than the gathers do. Measured,
-    # reverted; don't repeat.
+    # reverted; don't repeat. Nor ONE gather an axis for all four bins and
+    # a transpose in place of the 32 gathers and the stack: the sampled
+    # grid body reads 0.568 ms an image for 0.531 (PERF.md §6, PR 36).
     _, off = _bin_window(bin_size)
     px_max = pooled.shape[1] - 1
     py_max = pooled.shape[2] - 1
@@ -183,8 +187,22 @@ def _sift_one_scale(gray, bin_size: int, step: int):
             feats.append(block)  # (n, nkx, nky, 8)
     # layout: t + 8·i + 32·j  → stack bins in (j, i) order then interleave o
     desc = jnp.stack(feats, axis=3)  # (n, nkx, nky, 16, 8)
-    desc = desc.reshape(n, nkx * nky, _NBP * _NBP * _NBO)
-    return _normalize(desc)
+    return desc.reshape(n, nkx * nky, _NBP * _NBP * _NBO)
+
+
+@partial(jax.jit, static_argnames=("bin_size", "step"))
+def _sift_one_scale(gray, bin_size: int, step: int):
+    """Descriptors for one scale over the keypoint grid.
+
+    gray: (n, X, Y) already smoothed. Returns (n, nkx·nky, 128) float
+    descriptors (un-normalized binning already weighted), plus norms.
+    """
+    n, xd, yd = gray.shape
+    pooled = _pooled_maps(gray, bin_size)
+    grid = _grid(xd, yd, bin_size, step)
+    if not grid[0]:
+        return jnp.zeros((n, 0, _NBP * _NBP * _NBO)), jnp.zeros((n, 0))
+    return _normalize(_binned(pooled, grid, step, bin_size))
 
 
 def _bin_addresses(local, grid, step: int, bin_size: int, pooled_shape):
@@ -240,39 +258,82 @@ class SIFTExtractor(Transformer):
         rest. A column decodes to (scale, ix, iy) in the order
         ``descriptors_batch`` joins and reshapes — scales outermost, ``ix·nky
         + iy`` within one; every scale's pooled maps are made as the full
-        body makes them, and a column reads its 16 bins × 8 orientations
-        from its own scale's. Normalization, the contrast test and the
-        quantization read no other column. The (N, 128) stack, its
-        transpose and the join over scales are never built."""
+        body makes them, and a column's 16 bins × 8 orientations are read
+        from its own scale's by one of two bodies (``sampled_path``).
+        Normalization, the contrast test and the quantization read no other
+        column and see the s sampled rows alone; the (128, N) transpose is
+        never built."""
         gray = jnp.asarray(X)[..., 0].astype(jnp.float32)
-        n, xd, yd = gray.shape
+        _, xd, yd = gray.shape
+        path = self.sampled_path(xd, yd, columns.shape[1])
+        body = self._sampled_grid if path == "grid" else self._sampled_bins
         with jax.named_scope("ks.featurize.sift_sampled"):
-            # one gather an image for all its columns: the scales' pooled
-            # maps side by side, each column's bins addressed in its own
-            # scale's (a gather a scale for every column costs four times
-            # the lanes read: 0.13 ms an image each, PERF.md §6, PR 34)
-            maps, at, first, base = [], None, 0, 0
-            for bin_size, sigma, step in self._scales():
-                grid = _grid(xd, yd, bin_size, step)
-                if not grid[0]:
-                    continue
-                pooled = _pooled_maps(_smooth(gray, sigma), bin_size)
-                # columns of other scales address a keypoint of this one
-                # here, and are not kept
-                local = jnp.clip(columns - first, 0, grid[0] * grid[1] - 1)
-                here = base + _bin_addresses(
-                    local, grid, step, bin_size, pooled.shape[1:3]
-                )
-                at = here if at is None else jnp.where(
-                    (columns >= first)[..., None], here, at
-                )
-                maps.append(pooled.reshape(n, -1, _NBO))
-                first += grid[0] * grid[1]
-                base += maps[-1].shape[1]
-            desc = jnp.take_along_axis(
-                jnp.concatenate(maps, axis=1), at.reshape(n, -1, 1), axis=1
-            ).reshape(n, -1, _NBP * _NBP * _NBO)
+            desc = body(gray, columns)
             return jnp.swapaxes(_quantize(*_normalize(desc)), 1, 2)
+
+    def sampled_path(self, xd: int, yd: int, samples: int) -> str:
+        """Which body reads ``samples`` columns an image of ``xd`` × ``yd``
+        off the pooled maps, from shapes alone: ``"bins"`` gathers 16 rows
+        of 8 floats a column, ``"grid"`` reads all N rows of 128 floats
+        through the keypoint grid to take ``samples`` of them — whichever
+        moves its rows sooner by the two measured rates. One program each
+        on a TPU v5e, ms an image, bins | grid: 256 × 256 at steps 3-4-5-6,
+        1,220 of 13,436 columns (9.1%), slices of 256: 0.6745 | 0.5306
+        (the gather alone 0.2794); 500 × 375 at step 3, 651 of 73,505
+        (0.9%), slices of 64: 1.3562 | 1.8820 (the gather 0.1334). The
+        bodies cross where a sample is 4.4% of the grid."""
+        bins = _NBP * _NBP * samples * _BIN_ROW_NS
+        grid = self.num_descriptors(xd, yd) * _GRID_ROW_NS
+        return "grid" if grid < bins else "bins"
+
+    def _pooled_scales(self, gray):
+        """``(pooled maps, grid, step, bin_size)`` of each scale that fits
+        the images."""
+        _, xd, yd = gray.shape
+        for bin_size, sigma, step in self._scales():
+            grid = _grid(xd, yd, bin_size, step)
+            if grid[0]:
+                pooled = _pooled_maps(_smooth(gray, sigma), bin_size)
+                yield pooled, grid, step, bin_size
+
+    def _sampled_bins(self, gray, columns):
+        """The sparse body, (n, s, 128) raw sums: ONE gather an image of all
+        its columns' 16 × 8-float bins — the scales' pooled maps side by
+        side, each column's bins addressed in its own scale's (a gather a
+        scale for every column costs four times the lanes read: 0.13 ms an
+        image each, PERF.md §6, PR 34). The (N, 128) stack and the join
+        over scales are never built."""
+        n = gray.shape[0]
+        maps, at, first, base = [], None, 0, 0
+        for pooled, grid, step, bin_size in self._pooled_scales(gray):
+            # columns of other scales address a keypoint of this one
+            # here, and are not kept
+            local = jnp.clip(columns - first, 0, grid[0] * grid[1] - 1)
+            here = base + _bin_addresses(
+                local, grid, step, bin_size, pooled.shape[1:3]
+            )
+            at = here if at is None else jnp.where(
+                (columns >= first)[..., None], here, at
+            )
+            maps.append(pooled.reshape(n, -1, _NBO))
+            first += grid[0] * grid[1]
+            base += maps[-1].shape[1]
+        return jnp.take_along_axis(
+            jnp.concatenate(maps, axis=1), at.reshape(n, -1, 1), axis=1
+        ).reshape(n, -1, _NBP * _NBP * _NBO)
+
+    def _sampled_grid(self, gray, columns):
+        """The dense body, (n, s, 128) raw sums: every scale's bins at its
+        keypoint grid as the full body reads them (``_binned``), joined to
+        the (n, N, 128) stack of raw sums, and ONE take of the s sampled
+        rows of 512 bytes (a take a scale with a ``where`` over the scales
+        reads 0.571 ms an image where this reads 0.531, PERF.md §6,
+        PR 36)."""
+        stack = jnp.concatenate([
+            _binned(pooled, grid, step, bin_size)
+            for pooled, grid, step, bin_size in self._pooled_scales(gray)
+        ], axis=1)
+        return jnp.take_along_axis(stack, columns[..., None], axis=1)
 
     def num_descriptors(self, xd: int, yd: int) -> int:
         """N: grid points over the scales of an ``xd`` × ``yd`` image."""
@@ -291,19 +352,29 @@ class SIFTExtractor(Transformer):
         stack = self.num_descriptors(xd, yd) * _NBP * _NBP * _NBO * 4
         return stack + 2 * xd * yd * _NBO * 4
 
-    def sampled_scratch_bytes(self, shape: Tuple[int, ...]) -> int:
+    def sampled_scratch_bytes(
+        self, shape: Tuple[int, ...], samples: int
+    ) -> int:
         """What an image holds besides its sample while ``sampled_batch``
-        makes it: every scale's pooled maps side by side for the one
-        gather, and a scale's eight orientation maps before and after the
-        box sums. At 500 × 375 that is 22.9 + 12 MB, not the 37.6 MB stack
-        besides."""
+        makes ``samples`` columns of it: a scale's eight orientation maps
+        before and after the box sums, and what its body reads the columns
+        from. ``"bins"``: every scale's pooled maps side by side for the one
+        gather — at 500 × 375 that is 22.9 + 12 MB, not the 37.6 MB stack
+        besides. ``"grid"``: the (N, 128) stack of raw bins and the widest
+        scale's before it is joined — 6.9 + 3.4 + 4.2 MB at 256 × 256 and
+        scale step 1."""
         _, xd, yd = shape[:3]
-        positions = 2 * xd * yd
-        for bin_size, _, step in self._scales():
-            if _grid(xd, yd, bin_size, step)[0]:
-                window = _bin_window(bin_size)[0]
-                positions += (xd - window + 1) * (yd - window + 1)
-        return positions * _NBO * 4
+        maps = 2 * xd * yd * _NBO * 4
+        fits = [
+            (bin_size, int(np.prod(_grid(xd, yd, bin_size, step))))
+            for bin_size, _, step in self._scales()
+        ]
+        if self.sampled_path(xd, yd, samples) == "grid":
+            rows = sum(k for _, k in fits) + max(k for _, k in fits)
+            return maps + rows * _NBP * _NBP * _NBO * 4
+        windows = [_bin_window(bin_size)[0] for bin_size, k in fits if k]
+        positions = sum((xd - w + 1) * (yd - w + 1) for w in windows)
+        return maps + positions * _NBO * 4
 
     def trace_batch(self, X):
         # (n, N, 128) → (n, 128, N): the reference's column-major descriptor

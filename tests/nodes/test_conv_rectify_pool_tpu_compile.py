@@ -9,7 +9,10 @@ The sampled SIFT body (``SampledSIFTExtractor``) at ``voc_fv256``'s widths
 is compiled here too, in this file because one file's tests go to one xdist
 worker and only one process may hold the TPU's library: that an image's
 (73,505, 128) descriptor stack is nowhere a buffer, and that what an image
-holds while its sample is made is what segment dispatch is told.
+holds while its sample is made is what segment dispatch is told. And at
+``imagenet_fv16``'s widths, where the sample is dense enough to be read
+through the keypoint grid: the stack of raw bins is the widest buffer, the
+pooled maps are never joined, and the scratch is what dispatch is told.
 
 The topology is described inside a fixture, never at import: one process at
 a time may load the TPU's library, and every xdist worker imports this file.
@@ -36,7 +39,7 @@ from keystone_tpu.nodes.images.core import (
 from keystone_tpu.nodes.images.sift import SIFTExtractor
 from keystone_tpu.nodes.learning.pca import BatchPCATransformer
 from keystone_tpu.nodes.learning.zca import ZCAWhitener
-from keystone_tpu.nodes.stats import ColumnSampler
+from keystone_tpu.nodes.stats import ColumnSampler, SignedHellingerMapper
 from keystone_tpu.ops import conv_rectify_pool as crp
 
 FILTERS, IMAGES, SIDE = 10000, 1024, 32
@@ -125,34 +128,40 @@ def test_an_images_scratch_is_what_dispatch_is_told(compiled):
 VOC_SLICE, VOC_X, VOC_Y, VOC_COLUMNS = 64, 500, 375, 651
 
 
-@pytest.fixture(scope="module")
-def sampled(one_chip):
-    """SIFT → projection → sampler as the codebook's sampling pass runs it:
-    the one node over one row slice, its first row's index an argument."""
+def _compiled_slice(node, one_chip, rows, xd, yd):
+    """``node`` over one row slice, its first row's index an argument, as
+    segment dispatch runs it — compiled for the described chip."""
     from jax.experimental.compilation_cache import compilation_cache
 
-    rng = np.random.default_rng(0)
-    basis = np.linalg.qr(rng.standard_normal((128, 80)))[0].astype(np.float32)
-    node = SampledSIFTExtractor(
-        SIFTExtractor(), (BatchPCATransformer(basis),),
-        ColumnSampler(VOC_COLUMNS, seed=1),
-    )
     images = jax.ShapeDtypeStruct(
-        (VOC_SLICE, VOC_X, VOC_Y, 1), jnp.float32, sharding=one_chip
+        (rows, xd, yd, 1), jnp.float32, sharding=one_chip
     )
     row0 = jax.ShapeDtypeStruct((), jnp.int32, sharding=one_chip)
     cache_was_on = jax.config.jax_enable_compilation_cache
     jax.config.update("jax_enable_compilation_cache", False)
     compilation_cache.reset_cache()
     try:
-        return node, jax.jit(
+        return jax.jit(
             lambda X, r: node.trace_batch(
-                X, r + jnp.arange(VOC_SLICE, dtype=jnp.int32)
+                X, r + jnp.arange(rows, dtype=jnp.int32)
             )
         ).lower(images, row0).compile()
     finally:
         jax.config.update("jax_enable_compilation_cache", cache_was_on)
         compilation_cache.reset_cache()
+
+
+@pytest.fixture(scope="module")
+def sampled(one_chip):
+    """SIFT → projection → sampler as the codebook's sampling pass runs it:
+    the one node over one row slice."""
+    rng = np.random.default_rng(0)
+    basis = np.linalg.qr(rng.standard_normal((128, 80)))[0].astype(np.float32)
+    node = SampledSIFTExtractor(
+        SIFTExtractor(), (BatchPCATransformer(basis),),
+        ColumnSampler(VOC_COLUMNS, seed=1),
+    )
+    return node, _compiled_slice(node, one_chip, VOC_SLICE, VOC_X, VOC_Y)
 
 
 def test_the_descriptor_stack_is_no_buffer_of_a_sampling_pass(sampled):
@@ -178,4 +187,49 @@ def test_a_sampled_images_scratch_is_what_dispatch_is_told(sampled):
     node, compiled = sampled
     told = node.row_scratch_bytes((VOC_SLICE, VOC_X, VOC_Y, 1))
     held = compiled.memory_analysis().temp_size_in_bytes / VOC_SLICE
+    assert 0.9 * told <= held <= 1.1 * told, (told, held)
+
+
+# -- the sampled SIFT body at imagenet_fv16's widths --------------------------
+
+INET_SLICE, INET_SIDE, INET_COLUMNS = 256, 256, 1220
+
+
+@pytest.fixture(scope="module")
+def sampled_dense(one_chip):
+    """SIFT at scale step 1 → signed root → sampler as the PCA's sampling
+    pass of ``imagenet_fv16`` runs it: 1,220 of 13,436 columns an image."""
+    node = SampledSIFTExtractor(
+        SIFTExtractor(scale_step=1), (SignedHellingerMapper(),),
+        ColumnSampler(INET_COLUMNS, seed=1),
+    )
+    compiled = _compiled_slice(node, one_chip, INET_SLICE, INET_SIDE, INET_SIDE)
+    return node, compiled
+
+
+def test_a_dense_sample_is_taken_from_the_grids_stack(sampled_dense):
+    node, compiled = sampled_dense
+    assert node.sift.num_descriptors(INET_SIDE, INET_SIDE) == 13436
+    assert node.sift.sampled_path(INET_SIDE, INET_SIDE, INET_COLUMNS) == "grid"
+    text = compiled.as_text()
+    shapes = [
+        [int(d) for d in s.split(",")]
+        for s in re.findall(r"(?:f32|s32)\[([\d,]+)\]", text)
+    ]
+    # the widest an image has is the (13,436, 128) stack of raw bins; the
+    # four scales' pooled maps (251² + 248² + 245² + 242² positions of 8)
+    # are never laid side by side, and no (128, 13,436) transpose exists
+    widest = max(int(np.prod(s)) for s in shapes) / INET_SLICE
+    assert widest == 13436 * 128
+    joined = 251 * 251 + 248 * 248 + 245 * 245 + 242 * 242
+    assert not [s for s in shapes if joined in s]
+    assert not [s for s in shapes if s[-2:] == [128, 13436]]
+    assert re.search(r"f32\[256,128,1220\]", text)
+
+
+def test_a_dense_samples_scratch_is_what_dispatch_is_told(sampled_dense):
+    node, compiled = sampled_dense
+    told = node.row_scratch_bytes((INET_SLICE, INET_SIDE, INET_SIDE, 1))
+    assert told == 14_432_768
+    held = compiled.memory_analysis().temp_size_in_bytes / INET_SLICE
     assert 0.9 * told <= held <= 1.1 * told, (told, held)

@@ -5,6 +5,11 @@ descriptors, then ``ColumnSampler``. The same columns of the same
 descriptors: whole numbers after a floor, equal but for an off-by-one where
 two summation orders straddle one (none on the CPU at these sizes; the
 tolerance is the benchmark's, ``test_the_sampled_columns_are_the_references``).
+
+``sampled_batch`` reads the columns off the pooled maps by one of two bodies,
+chosen from shapes (``SIFTExtractor.sampled_path``): every case here runs on
+both — the fixture ``path`` answers for the rule — and the rule itself is
+held to the two configurations' shapes.
 """
 
 import jax
@@ -20,6 +25,15 @@ from keystone_tpu.nodes.learning.pca import BatchPCATransformer
 from keystone_tpu.nodes.stats import ColumnSampler
 
 X_DIM, Y_DIM = 64, 48  # 406 descriptors over four scales
+
+
+@pytest.fixture(params=["grid", "bins"])
+def path(request, monkeypatch):
+    """Both bodies, whatever the rule would choose at these small shapes."""
+    monkeypatch.setattr(
+        SIFTExtractor, "sampled_path", lambda self, xd, yd, s: request.param
+    )
+    return request.param
 
 
 def _images(n, seed=0, x=X_DIM, y=Y_DIM):
@@ -49,7 +63,7 @@ def _written(sift, sampler, X, rows, then=()):
     return sampler.trace_batch(D, rows)
 
 
-def test_the_sampled_body_makes_the_samplers_columns():
+def test_the_sampled_body_makes_the_samplers_columns(path):
     sift, sampler = SIFTExtractor(), ColumnSampler(60, seed=3)
     X, rows = _images(6), jnp.arange(6)
     assert sift.num_descriptors(X_DIM, Y_DIM) == 406
@@ -60,7 +74,7 @@ def test_the_sampled_body_makes_the_samplers_columns():
     assert 5.0 < np.asarray(want).mean() < 100.0  # not the zeros of a flat image
 
 
-def test_every_column_decodes_to_its_scale_and_keypoint():
+def test_every_column_decodes_to_its_scale_and_keypoint(path):
     """All 406 columns in order, and each scale's first and last twice
     over: the sampled body is the full body where nothing is left out."""
     sift = SIFTExtractor()
@@ -75,7 +89,7 @@ def test_every_column_decodes_to_its_scale_and_keypoint():
 
 
 @pytest.mark.parametrize("slice_rows", [4, 32])
-def test_row_slices_draw_what_the_whole_data_set_draws(slice_rows):
+def test_row_slices_draw_what_the_whole_data_set_draws(slice_rows, path):
     """The draw is keyed on the data-set row: 32 images in slices of 4, or
     as one batch whose first row is row 100 of its data set."""
     sift, sampler = SIFTExtractor(), ColumnSampler(25, seed=11)
@@ -93,13 +107,13 @@ def test_row_slices_draw_what_the_whole_data_set_draws(slice_rows):
     assert not np.array_equal(got, np.asarray(fn(X, jnp.arange(32))))
 
 
-def test_a_flat_image_samples_zeros():
+def test_a_flat_image_samples_zeros(path):
     node = SampledSIFTExtractor(SIFTExtractor(), (), ColumnSampler(40, seed=1))
     flat = jnp.full((2, X_DIM, Y_DIM, 1), 0.5, jnp.float32)
     assert not np.asarray(node.trace_batch(flat)).any()
 
 
-def test_the_projection_of_the_sample_is_the_sample_of_the_projection():
+def test_the_projection_of_the_sample_is_the_sample_of_the_projection(path):
     rng = np.random.default_rng(4)
     basis = np.linalg.qr(rng.standard_normal((128, 16)))[0].astype(np.float32)
     sift, pca, sampler = (
@@ -115,7 +129,7 @@ def test_the_projection_of_the_sample_is_the_sample_of_the_projection():
     )
 
 
-def test_a_scale_that_does_not_fit_has_no_columns():
+def test_a_scale_that_does_not_fit_has_no_columns(path):
     """A 36 × 30 image holds bins of 4 and 6 only (extents 16, 24, 32, 40
     against 30): two scales' keypoints are all the columns there are."""
     sift, sampler = SIFTExtractor(scale_step=1), ColumnSampler(50, seed=2)
@@ -126,7 +140,7 @@ def test_a_scale_that_does_not_fit_has_no_columns():
     _assert_same_sample(got, _written(sift, sampler, X, jnp.arange(3)))
 
 
-def test_items_and_chunks_draw_by_their_place_in_the_data_set():
+def test_items_and_chunks_draw_by_their_place_in_the_data_set(path):
     """Node dispatch of the one node: an item list and a chunked scan give
     each row the columns of its data-set index, as ``ColumnSampler`` does."""
     sift, sampler = SIFTExtractor(num_scales=2), ColumnSampler(9, seed=5)
@@ -144,7 +158,7 @@ def test_items_and_chunks_draw_by_their_place_in_the_data_set():
     np.testing.assert_array_equal(got, want)
 
 
-def test_a_row_is_priced_by_the_maps_and_not_by_the_stack():
+def test_a_row_is_priced_by_what_its_body_reads_the_columns_from():
     """What segment dispatch is told an image holds (the compiled program's
     own figure: tests/nodes/test_conv_rectify_pool_tpu_compile.py)."""
     shape = (16, 500, 375, 1)
@@ -156,11 +170,78 @@ def test_a_row_is_priced_by_the_maps_and_not_by_the_stack():
     assert node.row_scratch_bytes(shape) == joined + maps == 34_948_992
     assert sift.row_scratch_bytes(shape) == maps + 73505 * 128 * 4
     assert node.row_keyed and node.binds_alone
-    assert node.rows_fact == "sift_sampled_rows"
+    assert node.segment_facts(shape, 16) == {
+        "sift_sampled_rows": 16, "sift_sampled_path": "bins"
+    }
     assert "SIFTExtractor" in node.label
+    # imagenet_fv16: the grid's stack of raw bins, not the joined maps
+    shape = (256, 256, 256, 1)
+    sift = SIFTExtractor(scale_step=1)
+    node = SampledSIFTExtractor(sift, (), ColumnSampler(1221))
+    assert sift.num_descriptors(256, 256) == 13436
+    maps = 2 * 256 * 256 * 8 * 4
+    # 81² + 59² + 45² + 37² rows of raw bins joined, and the widest scale's
+    # before it is joined
+    stack = (13436 + 81 * 81) * 128 * 4
+    assert node.row_scratch_bytes(shape) == stack + maps == 14_432_768
+    assert node.segment_facts(shape, 256) == {
+        "sift_sampled_rows": 256, "sift_sampled_path": "grid"
+    }
 
 
-def test_the_signed_root_is_column_wise_and_samples_through():
+def test_the_two_bodies_give_the_same_sample():
+    """Equal on the CPU, element for element: the same sums of the same
+    pooled maps, read by two access patterns."""
+    sift = SIFTExtractor(scale_step=1)
+    X = _images(4, seed=12)
+    columns = ColumnSampler(90, seed=4).columns(
+        jnp.arange(4), sift.num_descriptors(X_DIM, Y_DIM)
+    )
+    gray = X[..., 0]
+    grid = np.asarray(sift._sampled_grid(gray, columns))
+    bins = np.asarray(sift._sampled_bins(gray, columns))
+    assert grid.shape == bins.shape == (4, 90, 128)
+    np.testing.assert_array_equal(grid, bins)
+    assert grid.any()
+
+
+@pytest.mark.parametrize(
+    "sift, shape, samples, want",
+    [
+        (SIFTExtractor(scale_step=1), (256, 256), 1221, "grid"),
+        (SIFTExtractor(), (500, 375), 651, "bins"),
+    ],
+    ids=["imagenet_fv16", "voc_fv256"],
+)
+def test_the_body_is_chosen_from_shapes_alone(
+    sift, shape, samples, want, monkeypatch
+):
+    """The sample's share of the keypoint grid decides — 9.1% of 13,436
+    against 0.9% of 73,505 — under ``jax.eval_shape``: no array exists."""
+    ran = []
+
+    def watched(name, body):
+        def run(self, gray, columns):
+            ran.append(name)
+            return body(self, gray, columns)
+        return run
+
+    for name in ("grid", "bins"):
+        attr = "_sampled_" + name
+        monkeypatch.setattr(
+            SIFTExtractor, attr, watched(name, getattr(SIFTExtractor, attr))
+        )
+    assert sift.sampled_path(*shape, samples) == want
+    out = jax.eval_shape(
+        sift.sampled_batch,
+        jax.ShapeDtypeStruct((2,) + shape + (1,), jnp.float32),
+        jax.ShapeDtypeStruct((2, samples), jnp.int32),
+    )
+    assert out.shape == (2, 128, samples)
+    assert ran == [want]
+
+
+def test_the_signed_root_is_column_wise_and_samples_through(path):
     """``SignedHellingerMapper`` acts element by element: the root of the
     sample is the sample of the roots (the ImageNet pipeline's SIFT branch
     puts it between the extractor and the sampler)."""

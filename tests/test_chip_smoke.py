@@ -11,6 +11,7 @@ import pytest
 
 import chip_smoke
 from keystone_tpu import compile as cmod
+from keystone_tpu.nodes.images.sift import SIFTExtractor
 from keystone_tpu.obs import tracer as tracer_mod
 from keystone_tpu.utils import timing
 
@@ -114,6 +115,12 @@ def test_fisher_leg_at_a_tiny_size():
     assert report["descriptor_max_gap"] <= 1.0
     # the sampled body draws the sampler's columns of the same descriptors
     assert report["sampled_max_gap"] <= 1.0 and report["sampled_share"] < 1e-3
+    # both counts are dense at this size; at the chip's, 2,000 and 8,000 of
+    # 73,505 columns lie on either side of the rule
+    assert report["sampled_paths"] == ["grid"]
+    sift = SIFTExtractor()
+    assert sift.sampled_path(500, 375, 2000) == "bins"
+    assert sift.sampled_path(500, 375, 8000) == "grid"
     assert report["basis"] < 5e-3 and report["features"] < 5e-3, report
     assert chip_smoke.FISHER_SHAPE == dict(
         images=8, x=500, y=375, dims=80, centres=256

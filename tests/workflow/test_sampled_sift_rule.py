@@ -196,6 +196,7 @@ def test_the_node_alone_is_dispatched_as_a_compiled_segment():
     (seg,) = segments
     assert seg["path"] == "compiled" and seg["nodes"] == 1
     assert seg["rows"] == seg["sift_sampled_rows"] == 6
+    assert seg["sift_sampled_path"] == SIFTExtractor().sampled_path(40, 36, 7)
     assert not [sp for sp in spans if sp.name == "node.SampledSIFTExtractor"]
     clear_memo()
     graph, _ = DefaultOptimizer().execute(pipeline(X).graph)
@@ -212,6 +213,23 @@ def test_a_full_sift_segment_counts_no_sampled_rows():
     segments = [sp.attrs for sp in spans if sp.name == "exec.segment"]
     assert segments and all("SIFTExtractor" in s["label"] for s in segments)
     assert not any("sift_sampled_rows" in s for s in segments)
+    assert not any("sift_sampled_path" in s for s in segments)
+
+
+@pytest.mark.parametrize("samples, want", [(60, "grid"), (2, "bins")])
+def test_a_sampled_segment_says_which_body_made_its_sample(samples, want):
+    """A sample on either side of the rule at 40 × 36 (99 descriptors): the
+    span names the body that ran beside the rows it counts."""
+    X = _gray(4)
+    assert SIFTExtractor().sampled_path(40, 36, samples) == want
+    pipeline = (
+        Cacher().and_then(SIFTExtractor())
+        .and_then(ColumnSampler(samples, seed=3))
+    )
+    _, spans = _traced(lambda: pipeline(X).get().to_array())
+    (seg,) = [sp.attrs for sp in spans if sp.name == "exec.segment"]
+    assert seg["label"] == "SampledSIFTExtractor"
+    assert seg["sift_sampled_rows"] == 4 and seg["sift_sampled_path"] == want
 
 
 def test_a_small_job_samples_through_the_node_twice():
@@ -235,6 +253,8 @@ def test_a_small_job_samples_through_the_node_twice():
     sampled = [s for s in segments if "sift_sampled_rows" in s]
     assert [s["sift_sampled_rows"] for s in sampled] == [n_train, n_train]
     assert all(s["label"] == "SampledSIFTExtractor" for s in sampled)
+    # 100 of a 48 × 48 image's 247 descriptors: read through the grid
+    assert [s["sift_sampled_path"] for s in sampled] == ["grid", "grid"]
     # the codebook's sample is drawn ahead of the cache the device declines
     assert not any("cache_declined_bytes" in s for s in sampled)
     assert all(
@@ -274,3 +294,40 @@ def test_the_rule_fires_through_the_signed_root_and_not_through_a_cache():
     graph, _ = SampledSIFTRule().apply(both.graph, {})
     (fused,) = _ops(graph)
     assert fused.then == (root, pca)
+
+
+def test_a_small_imagenet_job_names_the_body_of_its_two_sift_samples():
+    """``imagenet_sift_lcs_fv.run``: SIFT's two sampling passes go through
+    the node (LCS's two do not) and their spans say which body read the
+    columns — 50 of a 48 × 48 image's 188 descriptors at scale step 1."""
+    from keystone_tpu.pipelines import imagenet_sift_lcs_fv as imagenet
+
+    n_train, n_test, classes = 32, 16, 6
+    train, train_labels = imagenet.synthetic_imagenet(
+        n_train, classes, size=48, seed=1
+    )
+    test, test_labels = imagenet.synthetic_imagenet(
+        n_test, classes, size=48, seed=2
+    )
+    conf = imagenet.ImageNetSiftLcsFVConfig(
+        desc_dim=8, vocab_size=2, num_pca_samples=1600, num_gmm_samples=1600,
+        num_classes=classes, lam=1e-4,
+    )
+    (_, err, _), spans = _traced(
+        lambda: imagenet.run(train, train_labels, test, test_labels, conf)
+    )
+    assert 0.0 <= err.top1 <= 100.0
+    passes = [
+        sp.attrs for sp in spans if sp.name == "imagenet.sample_descriptors"
+    ]
+    assert [a["branch"] for a in passes] == ["sift", "sift", "lcs", "lcs"]
+    sampled = [
+        sp.attrs for sp in spans
+        if sp.name == "exec.segment" and "sift_sampled_path" in sp.attrs
+    ]
+    sift = SIFTExtractor(scale_step=conf.sift_scale_step)
+    want = sift.sampled_path(48, 48, 1600 // n_train)
+    assert want == "grid"
+    assert [a["sift_sampled_path"] for a in sampled] == [want, want]
+    assert [a["sift_sampled_rows"] for a in sampled] == [n_train, n_train]
+    assert all("SampledSIFTExtractor" in a["label"] for a in sampled)

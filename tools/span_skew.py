@@ -11,7 +11,7 @@ offset and its per-step scatter, the widest gap between an aligned span's
 edges and its annotation's, the idle split, what the content digest hashed
 and answered from memory a unit by span name, what segment dispatch did
 with the window's first segments (``rows``, the slices, ``conv_fused_rows``,
-``sift_sampled_rows``), and — for the readers that
+``sift_sampled_rows``, ``sift_sampled_path``), and — for the readers that
 match device operations by name — whether the operations' names or stats
 carry the ``ks.*`` named scopes. ``--cpu`` rehearses the host side on the
 CPU with the tests' tiny benchmark (no device plane: no idle split).
@@ -294,7 +294,8 @@ def main(argv=None) -> int:
     # what segment dispatch did with the first segments of the window: the
     # slices, and the counts that say a fused body engaged
     facts = ("label", "path", "rows", "row_slices", "slice_rows",
-             "conv_fused_rows", "sift_sampled_rows", "cache_declined_bytes")
+             "conv_fused_rows", "sift_sampled_rows", "sift_sampled_path",
+             "cache_declined_bytes")
     out["segments"] = [
         {k: sp.attrs[k] for k in facts if k in sp.attrs}
         for sp in spans if sp.name == "exec.segment"
