@@ -114,8 +114,8 @@ class CompileCounts:
 
         from keystone_tpu.obs import tracer as tracer_mod
 
-        tracer_mod.start()  # installs the tracer's compile-request listener
-        self._requests = tracer_mod._compile_count
+        tracer_mod.start()  # from here on: ``fallbacks_fired`` reads spans
+        self._record = tracer_mod.compile_record()
         self.hits = self.writes = 0
         monitoring.register_event_listener(self._on_event)
 
@@ -127,7 +127,7 @@ class CompileCounts:
 
     def snapshot(self) -> dict:
         return {
-            "backend_compile_requests": self._requests(),
+            "backend_compile_requests": self._record.requests["load"],
             "persistent_cache_hits": self.hits,
             "persistent_cache_writes": self.writes,
         }

@@ -11,7 +11,8 @@ re-exported here: ``obs.span`` is the record's module): a
 ``ks:<name>`` annotation in any profile being taken, and a span in memory
 with an installed tracer (``KEYSTONE_TRACE=/path/trace.json`` or the CLI's
 ``--trace PATH``) or, unsynced, for the length of a profiler session
-(``session_spans()``). With neither, the annotation is the whole cost.
+(``session_spans()``) or of the process's first job (``first_job_spans()``).
+With none, the annotation is the whole cost.
 """
 
 from .audit import cache_audit, log_cache_audit
@@ -32,6 +33,7 @@ from .tracer import (
     Tracer,
     current,
     export,
+    first_job_spans,
     install,
     reset,
     session_spans,
@@ -56,6 +58,7 @@ __all__ = [
     "new_trace_id",
     "record_scan_span",
     "export",
+    "first_job_spans",
     "format_top_spans",
     "install",
     "log_cache_audit",

@@ -47,6 +47,10 @@ def _span_args(sp) -> dict:
             ("sync_ms", round(sp.sync_seconds * 1e3, 3) or None),
             ("output_bytes", sp.output_bytes),
             ("compiles", sp.compiles or None),
+            ("cache_hits", sp.cache_hits or None),
+            ("trace_s", round(sp.trace_s, 6) or None),
+            ("lower_s", round(sp.lower_s, 6) or None),
+            ("load_s", round(sp.load_s, 6) or None),
             ("digest_bytes", sp.digest_bytes or None),
             ("digest_hits", sp.digest_hits or None),
         )
@@ -249,13 +253,63 @@ def format_top_spans(tracer: Tracer, n: int = 10, prefix: Optional[str] = None) 
     width = min(max(len(name) for name, _ in rows), 64)
     lines = [
         f"{'span':<{width}} {'seconds':>9} {'calls':>6} {'sync_s':>8} "
-        f"{'hits':>5} {'MB':>9} {'compiles':>8}"
+        f"{'hits':>5} {'MB':>9} {'compiles':>8} {'trace_s':>8} "
+        f"{'lower_s':>8} {'load_s':>8}"
     ]
     for name, row in rows:
         mb = (row["bytes"] or 0) / 2**20
         lines.append(
             f"{name[:width]:<{width}} {row['seconds']:>9.4f} "
             f"{row['calls']:>6} {row['sync_seconds']:>8.4f} "
-            f"{row['cache_hits']:>5} {mb:>9.2f} {row['compiles']:>8}"
+            f"{row['cache_hits']:>5} {mb:>9.2f} {row['compiles']:>8} "
+            f"{row['trace_s']:>8.4f} {row['lower_s']:>8.4f} "
+            f"{row['load_s']:>8.4f}"
         )
     return "\n".join(lines)
+
+
+def compile_seconds_by_span(
+    spans: Iterable, key=lambda sp: sp.name
+) -> Dict[str, float]:
+    """By span name (or ``key(span)``), the ``trace_s + lower_s + load_s``
+    that are a span's OWN: its counts less its direct children's (never
+    below 0: children on two threads at once each see what either
+    compiled)."""
+    spans = list(spans)
+    inside: Dict[int, float] = {}
+    for sp in spans:
+        if sp.parent_id is not None:
+            inside[sp.parent_id] = (
+                inside.get(sp.parent_id, 0.0)
+                + sp.trace_s + sp.lower_s + sp.load_s
+            )
+    out: Dict[str, float] = {}
+    for sp in spans:
+        own = sp.trace_s + sp.lower_s + sp.load_s - inside.get(sp.span_id, 0.0)
+        name = key(sp)
+        out[name] = out.get(name, 0.0) + max(own, 0.0)
+    return out
+
+
+def format_first_job(
+    spans: Iterable, programs: Dict[str, dict], root, n: int = 3
+) -> str:
+    """One line on a process's first job (``obs.tracer.first_job_spans``):
+    what ``root`` took, how much of it jax spent tracing, lowering and
+    loading, and the ``n`` spans and programs that hold most of that."""
+
+    def top(table: Dict[str, float]) -> str:
+        rows = sorted(table.items(), key=lambda kv: -kv[1])[:n]
+        return ", ".join(f"{k} {v:.3f}" for k, v in rows if v > 0) or "none"
+
+    by_fun = {
+        fun: sum(seconds for _, seconds in row.values())
+        for fun, row in programs.items()
+    }
+    return (
+        f"first job: {root.name} {root.seconds:.3f} s; jax traced "
+        f"{root.trace_s:.3f} s, lowered {root.lower_s:.3f} s, compiled or "
+        f"loaded {root.load_s:.3f} s ({root.compiles} requests, "
+        f"{root.cache_hits} from the cache); most of it under spans "
+        f"{top(compile_seconds_by_span(spans))}; in programs {top(by_fun)}"
+    )

@@ -2,9 +2,10 @@
 
 A :class:`Span` is what the :class:`~keystone_tpu.obs.tracer.Tracer`
 collects — name, DAG node identity, operator type, wall-clock interval,
-device-sync time, materialized output bytes, cache hit/miss, and the
-XLA-compile count delta across the region. Spans form a tree per thread
-(``parent_id``/``depth`` come from the tracer's thread-local stack).
+device-sync time, materialized output bytes, cache hit/miss, and what jax
+compiled, traced, lowered and loaded across the region. Spans form a tree
+per thread (``parent_id``/``depth`` come from the tracer's thread-local
+stack).
 
 The helpers here size and synchronize values WITHOUT side effects: sizing
 never forces a lazy dataset to materialize, and syncing only blocks on
@@ -43,8 +44,16 @@ class Span:
     sync_seconds: float = 0.0
     #: materialized result size, when cheaply knowable (see cheap_nbytes)
     output_bytes: Optional[int] = None
-    #: XLA backend compiles that happened inside this span
+    #: what jax.monitoring reported inside this span, process-wide
+    #: (``obs.tracer.CompileRecord``): XLA compile requests, those of them
+    #: the persistent cache answered, and the seconds — union of the
+    #: events' intervals a kind — in which jax traced Python to a jaxpr,
+    #: lowered a jaxpr to an MLIR module, compiled or loaded an executable
     compiles: int = 0
+    cache_hits: int = 0
+    trace_s: float = 0.0
+    lower_s: float = 0.0
+    load_s: float = 0.0
     #: bytes ``utils/params.content_digest`` hashed inside this span, and
     #: the digests it answered from memory there
     digest_bytes: int = 0

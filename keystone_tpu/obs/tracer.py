@@ -22,10 +22,20 @@ timeline. It records a :class:`Span` in memory while SOMEONE IS RECORDING:
   to one process-wide session recorder that NEVER syncs and never sizes
   outputs (the device trace already knows when the chip ran) and keeps at
   most :data:`SESSION_MAX_SPANS`; :func:`session_spans` returns them after
-  the session has ended.
+  the session has ended;
+* else, from the start of the process until its first job has closed — the
+  first parentless span that has children — a boot recorder, unsynced and
+  bounded like the session's: a process that runs ONE job pays its tracing,
+  lowering and cache loads there and nowhere else, so that job is always
+  kept (:func:`first_job_spans`) and explained in one INFO line.
 
-With neither, the annotation (half a microsecond) is the only cost: no
-``Span`` is allocated and the body gets :data:`NULL_SPAN`.
+With none of them — every span after the first job — the annotation (half a
+microsecond) is the only cost: no ``Span`` is allocated and the body gets
+:data:`NULL_SPAN`.
+
+Every recorded span carries what jax.monitoring said happened between its
+entry and its exit (:class:`CompileRecord`): ``compiles``, ``trace_s``,
+``lower_s``, ``load_s``, ``cache_hits``.
 
 :func:`current` returns an INSTALLED tracer only: the session recorder is
 not "the tracer" to ``fit_instrumentation``, ``AutoCacheRule`` or
@@ -42,50 +52,143 @@ import threading
 import time
 from typing import Any, Dict, Iterator, List, Optional
 
+from jax import monitoring as _monitoring
 from jax.profiler import TraceAnnotation as _Annotation
 
 from .span import Span, cheap_nbytes, sync_value
 
 logger = logging.getLogger(__name__)
 
-# -- XLA compile counting ---------------------------------------------------
+# -- what jax says of its own tracing, lowering and compiling ---------------
 
-#: process-wide count of XLA backend compile requests (persistent-cache
-#: hits included), fed by jax.monitoring.
-#: Listeners cannot be unregistered individually, so this installs once
-#: (lazily, on first Tracer construction) and stays for the process life;
-#: the increment is negligible and only spans read the counter.
-_compiles = itertools.count()
-_compiles_seen = 0
-_compile_listener_lock = threading.Lock()
-_compile_listener_installed = False
-
-
-def _compile_count() -> int:
-    return _compiles_seen
+#: jax.monitoring's three timed events (their last path segment) and the
+#: kind each is kept as. ``load`` is ``compile_or_get_cached``: the compile
+#: when cold; the persistent cache's read, deserialise and load when warm.
+_KINDS = {
+    "jaxpr_trace_duration": "trace",
+    "jaxpr_to_mlir_module_duration": "lower",
+    "backend_compile_duration": "load",
+}
 
 
-def _install_compile_listener() -> None:
-    global _compile_listener_installed
-    with _compile_listener_lock:
-        if _compile_listener_installed:
+def _cover(covered: List[tuple], start: float, end: float) -> float:
+    """Add ``[start, end)`` to the sorted, disjoint ``covered`` and return
+    the seconds it adds to their union. Events arrive as they END, so what
+    an interval overlaps sits at the list's tail: an outer trace arrives
+    after the inner traces it holds and swallows them."""
+    if end <= start:
+        return 0.0
+    added = end - start
+    later = []
+    while covered and covered[-1][1] > start:
+        s, e = covered.pop()
+        if s >= end:  # another thread's, which began after this one ended
+            later.append((s, e))
+            continue
+        added -= min(e, end) - max(s, start)
+        start, end = min(s, start), max(e, end)
+    covered.append((start, end))
+    covered.extend(reversed(later))
+    return added
+
+
+class CompileRecord:
+    """The seconds and requests jax.monitoring reports, a kind: Python
+    traced to a jaxpr (``trace``), the jaxpr lowered to an MLIR module
+    (``lower``), the module compiled or answered by the persistent cache
+    (``load``). A nested ``jit`` fires its own event inside the outer
+    one's interval, so a kind's seconds are the UNION of its events'
+    intervals, never their sum. Every span carries the change of these
+    between its entry and its exit; they count the whole process, so two
+    spans open at once on two threads both see a compile either made."""
+
+    def __init__(self) -> None:
+        self._lock = threading.Lock()
+        #: a kind's seconds (the union) and its events;
+        #: ``requests["load"]`` is every span's ``compiles``
+        self.seconds = dict.fromkeys(_KINDS.values(), 0.0)
+        self.requests = dict.fromkeys(_KINDS.values(), 0)
+        #: requests the persistent cache answered, and its seconds reading
+        #: them (inside ``load_s``)
+        self.cache_hits = 0
+        self.cache_read_s = 0.0
+        #: a kind's disjoint intervals: one entry a top-level trace, lower
+        #: or compile — they grow with what jax compiles, not with jobs
+        self._covered: Dict[str, List[tuple]] = {
+            kind: [] for kind in _KINDS.values()
+        }
+        #: ``{fun_name: {kind: [requests, seconds]}}``, a program's own
+        #: events summed (an outer function's holds what it traced inside)
+        self._by_fun: Dict[str, Dict[str, list]] = {}
+
+    def on_time_span(
+        self, event: str, start: float, end: float, fun_name: str = "", **kw
+    ) -> None:
+        kind = _KINDS.get(event.rsplit("/", 1)[-1])
+        if kind is None:
             return
-        _compile_listener_installed = True
-    try:
-        from jax import monitoring
+        # tracing names the function ``f``, lowering and compiling its
+        # module ``jit(f)``: one row a program
+        fun = str(fun_name)
+        if fun.endswith(")") and "(" in fun:
+            fun = fun[fun.index("(") + 1 : -1]
+        with self._lock:
+            self.requests[kind] += 1
+            self.seconds[kind] += _cover(self._covered[kind], start, end)
+            row = self._by_fun.get(fun)
+            if row is None:
+                row = self._by_fun[fun] = {
+                    k: [0, 0.0] for k in _KINDS.values()
+                }
+            row[kind][0] += 1
+            row[kind][1] += end - start
 
-        def _on_duration(event: str, duration: float, **kw) -> None:
-            # one /jax/core/compile/backend_compile_duration per compile
-            # REQUEST: jax times compile_or_get_cached, so a request the
-            # persistent cache answers fires it too (and additionally a
-            # /jax/compilation_cache/cache_hits event)
-            if event.endswith("backend_compile_duration"):
-                global _compiles_seen
-                _compiles_seen = next(_compiles) + 1
+    def on_duration(self, event: str, duration: float, **kw) -> None:
+        if event.endswith("compilation_cache/cache_retrieval_time_sec"):
+            with self._lock:
+                self.cache_read_s += duration
 
-        monitoring.register_event_duration_secs_listener(_on_duration)
-    except Exception:
-        logger.debug("jax compile-event listener unavailable", exc_info=True)
+    def on_event(self, event: str, **kw) -> None:
+        if event.endswith("compilation_cache/cache_hits"):
+            with self._lock:
+                self.cache_hits += 1
+
+    def programs(self, since: Optional[dict] = None) -> Dict[str, dict]:
+        """The table by ``fun_name`` as ``{fun: {kind: (requests,
+        seconds)}}``: all of it, or what was added since an earlier
+        reading of it."""
+        with self._lock:
+            table = {
+                fun: {kind: tuple(cell) for kind, cell in row.items()}
+                for fun, row in self._by_fun.items()
+            }
+        if since is None:
+            return table
+        out = {}
+        for fun, row in table.items():
+            was = since.get(fun)
+            if was is not None:
+                row = {
+                    kind: (n - was[kind][0], s - was[kind][1])
+                    for kind, (n, s) in row.items()
+                }
+            if any(n for n, _ in row.values()):
+                out[fun] = row
+        return out
+
+
+#: the process's one record. Listeners cannot be told apart once
+#: registered, so it listens from import on, for the process's life; they
+#: run only when jax traces or compiles — nothing on a warm job's path.
+_record = CompileRecord()
+_monitoring.register_event_time_span_listener(_record.on_time_span)
+_monitoring.register_event_duration_secs_listener(_record.on_duration)
+_monitoring.register_event_listener(_record.on_event)
+
+
+def compile_record() -> CompileRecord:
+    """The process's record of what jax traced, lowered and compiled."""
+    return _record
 
 
 # -- content-digest counting ------------------------------------------------
@@ -136,7 +239,6 @@ class Tracer:
         #: spans discarded below _spans[0] (discard_through): cursors
         #: from spans_since stay valid GLOBAL indices across compaction
         self._span_offset = 0
-        _install_compile_listener()
 
     # -- span recording -------------------------------------------------
 
@@ -201,9 +303,14 @@ class Tracer:
             cache=cache,
             attrs=attrs,
         )
-        # the count at entry, negated: _close adds the count at exit, which
-        # leaves the compile REQUESTS inside the span (cache hits included)
-        sp.compiles = -_compile_count()
+        # the counts at entry, negated: _close adds the counts at exit,
+        # which leaves what happened inside the span (``compiles``: the
+        # compile REQUESTS, those the persistent cache answered included)
+        rec, seconds = _record, _record.seconds
+        sp.compiles, sp.cache_hits = -rec.requests["load"], -rec.cache_hits
+        sp.trace_s, sp.lower_s, sp.load_s = (
+            -seconds["trace"], -seconds["lower"], -seconds["load"]
+        )
         sp.digest_bytes, sp.digest_hits = -_digest_bytes, -_digest_hits
         stack.append(sp)
         return sp
@@ -220,7 +327,12 @@ class Tracer:
                 if sp.output_bytes is None:
                     sp.output_bytes = cheap_nbytes(target)
         sp.end = time.perf_counter()
-        sp.compiles += _compile_count()
+        rec, seconds = _record, _record.seconds
+        sp.compiles += rec.requests["load"]
+        sp.cache_hits += rec.cache_hits
+        sp.trace_s += seconds["trace"]
+        sp.lower_s += seconds["lower"]
+        sp.load_s += seconds["load"]
         sp.digest_bytes += _digest_bytes
         sp.digest_hits += _digest_hits
         self._keep(sp)
@@ -333,6 +445,11 @@ class Tracer:
                     "sync_seconds": 0.0,
                     "bytes": 0,
                     "compiles": 0,
+                    "trace_s": 0.0,
+                    "lower_s": 0.0,
+                    "load_s": 0.0,
+                    # the persistent cache's; ``cache_hits`` is the memo's
+                    "compile_cache_hits": 0,
                     "cache_hits": 0,
                     "cache_misses": 0,
                 },
@@ -346,11 +463,16 @@ class Tracer:
             row["seconds"] += sp.seconds
             row["sync_seconds"] += sp.sync_seconds
             row["compiles"] += sp.compiles
+            row["trace_s"] += sp.trace_s
+            row["lower_s"] += sp.lower_s
+            row["load_s"] += sp.load_s
+            row["compile_cache_hits"] += sp.cache_hits
             if sp.output_bytes:
                 row["bytes"] = max(row["bytes"], sp.output_bytes)
         for row in agg.values():
-            row["seconds"] = round(row["seconds"], 4)
-            row["sync_seconds"] = round(row["sync_seconds"], 4)
+            for key in ("seconds", "sync_seconds", "trace_s", "lower_s",
+                        "load_s"):
+                row[key] = round(row[key], 4)
         return dict(sorted(agg.items()))
 
     # -- autocache estimates (see obs/audit.py) -------------------------
@@ -429,6 +551,8 @@ def current() -> Optional[Tracer]:
 #: the session recorder's bound: a fit job leaves about a hundred spans, so
 #: this holds minutes of back-to-back jobs and a forgotten session stays small
 SESSION_MAX_SPANS = 65536
+#: the boot recorder's: the largest job of the benchmark leaves a few hundred
+BOOT_MAX_SPANS = 8192
 
 _session: Optional[Tracer] = None
 _session_live = False
@@ -459,10 +583,61 @@ class _NullSpan:
 NULL_SPAN = _NullSpan()
 
 
+class _BootRecorder(Tracer):
+    """Keeps a process's first job: every span from the start of the
+    process until the first parentless span that has children has closed.
+    Like the session recorder it never syncs, never sizes outputs and is
+    bounded. It learns no span name: a lone parentless span ahead of the
+    job (a label upload) is kept and ends nothing."""
+
+    def __init__(self) -> None:
+        super().__init__(sync=False, max_spans=BOOT_MAX_SPANS)
+        #: the compile record's table by ``fun_name`` over the root span
+        self.programs: Dict[str, dict] = {}
+        self._programs_at_open: Dict[int, dict] = {}
+
+    def _open(self, name: str, **kw) -> Span:
+        sp = super()._open(name, **kw)
+        if sp.parent_id is None:
+            self._programs_at_open[sp.span_id] = _record.programs()
+        return sp
+
+    def _close(self, sp: Span) -> None:
+        super()._close(sp)
+        if sp.parent_id is not None:
+            return
+        at_open = self._programs_at_open.pop(sp.span_id, {})
+        spans = self.spans()
+        # a recorder that overflowed ends with its next root, children seen
+        # or not: the flag must come down in a process that has no job
+        if self.dropped or any(c.parent_id == sp.span_id for c in spans):
+            _end_boot(self, sp, _record.programs(since=at_open))
+
+
+def _end_boot(boot: _BootRecorder, root: Span, programs: dict) -> None:
+    """The first job has closed: the flag comes down for the life of the
+    process, and the job is explained once, at INFO."""
+    global _boot_live
+    with _session_lock:
+        if boot is not _boot or not _boot_live:
+            return
+        _boot_live = False
+    boot.programs = programs
+    if logger.isEnabledFor(logging.INFO):
+        from .export import format_first_job
+
+        logger.info("%s", format_first_job(boot.spans(), programs, root))
+
+
+_boot = _BootRecorder()
+_boot_live = True
+
+
 def _recorder() -> Optional[Tracer]:
     """Who keeps a span opened now on this thread: the installed tracer,
-    else the session recorder while a profiler session records, else
-    nobody. A session that begins after one has ended starts a new list."""
+    else the session recorder while a profiler session records, else the
+    boot recorder until the process's first job has closed, else nobody.
+    A session that begins after one has ended starts a new list."""
     global _session, _session_live
     if getattr(_suspend, "depth", 0):
         return None
@@ -470,7 +645,7 @@ def _recorder() -> Optional[Tracer]:
         return _current
     if not _Annotation.is_enabled():
         _session_live = False
-        return None
+        return _boot if _boot_live else None
     if not _session_live:
         with _session_lock:
             if not _session_live:
@@ -483,6 +658,22 @@ def session_spans() -> List[Span]:
     """The spans of the newest profiler session (readable after it has
     ended; ``[]`` before the first)."""
     return [] if _session is None else _session.spans()
+
+
+def first_job_spans() -> List[Span]:
+    """What the boot recorder kept: the process's first parentless span
+    that had children, with all of them, and any childless parentless span
+    ahead of it; nothing after it. While an installed tracer or a profiler
+    session records, the boot recorder waits: it keeps the first job that
+    nobody else took."""
+    return _boot.spans()
+
+
+def first_job_programs() -> Dict[str, dict]:
+    """``{fun_name: {kind: (requests, seconds)}}`` of what jax traced,
+    lowered and compiled inside the first job's root span; ``{}`` until it
+    has closed."""
+    return _boot.programs
 
 
 class span:
@@ -605,11 +796,12 @@ def stop() -> Optional[Tracer]:
 
 def reset() -> None:
     """Drop the installed tracer, the session recorder AND the export
-    path (test hygiene)."""
+    path, and arm the boot recorder anew (test hygiene)."""
     global _current, _export_path, _exported_span_count
-    global _session, _session_live
+    global _session, _session_live, _boot, _boot_live
     _current = None
     _session, _session_live = None, False
+    _boot, _boot_live = _BootRecorder(), True
     _export_path = None
     _exported_span_count = None
 

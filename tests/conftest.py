@@ -49,6 +49,21 @@ def pipeline_env():
 
 
 @pytest.fixture
+def past_first_job():
+    """A reset tracer in a process whose first job has closed: the boot
+    recorder (``obs.tracer.first_job_spans``) is down, and a span with
+    nobody recording is ``NULL_SPAN``. Leaves a reset tracer behind."""
+    from keystone_tpu.obs import tracer
+
+    tracer.reset()
+    with tracer.span("job"):
+        with tracer.span("plan.build"):
+            pass
+    yield
+    tracer.reset()
+
+
+@pytest.fixture
 def bf16_products(monkeypatch):
     """``lax.conv_general_dilated`` as ONE bf16 pass — operands rounded to
     bf16, float32 accumulation — which is how the TPU's default precision

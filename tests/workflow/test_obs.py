@@ -26,11 +26,8 @@ def test_configure_rejects_unknown_level():
 
 
 @pytest.fixture
-def no_tracer():
+def no_tracer(past_first_job):
     """The four span tests install a tracer: leave none behind."""
-    tracer.reset()
-    yield
-    tracer.reset()
 
 
 def test_trace_records_span_totals_and_logs(no_tracer, tmp_path, caplog):

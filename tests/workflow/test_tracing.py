@@ -25,12 +25,11 @@ from keystone_tpu.workflow.transformer import FunctionNode, Transformer
 
 
 @pytest.fixture(autouse=True)
-def clean_tracer():
+def clean_tracer(past_first_job):
     """Tracing must never leak across tests — a leaked tracer would add a
-    device sync to every executor pull in the rest of the suite."""
-    trace_mod.reset()
-    yield
-    trace_mod.reset()
+    device sync to every executor pull in the rest of the suite. A test
+    starts past the process's first job; the boot recorder's own tests
+    arm it anew with ``reset()``."""
 
 
 def _installed():

@@ -15,6 +15,14 @@ with the window's first segments (``rows``, the slices, ``conv_fused_rows``,
 match device operations by name — whether the operations' names or stats
 carry the ``ks.*`` named scopes. ``--cpu`` rehearses the host side on the
 CPU with the tests' tiny benchmark (no device plane: no idle split).
+
+It also prints the process's FIRST job — set-up's warm-up fit, which the
+program's boot recorder keeps (``obs.tracer.first_job_spans()``): by span
+name its seconds and what jax traced, lowered, compiled or loaded inside
+it, and the table by program. ``--first-job`` takes the profiler session
+around set-up instead of the window, so that the chip's idle gaps of a
+one-job process are split by span too, joined at the ends of the ``ks:job``
+annotation the ``job`` span itself leaves (no window is driven then).
 """
 
 from __future__ import annotations
@@ -206,12 +214,60 @@ def skew(spans, annotations, offset: float) -> dict:
     }
 
 
+def _key(sp) -> str:
+    """A span's name, with what tells its kind apart: the rule of a
+    ``plan.rule``, the members of an ``exec.segment``."""
+    for attr in ("rule", "label"):
+        if attr in sp.attrs:
+            return f"{sp.name}:{str(sp.attrs[attr])[:48]}"
+    return sp.name
+
+
+def first_job_table(spans, programs: dict, top: int = 12) -> dict:
+    """A job's spans by name — seconds and what jax.monitoring reported
+    inside (a nested span counts its children's too; ``own_compile_s`` is
+    its counts less theirs) — and the programs that took most of it."""
+    from keystone_tpu.obs.export import compile_seconds_by_span
+
+    fields = ("seconds", "trace_s", "lower_s", "load_s", "compiles",
+              "cache_hits")
+    by_span: dict = {}
+    for sp in spans:
+        row = by_span.setdefault(
+            _key(sp), dict.fromkeys(fields, 0) | {"calls": 0}
+        )
+        row["calls"] += 1
+        for field in fields:
+            row[field] += getattr(sp, field)
+    for name, own in compile_seconds_by_span(spans, key=_key).items():
+        by_span[name]["own_compile_s"] = own
+    rows = sorted(
+        programs.items(),
+        key=lambda kv: -sum(seconds for _, seconds in kv[1].values()),
+    )[:top]
+    return {
+        "by_span": dict(sorted(
+            by_span.items(), key=lambda kv: -kv[1]["seconds"]
+        )),
+        "programs": {
+            fun: {kind: {"requests": n, "seconds": s}
+                  for kind, (n, s) in row.items()}
+            for fun, row in rows
+        },
+        "programs_in_all": len(programs),
+    }
+
+
 def main(argv=None) -> int:
     ap = argparse.ArgumentParser("tools/span_skew.py")
     ap.add_argument("--workload", default="timit_cos4.fit")
     ap.add_argument("--seed", type=int, default=2147484001)
     ap.add_argument("--seconds", type=float, default=5.0)
     ap.add_argument("--cpu", action="store_true")
+    ap.add_argument(
+        "--first-job", action="store_true",
+        help="trace set-up (the process's first job), not the window",
+    )
     args = ap.parse_args(argv)
     started = time.perf_counter()
 
@@ -252,19 +308,36 @@ def main(argv=None) -> int:
         traffic=traffic, seed=args.seed, seconds=args.seconds, trace=True,
         device=device, peak=peak, phases=harness.Phases(),
     )
-    state = driver.setup(run)
     trace_dir = tempfile.mkdtemp(prefix="span_skew_trace_")
+    programs_before = tracer.compile_record().programs()
     t0 = time.perf_counter()
-    with trace_mod.traced(trace_dir):
-        driver.window(run, state, args.seconds)
+    if args.first_job:
+        # the session takes the first job from the boot recorder
+        with trace_mod.traced(trace_dir):
+            driver.setup(run)
+        anchor = "ks:job"
+    else:
+        state = driver.setup(run)
+        t0 = time.perf_counter()
+        with trace_mod.traced(trace_dir):
+            driver.window(run, state, args.seconds)
+        anchor = "bench:fit.step"
     traced_s = time.perf_counter() - t0
     spans = [sp for sp in tracer.session_spans() if not sp.instant]
     data = _xplane(trace_dir)
     ks = host_annotations(data, "ks:")
     anchors = [
         (start, end) for name, start, end, _ in
-        host_annotations(data, "bench:") if name == "bench:fit.step"
+        host_annotations(data, anchor) if name == anchor
     ]
+    if args.first_job:
+        run.facts.setdefault("units", max(len(anchors), 1))
+        first, programs = spans, tracer.compile_record().programs(
+            since=programs_before  # all of set-up: the data's programs too
+        )
+    else:
+        first = tracer.first_job_spans()
+        programs = tracer.first_job_programs()
 
     from benchmark.readers import span_idle
 
@@ -276,6 +349,33 @@ def main(argv=None) -> int:
         "spans_per_unit": len(spans) / max(run.facts.get("units", 1), 1),
         "names": sorted({sp.name for sp in spans}),
     }
+    job = next((sp for sp in first if sp.name == "job"), None)
+    if job is not None:
+        record = tracer.compile_record()
+        out["first_job"] = {
+            "traced": bool(args.first_job),
+            "seconds": job.seconds, "trace_s": job.trace_s,
+            "lower_s": job.lower_s, "load_s": job.load_s,
+            "compiles": job.compiles, "cache_hits": job.cache_hits,
+            "spans": len(first),
+            # the process so far, set-up's data and the window included
+            "process": {
+                "requests": dict(record.requests),
+                "seconds": dict(record.seconds),
+                "cache_hits": record.cache_hits,
+                "cache_read_s": record.cache_read_s,
+            },
+            **first_job_table(first, programs),
+        }
+    # what a WARM job still pays jax: the median window job beside the first
+    jobs = [sp for sp in spans if sp.name == "job"]
+    if jobs and not args.first_job:
+        import statistics
+
+        out["window_job"] = {
+            field: statistics.median(getattr(sp, field) for sp in jobs)
+            for field in ("seconds", "trace_s", "lower_s", "load_s")
+        }
     # what utils/params.content_digest did inside each kind of span, a unit
     # (a nested span counts its children's too: plan.optimize holds the
     # plan.rule spans, job everything)
@@ -317,14 +417,16 @@ def main(argv=None) -> int:
             split = span_idle.idle_by_span(
                 [(sp.name + (":" + sp.attrs["rule"] if "rule" in sp.attrs
                              else ""), sp.start, sp.end) for sp in spans],
-                reduction.annotations.get("bench:fit.step", []),
+                anchors if args.first_job
+                else reduction.annotations.get(anchor, []),
                 reduction.busy, "job",
             )
             out["idle_s_by_span"] = dict(
                 sorted(split.items(), key=lambda kv: -kv[1])
             )
-            out["idle_s_under_anchor"] = reduction.idle_seconds.get(
-                "bench:fit.step"
+            out["idle_s_under_anchor"] = (
+                sum(split.values()) if args.first_job
+                else reduction.idle_seconds.get(anchor)
             )
         out["op_names_with_scope"] = sum(
             1 for name in reduction.op_seconds if SCOPE.search(name)
