@@ -488,11 +488,12 @@ def _member_facts(
     """What the members that speak of their rows under names of their own
     say of ``rows`` rows — ``segment_facts(shape, rows)``: the fused
     convolution's ``conv_fused_rows`` where its kernel engages, the sampled
-    SIFT body's ``sift_sampled_rows`` and ``sift_sampled_path`` — each asked
-    at the shape its first input has where ``arrays`` go through
-    ``slice_rows`` at a time. The members ahead of the last such one are
-    evaluated abstractly (``jax.eval_shape``: no operation runs), none
-    where none speaks."""
+    SIFT body's ``sift_sampled_rows`` and ``sift_sampled_path``, the
+    guarded cosine's ``cosine_bounded_rows`` — each asked at the shape its
+    first input has where ``arrays`` go through ``slice_rows`` at a time.
+    The members a speaking member's first input is made from are
+    evaluated abstractly (``jax.eval_shape``: no operation runs), and no
+    other: a trace costs the host milliseconds, at every dispatch."""
     import jax
 
     from ..workflow.operators import GatherTransformerOperator
@@ -500,21 +501,29 @@ def _member_facts(
     speaking = {
         i for i, (op, _) in enumerate(steps) if hasattr(op, "segment_facts")
     }
+    # a member's value sits behind the inputs', in step order
+    first = len(arrays)
+    needed, todo = set(), [steps[i][1][0] for i in speaking]
+    while todo:
+        slot = todo.pop()
+        if slot >= first and slot not in needed:
+            needed.add(slot)
+            todo.extend(steps[slot - first][1])
     facts: Dict[str, Any] = {}
-    values: List[Any] = [
-        jax.ShapeDtypeStruct((slice_rows,) + a.shape[1:], a.dtype)
-        for a in arrays
-    ]
-    for i, (op, slots) in enumerate(steps[: max(speaking, default=-1) + 1]):
-        args = [values[s] for s in slots]
+    values: Dict[int, Any] = {
+        slot: jax.ShapeDtypeStruct((slice_rows,) + a.shape[1:], a.dtype)
+        for slot, a in enumerate(arrays)
+    }
+    for i, (op, slots) in enumerate(steps):
         if i in speaking:
-            facts.update(op.segment_facts(args[0].shape, rows))
-        if i == max(speaking):
-            break
+            facts.update(op.segment_facts(values[slots[0]].shape, rows))
+        if first + i not in needed:
+            continue
+        args = [values[s] for s in slots]
         if isinstance(op, GatherTransformerOperator):
-            values.append(tuple(args))
+            values[first + i] = tuple(args)
         else:
-            values.append(jax.eval_shape(op.trace_batch, *args))
+            values[first + i] = jax.eval_shape(op.trace_batch, *args)
     return facts
 
 

@@ -1,5 +1,8 @@
-"""Pallas TPU kernels for hot ops where hand-tiling beats or stabilizes
-the XLA lowering. Current kernels:
+"""Hand-written bodies for hot ops: Pallas TPU kernels where hand-tiling
+beats or stabilizes the XLA lowering, and :mod:`.bounded_cos`, a plain
+``lax`` cosine for arguments of bounded size that XLA fuses behind a
+product (``CosineRandomFeatures``; cells ``timit_cos4.apply`` / ``.fit``).
+Current kernels:
 
 * :mod:`.gaussian_kernel` — fused Gaussian kernel block (GEMM + norms +
   exp in one VMEM-resident tile), the KRR hot loop's block generator.

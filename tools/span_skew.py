@@ -11,8 +11,8 @@ offset and its per-step scatter, the widest gap between an aligned span's
 edges and its annotation's, the idle split, what the content digest hashed
 and answered from memory a unit by span name, what segment dispatch did
 with the window's first segments (``rows``, the slices, ``conv_fused_rows``,
-``sift_sampled_rows``, ``sift_sampled_path``), and — for the readers that
-match device operations by name — whether the operations' names or stats
+``sift_sampled_rows``, ``sift_sampled_path``, ``cosine_bounded_rows``), and
+— for the readers that match device operations by name — whether the operations' names or stats
 carry the ``ks.*`` named scopes. ``--cpu`` rehearses the host side on the
 CPU with the tests' tiny benchmark (no device plane: no idle split).
 
@@ -395,6 +395,7 @@ def main(argv=None) -> int:
     # slices, and the counts that say a fused body engaged
     facts = ("label", "path", "rows", "row_slices", "slice_rows",
              "conv_fused_rows", "sift_sampled_rows", "sift_sampled_path",
+             "cosine_bounded_rows",
              "cache_declined_bytes")
     out["segments"] = [
         {k: sp.attrs[k] for k in facts if k in sp.attrs}
