@@ -3,7 +3,7 @@
 One process drives the main path — fit a pipeline, then serve it — once,
 through the entry points a user would call, at the full width of
 MnistRandomFFT (numFFTs=4, blockSize=2048, λ=1000; 60,000 train / 10,000
-test rows of the seeded synthetic task, generated in HBM). Six legs:
+test rows of the seeded synthetic task, generated in HBM). Five legs:
 
 * ``fit``    — CLI dispatch and backend selection through
   ``python -m keystone_tpu MnistRandomFFT --backend tpu``'s ``main``, then
@@ -12,8 +12,6 @@ test rows of the seeded synthetic task, generated in HBM). Six legs:
 * ``serve``  — the fitted pipeline behind ``ServingFleet`` at its default
   of one replica per device; every reply must equal ``fitted.apply`` on
   the same row.
-* ``kernel`` — the Gaussian Pallas kernel, compiled, against the XLA
-  lowering of the same algebra.
 * ``conv_chain`` — the fused conv → rectify → pool Pallas kernel at
   ``cifar_patch10k``'s 10,000 filters, compiled, against the XLA lowering
   of the three bodies it replaces, and that the front door picked it.
@@ -70,10 +68,6 @@ FULL = dict(
 #: n=10,000 is 0.0021. Below the band is better than Bayes (a leak); above
 #: it is a solve that lost precision or solved the wrong system.
 TEST_ERROR_BAND = (0.040, 0.055)
-
-#: docstring shape of ops/gaussian_kernel.py (its measured n is 131072;
-#: the grid only repeats over n, so a shorter n compiles the same tile)
-KERNEL_SHAPE = dict(n=8192, d=512, b=2048)
 
 #: cifar_patch10k's filter bank (benchmark/configs/cifar_patch10k.json) over
 #: as many images as the three XLA bodies hold at once (29 MB an image)
@@ -329,69 +323,6 @@ def serve_leg(fitted, rows, *, buckets, n_requests):
         "boot_seconds": round(boot_seconds, 3),
         "traffic_seconds": round(traffic_seconds, 3),
     }
-
-
-def kernel_leg(*, n, d, b, interpret=False):
-    """The Pallas Gaussian kernel block against ``_gaussian_block_xla``,
-    the XLA lowering of the same algebra it stands in for. Compiled
-    (``interpret=False``) the front door ``_gaussian_block`` must pick the
-    kernel for this shape. The distance to the same lowering at true-f32
-    GEMM precision is reported as a fact: on the chip both run the cross
-    product as one bf16 pass."""
-    import jax
-    import jax.numpy as jnp
-    import numpy as np
-
-    from keystone_tpu.nodes.learning.kernel import (
-        _gaussian_block,
-        _gaussian_block_xla,
-    )
-    from keystone_tpu.ops.gaussian_kernel import (
-        gaussian_kernel_block_pallas,
-        pallas_block_supported,
-    )
-
-    kx, kb = jax.random.split(jax.random.PRNGKey(0))
-    X = jax.random.normal(kx, (n, d), jnp.float32)
-    Xb = jax.random.normal(kb, (b, d), jnp.float32)
-    gamma = 1.0 / d
-
-    t0 = time.perf_counter()
-    got = jax.block_until_ready(
-        gaussian_kernel_block_pallas(X, Xb, gamma, interpret=interpret)
-    )
-    first_seconds = time.perf_counter() - t0
-    t0 = time.perf_counter()
-    jax.block_until_ready(
-        gaussian_kernel_block_pallas(X, Xb, gamma, interpret=interpret)
-    )
-    repeat_seconds = time.perf_counter() - t0
-    want = _gaussian_block_xla(X, Xb, gamma)
-    with jax.default_matmul_precision("highest"):
-        f32 = _gaussian_block_xla(X, Xb, gamma)
-    got, want, f32 = (np.asarray(a) for a in (got, want, f32))
-    matches = bool(np.allclose(got, want, rtol=1e-5, atol=1e-6))
-    report = {
-        "shape": {"n": n, "d": d, "b": b},
-        "interpret": interpret,
-        "finite": bool(np.isfinite(got).all()),
-        "matches_xla": matches,
-        "max_abs_diff_xla": float(np.max(np.abs(got - want))),
-        "max_abs_diff_xla_f32": float(np.max(np.abs(got - f32))),
-        "first_seconds": round(first_seconds, 3),
-        "repeat_seconds": round(repeat_seconds, 4),
-    }
-    ok = matches and report["finite"] and got.shape == (n, b)
-    if not interpret:
-        supported = pallas_block_supported(n, d, b)
-        chosen = supported and bool(
-            np.array_equal(np.asarray(_gaussian_block(X, Xb, gamma)), got)
-        )
-        report["supported"] = supported
-        report["front_door_chose_kernel"] = chosen
-        ok = ok and chosen
-    report["ok"] = bool(ok)
-    return report
 
 
 def conv_chain_leg(*, filters, images, side=32, interpret=False):
@@ -820,7 +751,6 @@ def main() -> int:
     leg("serve", lambda: serve_leg(
         fitted, rows, buckets=(8, 32, 128), n_requests=400
     ))
-    leg("kernel", lambda: kernel_leg(**KERNEL_SHAPE))
     leg("conv_chain", lambda: conv_chain_leg(**CONV_CHAIN_SHAPE))
     leg("fisher", lambda: fisher_leg(**FISHER_SHAPE))
     leg("weighted", lambda: weighted_leg(**WEIGHTED_SHAPE))

@@ -63,10 +63,14 @@ class ConvRectifyPool(Transformer):
     def kernel_mode(self, shape: Tuple[int, ...]) -> Optional[str]:
         """How the fused kernel runs a batch of this shape (``"compiled"``),
         or None where the three bodies run: off the TPU, a batch that is
-        not (n, x, y, channels) images, shapes the tiling does not admit."""
+        not (n, x, y, channels) images, shapes the tiling does not admit, a
+        Convolver that states a precision above the kernel's one bf16
+        pass."""
         mode = _kernel.kernel_mode()
         S, C = self.conv.conv_size, self.conv.img_channels
         if mode is None or len(shape) != 4 or shape[3] != C or not shape[0]:
+            return None
+        if self.conv.precision not in (None, "default"):
             return None
         fits = _kernel.supported(
             shape[1] - S + 1, shape[2] - S + 1, S * S * C,
@@ -76,15 +80,23 @@ class ConvRectifyPool(Transformer):
         return mode if fits else None
 
     def row_scratch_bytes(self, shape: Tuple[int, ...]) -> int:
-        """HBM bytes an image holds ahead of the kernel besides the node's
-        output — segment dispatch prices a row by it (``compile/segment.py:
-        _item_bytes``) — or 0 where the bodies run."""
-        if self.kernel_mode(shape) is None:
+        """HBM bytes an image holds besides the node's output — segment
+        dispatch prices a row by it (``compile/segment.py:_item_bytes``):
+        ahead of the kernel, its patch rows; where the three bodies run,
+        the convolution's (rx, ry, K) output and the rectifier's (rx, ry,
+        2K), float32 with the filters laid out in 128 lanes (1.12 MB an
+        image at 100 filters, above the 0.3-0.84 MB the TPU compiler gives
+        the bodies' program at 256 to 50,000 images)."""
+        if len(shape) != 4:
             return 0
         S = self.conv.conv_size
+        rx, ry = shape[1] - S + 1, shape[2] - S + 1
+        if self.kernel_mode(shape) is None:
+            lanes = lambda c: -(-c // 128) * 128  # noqa: E731
+            K = self.conv.filters.shape[0]
+            return 4 * rx * ry * (lanes(K) + lanes(2 * K))
         return _kernel.scratch_bytes(
-            shape[1] - S + 1, shape[2] - S + 1,
-            self.pooler.stride, self.pooler.pool_size,
+            rx, ry, self.pooler.stride, self.pooler.pool_size,
         )
 
     def segment_facts(self, shape: Tuple[int, ...], rows: int) -> dict:

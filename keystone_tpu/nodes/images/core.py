@@ -117,6 +117,10 @@ class Convolver(Transformer):
     the patch mean taken out of the FILTER (f̃ = f − f̄, so p·f̃ = (p − μ)·f):
 
         p̂·f = conv(img − c, f̃) / sd_patch      (any constant c an image)
+
+    ``precision`` is the patch product's (``jax.lax.Precision`` names; None:
+    jax's default, one bf16 pass on a TPU). A stated precision above one
+    pass also keeps ``ConvRectifyPool`` off its one-pass Pallas kernel.
     """
 
     def __init__(
@@ -128,8 +132,10 @@ class Convolver(Transformer):
         whitener=None,
         normalize_patches: bool = True,
         var_constant: float = 10.0,
+        precision: Optional[str] = None,
     ):
         self.filters = as_param(filters, dtype='float32')
+        self.precision = precision
         self.img_x = img_x
         self.img_y = img_y
         self.img_channels = img_channels
@@ -191,7 +197,7 @@ class Convolver(Transformer):
         kernel = jnp.transpose(filters.reshape(K, S, S, C), (2, 1, 3, 0))
         conv = jax.lax.conv_general_dilated(
             X, kernel, window_strides=(1, 1), padding="VALID",
-            dimension_numbers=_DIMNUMS,
+            dimension_numbers=_DIMNUMS, precision=self.precision,
         )  # (n, resX, resY, K)
 
         if self.normalize_patches:

@@ -11,6 +11,22 @@ column block at a time is computed as a single GEMM + elementwise exp
 (row-sharded train data × replicated block), cached in HBM, and freed after
 its solve; exactly the reference's streaming pattern with the
 broadcast/treeReduce choreography replaced by XLA collectives.
+
+**Precision.** Every product states its precision; on a TPU an unstated
+float32 product is ONE bf16 pass. The published λ is 0: the 5,000² block of
+RandomPatchCifarKernel's kernel has eigenvalues from 0.014 to 3,640, and
+its coefficients reach 100 against scores of ±1, so a one-pass product
+anywhere the coefficients are read cancels every digit of the scores
+(``benchmark/configs/cifar_krr.json``'s ``precision`` has the readings):
+
+* :data:`KERNEL_PRECISION` — the cross product ``x·y`` of a kernel block,
+  in the fit's generation and in the mapper's apply;
+* :data:`SOLVE_PRECISION` — the residual ``K_Bᵀ W`` of a block step. The
+  block's Cholesky factor and its triangular solves are XLA's own, whose
+  blocked updates run at HIGHEST on the TPU (every dot of a 5,000² factor
+  and solve, compiled for a described v5e, carries
+  ``operand_precision={highest,highest}``);
+* :data:`APPLY_PRECISION` — the mapper's ``K_b · W_b``.
 """
 
 from __future__ import annotations
@@ -29,29 +45,32 @@ from ...workflow.transformer import LabelEstimator, Transformer
 from ...workflow.node_optimization import Optimizable
 from .cost import AutoSolverFrontDoor, CostModel, combine_cost
 
+#: the kernel's cross product: three bf16 passes. One pass moves the
+#: held-out scores of a 5,000-row fit by 1.5e-2 of their norm, three by
+#: 4.9e-4 — under what float32 itself leaves (CPU emulation; the chip's
+#: readings are the configuration's)
+KERNEL_PRECISION = "high"
+#: the residual of a block step: three passes move the scores by 2.1e-3
+SOLVE_PRECISION = "highest"
+#: ``K_b · W_b`` of the apply: one pass moves the scores by 2.1 (they are
+#: sums of ±100 coefficients), three by 3.6e-3
+APPLY_PRECISION = "highest"
+
 
 @jax.jit
-def _gaussian_block_xla(X, Xb, gamma):
-    """exp(−γ‖x−y‖²) for all (row of X, row of Xb): (n, b)
+def _gaussian_block(X, Xb, gamma):
+    """exp(−γ‖x−y‖²) for all (row of X, row of Xb): (n, b), the cross
+    product at :data:`KERNEL_PRECISION`
     (parity: computeKernel, KernelGenerator.scala:138-206)."""
     xn = jnp.sum(X * X, axis=1, keepdims=True)
     bn = jnp.sum(Xb * Xb, axis=1)
-    sq = xn - 2.0 * (X @ Xb.T) + bn
+    sq = xn - 2.0 * jnp.matmul(X, Xb.T, precision=KERNEL_PRECISION) + bn
     return jnp.exp(-gamma * jnp.maximum(sq, 0.0))
 
 
-def _gaussian_block(X, Xb, gamma):
-    """Kernel-block front door: the fused Pallas kernel on TPU when the
-    tile working set fits VMEM (ops/gaussian_kernel.py), identical-math
-    XLA lowering otherwise."""
-    from ...ops.gaussian_kernel import (
-        gaussian_kernel_block_pallas,
-        pallas_block_supported,
-    )
-
-    if pallas_block_supported(X.shape[0], X.shape[1], Xb.shape[0]):
-        return gaussian_kernel_block_pallas(X, Xb, gamma)
-    return _gaussian_block_xla(X, Xb, gamma)
+def _kernel_span(rows: int, cols: int):
+    """The span of one kernel generation: its ``rows`` × ``cols`` entries."""
+    return span("krr.kernel", rows=rows, cols=cols, entries=rows * cols)
 
 
 class BlockKernelMatrix:
@@ -90,8 +109,8 @@ class KernelBlockLinearMapper(Transformer):
     # Never a segment member (``check/segments.py`` makes it a barrier):
     # train_X/W are dataset-sized, so baking them into a segment's XLA
     # module as literals (or fetching them host-side) is exactly the wrong
-    # trade. They stay device-resident; _gaussian_block takes them as jit
-    # *arguments*.
+    # trade. They stay device-resident; every block is ONE program that
+    # takes them as jit *arguments* (``_kernel_apply_block``).
     no_fuse = True
 
     def __init__(self, train_X, model_W, gamma: float, block_size: int):
@@ -100,15 +119,54 @@ class KernelBlockLinearMapper(Transformer):
         self.gamma = gamma
         self.block_size = block_size
 
+    def _blocks(self):
+        n_train = self.train_X.shape[0]
+        for start in range(0, n_train, self.block_size):
+            yield start, min(self.block_size, n_train - start)
+
     def trace_batch(self, X):
         X = jnp.asarray(X, dtype=jnp.float32)
-        n_train = self.train_X.shape[0]
         out = jnp.zeros((X.shape[0], self.W.shape[1]), dtype=jnp.float32)
-        for start in range(0, n_train, self.block_size):
-            end = min(start + self.block_size, n_train)
-            Kb = _gaussian_block(X, self.train_X[start:end], self.gamma)
-            out = out + Kb @ self.W[start:end]
+        for start, size in self._blocks():
+            out = _kernel_apply_block(
+                X, self.train_X, self.W, out, start, jnp.float32(self.gamma),
+                bs=size,
+            )
         return out
+
+    def apply_batch(self, data: Dataset) -> Dataset:
+        """A data set block by block, each kernel generation on a
+        ``krr.kernel`` span, the whole under ``krr.apply`` with the kernel
+        entries it made."""
+        data = Dataset.of(data)
+        if not data.is_batched:
+            return super().apply_batch(data)
+        X = jnp.asarray(data.to_array(), dtype=jnp.float32)
+        rows, n_train = X.shape[0], self.train_X.shape[0]
+        out = jnp.zeros((rows, self.W.shape[1]), dtype=jnp.float32)
+        with span(
+            "krr.apply", rows=rows, cols=n_train, entries=rows * n_train,
+        ) as sp:
+            for start, size in self._blocks():
+                with _kernel_span(rows, size) as kp:
+                    out = _kernel_apply_block(
+                        X, self.train_X, self.W, out, start,
+                        jnp.float32(self.gamma), bs=size,
+                    )
+                    kp.sync_on(out)
+            sp.sync_on(out)
+        return Dataset(out, batched=True)
+
+
+@partial(jax.jit, static_argnames=("bs",))
+def _kernel_apply_block(X, train_X, W, out, start, gamma, *, bs):
+    """``out + K(X, train_X[start:start+bs]) · W[start:start+bs]``: one
+    block of the apply as one program, the training rows and the
+    coefficients sliced inside it."""
+    Tb = jax.lax.dynamic_slice_in_dim(train_X, start, bs, axis=0)
+    Wb = jax.lax.dynamic_slice_in_dim(W, start, bs, axis=0)
+    Kb = _gaussian_block(X, Tb, gamma)
+    return out + jnp.matmul(Kb, Wb, precision=APPLY_PRECISION)
 
 
 def _krr_block_step_impl(X, Y, W, start, gamma, lam, *, bs):
@@ -125,9 +183,16 @@ def _krr_block_step_impl(X, Y, W, start, gamma, lam, *, bs):
     Kbb = jax.lax.dynamic_slice_in_dim(Kb, start, bs, axis=0)
     W_old = jax.lax.dynamic_slice_in_dim(W, start, bs, axis=0)
     Yb = jax.lax.dynamic_slice_in_dim(Y, start, bs, axis=0)
-    residual = Kb.T @ W - Kbb.T @ W_old
-    W_new = solve_spd(Kbb, Yb - residual, lam)
+    W_new = solve_spd(Kbb, Yb - _residual(Kb, Kbb, W, W_old), lam)
     return jax.lax.dynamic_update_slice_in_dim(W, W_new, start, axis=0)
+
+
+def _residual(Kb, Kbb, W, W_old):
+    """``K_Bᵀ W − K_BBᵀ W_B``: what the other blocks predict for block B."""
+    return (
+        jnp.matmul(Kb.T, W, precision=SOLVE_PRECISION)
+        - jnp.matmul(Kbb.T, W_old, precision=SOLVE_PRECISION)
+    )
 
 
 def _krr_block_step_cached_impl(Kb, Y, W, start, lam, *, bs):
@@ -135,8 +200,7 @@ def _krr_block_step_cached_impl(Kb, Y, W, start, lam, *, bs):
     Kbb = jax.lax.dynamic_slice_in_dim(Kb, start, bs, axis=0)
     W_old = jax.lax.dynamic_slice_in_dim(W, start, bs, axis=0)
     Yb = jax.lax.dynamic_slice_in_dim(Y, start, bs, axis=0)
-    residual = Kb.T @ W - Kbb.T @ W_old
-    W_new = solve_spd(Kbb, Yb - residual, lam)
+    W_new = solve_spd(Kbb, Yb - _residual(Kb, Kbb, W, W_old), lam)
     return jax.lax.dynamic_update_slice_in_dim(W, W_new, start, axis=0)
 
 
@@ -257,50 +321,65 @@ class KernelRidgeRegression(LabelEstimator, CostModel):
                 start_epoch = int(saved["epoch"])
                 start_step = int(saved["step"])
         steps_done = 0
-        for epoch in range(self.num_epochs):
-            # the permutation stream must be identical across a resume, so
-            # draw it per epoch regardless of where we restart
-            order = list(range(num_blocks))
-            if rng is not None:
-                rng.shuffle(order)
-            if epoch < start_epoch:
-                continue
-            for step, blk in enumerate(order):
-                if epoch == start_epoch and step < start_step:
+        with span(
+            "krr.fit", blocks=num_blocks, epochs=self.num_epochs,
+            block_size=bs,
+        ) as fit_sp:
+            for epoch in range(self.num_epochs):
+                # the permutation stream must be identical across a resume,
+                # so draw it per epoch regardless of where we restart
+                order = list(range(num_blocks))
+                if rng is not None:
+                    rng.shuffle(order)
+                if epoch < start_epoch:
                     continue
-                start = blk * bs
-                size = min(bs, n - start)
-                # ONE fused program per block (generation + residual +
-                # Cholesky solve + in-place model update); phase table
-                # keeps the per-block wall (parity: the reference's
-                # per-block timing logs, KernelRidgeRegression.scala:
-                # 216-224 — its four sub-phases are one XLA program here)
-                with span("krr.block_step") as sp:
-                    if self.cache_kernel:
-                        Kb = kernel_cache.get(start)
-                        if Kb is None:
-                            Kb = _kernel_block_slice(
-                                X, start, jnp.float32(self.gamma), size
+                for step, blk in enumerate(order):
+                    if epoch == start_epoch and step < start_step:
+                        continue
+                    start = blk * bs
+                    size = min(bs, n - start)
+                    # ONE program per block step (residual + Cholesky solve
+                    # + in-place model update; with the generation too where
+                    # the kernel is not cached); the span keeps the
+                    # per-block wall (parity: the reference's per-block
+                    # timing logs, KernelRidgeRegression.scala:216-224)
+                    with span(
+                        "krr.block_step", block=blk, rows=n, cols=size,
+                    ) as sp:
+                        if self.cache_kernel:
+                            Kb = kernel_cache.get(start)
+                            if Kb is None:
+                                with _kernel_span(n, size) as kp:
+                                    Kb = _kernel_block_slice(
+                                        X, start, jnp.float32(self.gamma),
+                                        size,
+                                    )
+                                    kp.sync_on(Kb)
+                                kernel_cache[start] = Kb
+                            W = _krr_block_step_cached(
+                                Kb, Y, W, start, jnp.float32(self.lam),
+                                bs=size,
                             )
-                            kernel_cache[start] = Kb
-                        W = _krr_block_step_cached(
-                            Kb, Y, W, start, jnp.float32(self.lam),
-                            bs=size,
+                        else:
+                            with _kernel_span(n, size):
+                                W = _krr_block_step(
+                                    X, Y, W, start, jnp.float32(self.gamma),
+                                    jnp.float32(self.lam), bs=size,
+                                )
+                        sp.sync_on(W)
+                    steps_done += 1
+                    if ckpt and steps_done % self.checkpoint_interval == 0:
+                        np.savez(
+                            ckpt,
+                            W=np.asarray(jax.block_until_ready(W)),
+                            epoch=epoch,
+                            step=step + 1,
                         )
-                    else:
-                        W = _krr_block_step(
-                            X, Y, W, start, jnp.float32(self.gamma),
-                            jnp.float32(self.lam), bs=size,
-                        )
-                    sp.sync_on(W)
-                steps_done += 1
-                if ckpt and steps_done % self.checkpoint_interval == 0:
-                    np.savez(
-                        ckpt,
-                        W=np.asarray(jax.block_until_ready(W)),
-                        epoch=epoch,
-                        step=step + 1,
-                    )
+            # what the cache held when the solve ended
+            fit_sp.attrs["cached_bytes"] = sum(
+                int(K.nbytes) for K in kernel_cache.values()
+            )
+            fit_sp.sync_on(W)
         if ckpt and os.path.exists(ckpt):
             os.remove(ckpt)  # complete fit: drop the restart state
         return KernelBlockLinearMapper(X, W, self.gamma, bs)

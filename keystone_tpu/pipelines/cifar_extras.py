@@ -14,7 +14,9 @@ RandomPatchCifar; what differs is the featurization/solver tail:
     AugmentedExamplesEvaluator.
   * RandomPatchCifarKernel: whitened patch features → StandardScaler →
     Gauss-Seidel kernel ridge regression with streaming kernel blocks
-    (cache_blocks configurable) and periodic solver-state checkpointing.
+    (cache_blocks configurable) and periodic solver-state checkpointing —
+    its own module, ``pipelines/random_patch_cifar_kernel.py``, whose
+    ``run`` the CLI below calls.
 """
 
 from __future__ import annotations
@@ -42,11 +44,12 @@ from ..nodes.images.core import (
     RandomPatcher,
     SymmetricRectifier,
 )
-from ..nodes.learning.kernel import KernelRidgeRegression
 from ..nodes.learning.linear import LinearMapEstimator
 from ..nodes.stats import StandardScaler
 from ..nodes.util import ClassLabelIndicators, MaxClassifier
 from .random_patch_cifar import RandomCifarConfig, learn_filters
+from .random_patch_cifar_kernel import KernelCifarConfig
+from .random_patch_cifar_kernel import run as run_random_patch_cifar_kernel
 
 NUM_CLASSES = 10
 
@@ -176,59 +179,6 @@ def run_random_patch_cifar_augmented(
         names.tolist(), NUM_CLASSES, "average"
     ).evaluate(scores, np.repeat(np.asarray(test.labels.to_array()), n_aug))
     return scorer, evaluation, time.perf_counter() - start
-
-
-# ---- RandomPatchCifarKernel ---------------------------------------------
-
-@dataclass
-class KernelCifarConfig(RandomCifarConfig):
-    """Parity: RandomCifarConfig (RandomPatchCifarKernel.scala:101-117)."""
-
-    gamma: float = 2e-4
-    cache_kernel: bool = True
-    block_size: int = 5000
-    num_epochs: int = 1
-    checkpoint_dir: Optional[str] = None
-
-
-def run_random_patch_cifar_kernel(
-    train: LabeledData, test: LabeledData, conf: KernelCifarConfig
-):
-    """Whitened patch conv features into blockwise kernel ridge regression
-    (parity: RandomPatchCifarKernel.scala:20-98)."""
-    start = time.perf_counter()
-    labels = ClassLabelIndicators(NUM_CLASSES).apply_batch(train.labels)
-    filters, whitener = learn_filters(train.data, conf)
-    featurizer = (
-        Convolver(filters, NROW, NROW, NCHAN, whitener=whitener,
-                  normalize_patches=True)
-        .and_then(SymmetricRectifier(alpha=conf.alpha))
-        .and_then(Pooler(conf.pool_stride, conf.pool_size, None, "sum"))
-        .and_then(ImageVectorizer())
-    )
-    pipeline = featurizer.and_then(
-        StandardScaler(), train.data
-    ).and_then(
-        KernelRidgeRegression(
-            conf.gamma,
-            conf.lam or 0.0,
-            conf.block_size,
-            conf.num_epochs,
-            block_permuter=conf.seed,
-            cache_kernel=conf.cache_kernel,
-            checkpoint_dir=conf.checkpoint_dir,
-        ),
-        train.data,
-        labels,
-    ).and_then(MaxClassifier())
-    ev = MulticlassClassifierEvaluator(NUM_CLASSES)
-    train_err = ev.evaluate(
-        pipeline(train.data).get().to_array(), train.labels
-    ).total_error
-    test_err = ev.evaluate(
-        pipeline(test.data).get().to_array(), test.labels
-    ).total_error
-    return pipeline, train_err, test_err, time.perf_counter() - start
 
 
 # ---- CLI -----------------------------------------------------------------

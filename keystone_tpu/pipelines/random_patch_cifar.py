@@ -62,13 +62,26 @@ class RandomCifarConfig:
 @partial(jax.jit, static_argnums=(4,))
 def _patches_at(X, img, x0, y0, size: int):
     """The ``size``×``size`` windows of ``X`` (n, X, Y, C) whose corners are
-    ``(img[i], x0[i], y0[i])``, as (len(img), size, size, C)."""
-    def one(i, x, y):
-        return jax.lax.dynamic_slice(
-            X, (i, x, y, 0), (1, size, size, X.shape[-1])
-        )[0]
+    ``(img[i], x0[i], y0[i])``, as (len(img), size, size, C).
 
-    return jax.vmap(one)(img, x0, y0)
+    The images' rows side by side, (n·X, Y·C), are ONE gather of whole
+    rows — ``size`` a window, an embedding lookup's access — and each
+    window's ``size``·C columns are chosen from its rows by the offset
+    ``y0`` among the Y − size + 1 static ones. Sliced from (n, X, Y, C)
+    itself, the gather takes the images in a layout whose minor C = 3 pads
+    to 128 lanes — 26.2 GB at 50,000 images on the TPU, 8.6 GB at 16,384;
+    a window cut from the rows at its own column offset compiles to a loop
+    a window (112 ms a draw of 100,000 on the chip)."""
+    n, xd, yd, c = X.shape
+    rows = X.reshape(n * xd, yd * c)
+    first = img * xd + x0
+    band = rows[first[:, None] + jnp.arange(size)]  # (m, size, Y·C)
+    out = jnp.zeros(band.shape[:2] + (size * c,), X.dtype)
+    for y in range(yd - size + 1):
+        out = out + jnp.where(
+            (y0 == y)[:, None, None], band[:, :, y * c:(y + size) * c], 0.0
+        )
+    return out.reshape(-1, size, size, c)
 
 
 def sample_patches(images, conf: "RandomCifarConfig"):

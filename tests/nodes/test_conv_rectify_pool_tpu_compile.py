@@ -233,3 +233,73 @@ def test_a_dense_samples_scratch_is_what_dispatch_is_told(sampled_dense):
     assert told == 14_432_768
     held = compiled.memory_analysis().temp_size_in_bytes / INET_SLICE
     assert 0.9 * told <= held <= 1.1 * told, (told, held)
+
+
+# -- the patch draw of the filter learning at cifar_krr's 50,000 images ------
+
+
+def test_the_patch_draw_holds_no_padded_copy_of_the_images(one_chip):
+    """100,000 windows drawn from 50,000 images: one gather of whole image
+    rows, the draw's temporaries near 3 GB. Sliced from (n, 32, 32, 3)
+    itself the compiler lays the images out with C = 3 padded to 128
+    lanes, a 26.2 GB copy that no chip holds."""
+    from jax.experimental.compilation_cache import compilation_cache
+
+    from keystone_tpu.pipelines.random_patch_cifar import _patches_at
+
+    n, m = 50000, 100000
+    images = jax.ShapeDtypeStruct((n, SIDE, SIDE, 3), jnp.float32,
+                                  sharding=one_chip)
+    index = jax.ShapeDtypeStruct((m,), jnp.int32, sharding=one_chip)
+    cache_was_on = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    try:
+        compiled = _patches_at.lower(images, index, index, index, 6).compile()
+    finally:
+        jax.config.update("jax_enable_compilation_cache", cache_was_on)
+        compilation_cache.reset_cache()
+    memory = compiled.memory_analysis()
+    # the windows themselves, in the chip's tiling
+    assert memory.output_size_in_bytes < 2 * m * 6 * 6 * 3 * 4
+    assert memory.temp_size_in_bytes < 4e9
+    # one gather of whole rows (a window cut at its own column offset
+    # compiles to no gather at all: a loop of slices, a window a step)
+    assert len(re.findall(r" gather\(", compiled.as_text())) == 1
+
+
+def test_the_bodies_hold_no_more_than_dispatch_is_told(one_chip):
+    """The three bodies at ``cifar_krr``'s 100 filters and stated precision
+    over 1,024 images: the temporaries the TPU compiler gives the program
+    an image stay under what ``row_scratch_bytes`` declares."""
+    from jax.experimental.compilation_cache import compilation_cache
+
+    rng = np.random.default_rng(0)
+    m = 6 * 6 * 3
+    node = ConvRectifyPool(
+        Convolver(
+            rng.standard_normal((100, m)).astype(np.float32), SIDE, SIDE, 3,
+            whitener=ZCAWhitener(
+                np.eye(m, dtype=np.float32), np.zeros(m, np.float32)
+            ),
+            precision="high",
+        ),
+        SymmetricRectifier(alpha=0.25), Pooler(13, 14, None, "sum"),
+    )
+    vectorizer = ImageVectorizer()
+    images = jax.ShapeDtypeStruct(
+        (IMAGES, SIDE, SIDE, 3), jnp.float32, sharding=one_chip
+    )
+    cache_was_on = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    try:
+        compiled = jax.jit(
+            lambda X: vectorizer.trace_batch(node.trace_batch(X))
+        ).lower(images).compile()
+    finally:
+        jax.config.update("jax_enable_compilation_cache", cache_was_on)
+        compilation_cache.reset_cache()
+    told = node.row_scratch_bytes((IMAGES, SIDE, SIDE, 3))
+    held = compiled.memory_analysis().temp_size_in_bytes / IMAGES
+    assert 0.3 * told <= held <= told, (told, held)

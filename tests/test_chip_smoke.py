@@ -85,9 +85,7 @@ def test_fit_then_serve_legs_at_tiny_size(tmp_path):
     assert chip_smoke.fallbacks_fired() == {}
 
 
-def test_kernel_leg_interpreted_and_sync_check():
-    report = chip_smoke.kernel_leg(n=700, d=128, b=256, interpret=True)
-    assert report["ok"], report
+def test_sync_check():
     sync = chip_smoke.sync_check(size=64, steps=3)
     assert sync["block_until_ready_seconds"] > 0
 

@@ -11,7 +11,10 @@ offset and its per-step scatter, the widest gap between an aligned span's
 edges and its annotation's, the idle split, what the content digest hashed
 and answered from memory a unit by span name, what segment dispatch did
 with the window's first segments (``rows``, the slices, ``conv_fused_rows``,
-``sift_sampled_rows``, ``sift_sampled_path``, ``cosine_bounded_rows``), and
+``sift_sampled_rows``, ``sift_sampled_path``, ``cosine_bounded_rows``), what
+the kernel solver did a unit (``krr.fit``'s ``blocks``, ``epochs``,
+``block_size``, ``cached_bytes``; the ``entries`` of the ``krr.kernel``
+generations and of the ``krr.apply`` spans), and
 — for the readers that match device operations by name — whether the operations' names or stats
 carry the ``ks.*`` named scopes. ``--cpu`` rehearses the host side on the
 CPU with the tests' tiny benchmark (no device plane: no idle split).
@@ -401,6 +404,24 @@ def main(argv=None) -> int:
         {k: sp.attrs[k] for k in facts if k in sp.attrs}
         for sp in spans if sp.name == "exec.segment"
     ][:16]
+    # what the kernel solver did a unit: its fit and the kernel entries it
+    # made, in the fit and in the applies
+    krr: dict = {}
+    for sp in spans:
+        if sp.name == "krr.fit" and "fit" not in krr:
+            krr["fit"] = {
+                k: sp.attrs.get(k)
+                for k in ("blocks", "epochs", "block_size", "cached_bytes")
+            }
+        elif sp.name in ("krr.kernel", "krr.apply"):
+            row = krr.setdefault(
+                sp.name, {"spans": 0, "entries": 0.0, "seconds": 0.0},
+            )
+            row["spans"] += 1 / units
+            row["entries"] += sp.attrs.get("entries", 0) / units
+            row["seconds"] += sp.seconds / units
+    if krr:
+        out["krr_per_unit"] = krr
     roots = [(sp.name, sp.start, sp.end) for sp in spans if sp.name == "job"]
     offset = span_idle.offset_of(roots, anchors) if anchors else None
     if offset is not None:
