@@ -489,7 +489,8 @@ def _member_facts(
     say of ``rows`` rows — ``segment_facts(shape, rows)``: the fused
     convolution's ``conv_fused_rows`` where its kernel engages, the sampled
     SIFT body's ``sift_sampled_rows`` and ``sift_sampled_path``, the
-    guarded cosine's ``cosine_bounded_rows`` — each asked at the shape its
+    guarded cosine's ``cosine_bounded_rows``, the fused cosine scores'
+    ``cosine_scores_rows`` — each asked at the shape its
     first input has where ``arrays`` go through ``slice_rows`` at a time.
     The members a speaking member's first input is made from are
     evaluated abstractly (``jax.eval_shape``: no operation runs), and no

@@ -30,14 +30,20 @@ of raw bins.
 
 from __future__ import annotations
 
-from typing import Callable, List, Optional, Sequence, Tuple
+from typing import Optional, Sequence, Tuple
 
 import jax
 import jax.numpy as jnp
 
 from ...ops import conv_rectify_pool as _kernel
 from ...workflow.graph import Graph, NodeId
-from ...workflow.rules import Annotations, Rule
+from ...workflow.rules import (
+    Annotations,
+    Rule,
+    heads,
+    only_reader_of,
+    replace_chain,
+)
 from ...workflow.transformer import Transformer
 from ..stats.core import ColumnSampler, RowKeyedTransformer
 from .core import Convolver, Pooler, SymmetricRectifier
@@ -130,49 +136,6 @@ class ConvRectifyPool(Transformer):
             )
 
 
-def _heads(graph: Graph, kind) -> List[NodeId]:
-    return [
-        n for n in sorted(graph.nodes)
-        if isinstance(graph.get_operator(n), kind)
-    ]
-
-
-def _only_reader_of(
-    graph: Graph, annotations: Annotations
-) -> Callable[[NodeId], Optional[NodeId]]:
-    """``only_reader(node)``: the one node that reads ``node``, or None
-    where a second node or a sink reads it too, or its result is saved
-    (annotated: it has to reach the state table)."""
-    readers: dict = {}
-    for node in graph.nodes:
-        for d in graph.get_dependencies(node):
-            readers.setdefault(d, []).append(node)
-    for d in graph.sink_dependencies.values():
-        readers.setdefault(d, []).append(None)
-
-    def only_reader(node: NodeId) -> Optional[NodeId]:
-        found = readers.get(node, [])
-        if len(found) != 1 or node in annotations:
-            return None
-        return found[0]
-
-    return only_reader
-
-
-def _replace_chain(
-    graph: Graph, chain: Sequence[NodeId], fused: Transformer
-) -> Graph:
-    """``fused`` at the last node of ``chain``, reading what its first node
-    read; the nodes before the last go."""
-    graph = graph.set_operator(chain[-1], fused)
-    graph = graph.set_dependencies(
-        chain[-1], graph.get_dependencies(chain[0])
-    )
-    for node in reversed(chain[:-1]):
-        graph = graph.remove_node(node)
-    return graph
-
-
 class ConvChainRule(Rule):
     """Replace every ``Convolver`` whose only reader is a
     ``SymmetricRectifier`` whose only reader is a ``Pooler`` with
@@ -186,10 +149,10 @@ class ConvChainRule(Rule):
     def apply(
         self, graph: Graph, annotations: Annotations
     ) -> Tuple[Graph, Annotations]:
-        heads = _heads(graph, Convolver)
-        if not heads:
+        found = heads(graph, Convolver)
+        if not found:
             return graph, annotations
-        only_reader = _only_reader_of(graph, annotations)
+        only_reader = only_reader_of(graph, annotations)
 
         def reader(node: Optional[NodeId], kind) -> Optional[NodeId]:
             found = None if node is None else only_reader(node)
@@ -197,7 +160,7 @@ class ConvChainRule(Rule):
                 return None
             return found if isinstance(graph.get_operator(found), kind) else None
 
-        for head in heads:
+        for head in found:
             mid = reader(head, SymmetricRectifier)
             tail = reader(mid, Pooler)
             if tail is None:
@@ -208,7 +171,7 @@ class ConvChainRule(Rule):
             fused = ConvRectifyPool(
                 graph.get_operator(head), graph.get_operator(mid), pooler
             )
-            graph = _replace_chain(graph, (head, mid, tail), fused)
+            graph = replace_chain(graph, (head, mid, tail), fused)
         return graph, annotations
 
 
@@ -280,11 +243,11 @@ class SampledSIFTRule(Rule):
     def apply(
         self, graph: Graph, annotations: Annotations
     ) -> Tuple[Graph, Annotations]:
-        heads = _heads(graph, SIFTExtractor)
-        if not heads:
+        found = heads(graph, SIFTExtractor)
+        if not found:
             return graph, annotations
-        only_reader = _only_reader_of(graph, annotations)
-        for head in heads:
+        only_reader = only_reader_of(graph, annotations)
+        for head in found:
             chain = [head]
             while True:
                 node = only_reader(chain[-1])
@@ -298,5 +261,5 @@ class SampledSIFTRule(Rule):
             if len(chain) < 2 or not isinstance(ops[-1], ColumnSampler):
                 continue
             fused = SampledSIFTExtractor(ops[0], ops[1:-1], ops[-1])
-            graph = _replace_chain(graph, chain, fused)
+            graph = replace_chain(graph, chain, fused)
         return graph, annotations

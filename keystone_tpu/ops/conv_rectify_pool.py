@@ -43,11 +43,13 @@ from __future__ import annotations
 
 import dataclasses
 import functools
-from typing import List, Optional, Tuple
+from typing import List, Tuple
 
 import jax
 import jax.numpy as jnp
 import numpy as np
+
+from . import kernel_mode  # noqa: F401  (how this kernel runs here)
 
 #: rows of a group start on a multiple of this (the bf16 sublane tile)
 _ROW_ALIGN = 16
@@ -333,12 +335,3 @@ def conv_rectify_pool(
     # once
     pooled = pooled[:n].reshape(n, plan.npy, plan.npx, 2 * K)
     return jnp.transpose(pooled, (0, 2, 1, 3))
-
-
-def kernel_mode() -> Optional[str]:
-    """How the kernel would run in this process: ``"compiled"`` on one TPU
-    chip, None anywhere else — the callers then run the XLA bodies, which
-    XLA can partition over a mesh as a custom call cannot. (The CPU tests
-    run it as ``"interpret"``.)"""
-    on_one_tpu = jax.default_backend() == "tpu" and jax.device_count() == 1
-    return "compiled" if on_one_tpu else None

@@ -537,9 +537,13 @@ class Pipeline(Chainable):
                 }
                 executor = fresh
 
+        from ..nodes.learning.cosine_linear import CosineScoresRule
         from .rules import UnusedBranchRemovalRule
 
         graph, _ = UnusedBranchRemovalRule().apply(graph, {})
+        # a fitted mapper over cosine features becomes ONE node here: the
+        # optimizer ran while it was still an estimator
+        graph, _ = CosineScoresRule().apply(graph, annotations)
         for node in graph.nodes:
             op = graph.get_operator(node)
             if not isinstance(op, (TransformerOperator, ExpressionOperator, DatasetOperator, DatumOperator)):

@@ -13,7 +13,7 @@ from __future__ import annotations
 
 import logging
 from dataclasses import dataclass
-from typing import Dict, List, Optional, Sequence, Tuple
+from typing import Callable, Dict, List, Optional, Sequence, Tuple
 
 from . import analysis
 from .env import PipelineEnv
@@ -138,6 +138,55 @@ class SavedStateLoadRule(Rule):
                 graph = graph.set_operator(node, ExpressionOperator(expr))
                 graph = graph.set_dependencies(node, [])
         return graph, annotations
+
+
+# -- for the rules that put ONE node in place of a chain, matching operator
+#    types and scalars and reading no array (``nodes/images/chain.py``,
+#    ``nodes/learning/cosine_linear.py``) -----------------------------------
+
+
+def heads(graph: Graph, kind) -> List[NodeId]:
+    """The nodes whose operator is a ``kind``, in id order."""
+    return [
+        n for n in sorted(graph.nodes)
+        if isinstance(graph.get_operator(n), kind)
+    ]
+
+
+def only_reader_of(
+    graph: Graph, annotations: Annotations
+) -> Callable[[NodeId], Optional[NodeId]]:
+    """``only_reader(node)``: the one node that reads ``node``, or None
+    where a second node or a sink reads it too, or its result is saved
+    (annotated: it has to reach the state table)."""
+    readers: dict = {}
+    for node in graph.nodes:
+        for d in graph.get_dependencies(node):
+            readers.setdefault(d, []).append(node)
+    for d in graph.sink_dependencies.values():
+        readers.setdefault(d, []).append(None)
+
+    def only_reader(node: NodeId) -> Optional[NodeId]:
+        found = readers.get(node, [])
+        if len(found) != 1 or node in annotations:
+            return None
+        return found[0]
+
+    return only_reader
+
+
+def replace_chain(
+    graph: Graph, chain: Sequence[NodeId], fused: Operator
+) -> Graph:
+    """``fused`` at the last node of ``chain``, reading what its first node
+    read; the nodes before the last go."""
+    graph = graph.set_operator(chain[-1], fused)
+    graph = graph.set_dependencies(
+        chain[-1], graph.get_dependencies(chain[0])
+    )
+    for node in reversed(chain[:-1]):
+        graph = graph.remove_node(node)
+    return graph
 
 
 class UnusedBranchRemovalRule(Rule):

@@ -334,8 +334,9 @@ def test_batch_coupled_members_over_chunks_raise_typed():
 def test_tiny_timit_job_is_one_segment_a_data_set():
     """No optimizer patching: the default rule stack, the planner, the
     binding. The cosines, the gather and the combiner are ONE program over
-    the training set at fit and (with the fitted mapper and the classifier)
-    over the test set at apply; none of them runs as a node."""
+    the training set at fit; at apply the fitted chain is ONE node with the
+    mapper (``CosineScoresRule``) and, with the classifier, ONE program over
+    the test set; none of them runs as a node."""
     from keystone_tpu.nodes.learning.linear import BlockLeastSquaresEstimator
     from keystone_tpu.nodes.util import ClassLabelIndicators, MaxClassifier
     from keystone_tpu.pipelines.timit import (
@@ -370,14 +371,15 @@ def test_tiny_timit_job_is_one_segment_a_data_set():
         apply_spans = tracer.spans()[len(fit_spans):]
     finally:
         tracer_mod.reset()
-    for spans, members in ((fit_spans, 4), (apply_spans, 6)):
+    for spans, members, label in (
+        (fit_spans, 4, "CosineRandomFeatures+CosineRandomFeatures+"
+                       "GatherTransformerOperator+VectorCombiner"),
+        (apply_spans, 2, "CosineLinearMapper+MaxClassifier"),
+    ):
         (sp,) = _segment_spans(spans)
         assert sp.attrs["path"] == "compiled"
         assert sp.attrs["nodes"] == members
-        assert sp.attrs["label"].startswith(
-            "CosineRandomFeatures+CosineRandomFeatures+"
-            "GatherTransformerOperator+VectorCombiner"
-        )
+        assert sp.attrs["label"] == label
         for name in (
             "node.CosineRandomFeatures", "node.GatherTransformerOperator",
             "node.VectorCombiner",

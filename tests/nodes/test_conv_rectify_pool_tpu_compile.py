@@ -13,6 +13,9 @@ holds while its sample is made is what segment dispatch is told. And at
 ``imagenet_fv16``'s widths, where the sample is dense enough to be read
 through the keypoint grid: the stack of raw bins is the widest buffer, the
 pooled maps are never joined, and the scratch is what dispatch is told.
+And the fitted TIMIT chain's one node (``CosineLinearMapper``) at
+``timit_cos4``'s widths: Mosaic takes the cosine-scores kernel, and no
+buffer holds a scoring chunk's features.
 
 The topology is described inside a fixture, never at import: one process at
 a time may load the TPU's library, and every xdist worker imports this file.
@@ -41,6 +44,7 @@ from keystone_tpu.nodes.learning.pca import BatchPCATransformer
 from keystone_tpu.nodes.learning.zca import ZCAWhitener
 from keystone_tpu.nodes.stats import ColumnSampler, SignedHellingerMapper
 from keystone_tpu.ops import conv_rectify_pool as crp
+from keystone_tpu.ops import cosine_scores as cs
 
 FILTERS, IMAGES, SIDE = 10000, 1024, 32
 
@@ -303,3 +307,106 @@ def test_the_bodies_hold_no_more_than_dispatch_is_told(one_chip):
     told = node.row_scratch_bytes((IMAGES, SIDE, SIDE, 3))
     held = compiled.memory_analysis().temp_size_in_bytes / IMAGES
     assert 0.3 * told <= held <= told, (told, held)
+
+
+# -- the cosine scores at timit_cos4's widths ---------------------------------
+
+COS_ROWS, COS_DIM, COS_FEATURES, COS_CLASSES = 65536, 440, 4096, 147
+
+
+def _cosine_scores_program(one_chip, rows):
+    """The fitted chain's one node and the classifier over a scoring chunk
+    of ``rows``: the guard, the fused kernel and its fallback, compiled for
+    the described chip with the parameters as arguments (as the literals a
+    fitted program holds they cost the compiler 20 s more, and the same
+    program)."""
+    from jax.experimental.compilation_cache import compilation_cache
+
+    from keystone_tpu.nodes.learning import cosine_linear
+
+    def spec(shape):
+        return jax.ShapeDtypeStruct(shape, jnp.float32, sharding=one_chip)
+
+    branches = 4
+    args = (
+        spec((rows, COS_DIM)),
+        spec((COS_DIM, branches * COS_FEATURES)),
+        spec((branches * COS_FEATURES,)),
+        spec((branches * COS_FEATURES,)),
+        spec((branches * COS_FEATURES, COS_CLASSES)),
+        spec((COS_CLASSES,)),
+        spec((branches,)), spec((branches,)),
+    )
+    cache_was_on = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    try:
+        return jax.jit(
+            lambda *a: jnp.argmax(
+                cosine_linear._guarded_scores(*a, interpret=False), axis=-1
+            )
+        ).lower(*args).compile()
+    finally:
+        jax.config.update("jax_enable_compilation_cache", cache_was_on)
+        compilation_cache.reset_cache()
+
+
+@pytest.fixture(scope="module")
+def cosine_scores(one_chip):
+    return {
+        rows: _cosine_scores_program(one_chip, rows)
+        for rows in (COS_ROWS, 2 * COS_ROWS)
+    }
+
+
+def test_mosaic_takes_the_cosine_scores_kernel(cosine_scores):
+    text = cosine_scores[COS_ROWS].as_text()
+    assert "tpu_custom_call" in text
+    assert re.search(r"%cosine_scores[.\d]* = f32\[65536,147\]", text)
+
+
+def test_a_chunks_features_are_no_hbm_buffer(cosine_scores):
+    """Not in the kernel's branch and not in the guard's fallback: no
+    array of the program holds a chunk's features, a branch's or all
+    four's (4.3 GB where the chain is written out)."""
+    text = cosine_scores[COS_ROWS].as_text()
+    shapes = set(re.findall(r"(?:f32|bf16)\[([\d,]+)\]", text))
+    assert "65536,4096" not in shapes and "65536,16384" not in shapes
+    widest = max(
+        int(np.prod([int(d) for d in s.split(",")])) for s in shapes
+    )
+    assert widest < COS_ROWS * COS_FEATURES
+
+
+def test_a_scored_rows_scratch_is_what_dispatch_is_told(cosine_scores):
+    """What a row adds to the program's temporaries — the guard's fallback
+    takes the rows a block at a time — against ``row_scratch_bytes``: no
+    more, and not far under."""
+    from keystone_tpu.nodes.learning.cosine_linear import CosineLinearMapper
+    from keystone_tpu.nodes.learning.linear import BlockLinearMapper
+    from keystone_tpu.nodes.stats import CosineRandomFeatures
+
+    rng = np.random.default_rng(0)
+    node = CosineLinearMapper(
+        [CosineRandomFeatures.create(COS_DIM, COS_FEATURES, 0.05555, seed=i)
+         for i in range(4)],
+        BlockLinearMapper(
+            [rng.standard_normal((COS_FEATURES, COS_CLASSES)).astype(np.float32)
+             for _ in range(4)], COS_FEATURES,
+        ),
+    )
+    mode = cs.kernel_mode
+    cs.kernel_mode = lambda: "compiled"  # the backend here is the CPU
+    try:
+        told = node.row_scratch_bytes((COS_ROWS, COS_DIM))
+    finally:
+        cs.kernel_mode = mode
+    temps = {
+        rows: c.memory_analysis().temp_size_in_bytes
+        for rows, c in cosine_scores.items()
+    }
+    held = (temps[2 * COS_ROWS] - temps[COS_ROWS]) / COS_ROWS
+    # the two branches of the guard share their buffers: what dispatch is
+    # told is the larger branch's, an upper bound
+    assert 0.5 * told <= held <= told, (told, held)
+    assert temps[COS_ROWS] < 0.5e9  # the parent's program: 7.5 GB

@@ -203,9 +203,12 @@ def test_exec_segment_counts_the_rows_through_the_guarded_body(monkeypatch):
         tracer_mod.reset()
         reset_dispatchers()
     assert [s.attrs["rows"] for s in spans] == [96, 24]
+    # the fit's featurizer, then the fitted chain as ONE node with its map
+    # (``CosineScoresRule``), whose branches say the same
+    assert spans[0].attrs["label"].startswith("CosineRandomFeatures+")
+    assert spans[1].attrs["label"].startswith("CosineLinearMapper+")
     for sp in spans:
         assert sp.attrs["path"] == "compiled"
-        assert sp.attrs["label"].startswith("CosineRandomFeatures+")
         assert sp.attrs["cosine_bounded_rows"] == sp.attrs["rows"]
     narrow = CosineRandomFeatures(np.ones((4, 24), np.float16), np.zeros(4, F))
     assert narrow.segment_facts((24, 24), 24) == {}  # no guarded body lowered

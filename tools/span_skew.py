@@ -11,7 +11,8 @@ offset and its per-step scatter, the widest gap between an aligned span's
 edges and its annotation's, the idle split, what the content digest hashed
 and answered from memory a unit by span name, what segment dispatch did
 with the window's first segments (``rows``, the slices, ``conv_fused_rows``,
-``sift_sampled_rows``, ``sift_sampled_path``, ``cosine_bounded_rows``), what
+``sift_sampled_rows``, ``sift_sampled_path``, ``cosine_bounded_rows``,
+``cosine_scores_rows``), what
 the kernel solver did a unit (``krr.fit``'s ``blocks``, ``epochs``,
 ``block_size``, ``cached_bytes``; the ``entries`` of the ``krr.kernel``
 generations and of the ``krr.apply`` spans), and
@@ -398,7 +399,7 @@ def main(argv=None) -> int:
     # slices, and the counts that say a fused body engaged
     facts = ("label", "path", "rows", "row_slices", "slice_rows",
              "conv_fused_rows", "sift_sampled_rows", "sift_sampled_path",
-             "cosine_bounded_rows",
+             "cosine_bounded_rows", "cosine_scores_rows",
              "cache_declined_bytes")
     out["segments"] = [
         {k: sp.attrs[k] for k in facts if k in sp.attrs}
